@@ -1,0 +1,835 @@
+"""First proof that the system starts on the chip: one process, all visible
+chips, the main path end to end through the normal entry points.
+
+    python chip_smoke.py                 # needs a TPU; fails without one
+    python chip_smoke.py --rehearse-cpu  # toy sizes on 2 virtual CPU devices
+
+Phases (a failure in any is fatal; nothing is retried on another path):
+
+1. identity  device facts, versions, compile-cache directory, the native
+             host library must have built, host link rates
+2. kernels   each Pallas kernel, compiled, at its bench shape against its
+             jax.numpy reference on the same chip
+3. deepfm    bench.py's DeepFM at full width as a trainer: text files ->
+             Dataset (ingest worker processes) -> two train passes with a
+             pipelined split build and fused boundary between them; then
+             the same pass on the XLA kernel path, twice, to bound the
+             Pallas-vs-XLA difference by XLA's difference with itself
+4. predict   export -> CTRPredictor -> PredictServer answers a
+             PredictClient over the wire, bit-identical to direct predict
+5. gpt       bench.py's GPT config: two train steps on the flash path,
+             step-1 loss against the ring (XLA) attention of the same model
+6. facts     compile seconds / cache hits / peak memory per phase
+
+The report goes to <out>/chip_smoke_report.json and to stdout; the last
+line of stdout is {"ok": true, "device": {...}}. This script claims no
+speed: every time in the report is set-up cost or a link fact.
+"""
+
+import argparse
+import json
+import os
+import sys
+import tempfile
+import time
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+
+# bench.py's deepfm / gpt configurations (bench.py:226-242, 894-896). Cut
+# for the smoke: the resident store (8M keys, not 50M) and the batch
+# count (10 per pass, not 64) — every width is the bench's.
+FULL = {
+    "slots": 26, "emb_dim": 16, "dense_dim": 13, "hidden": (400, 400, 400),
+    "batch": 16384, "store_keys": 8_000_000, "pass_keys": 4_000_000,
+    "new_keys": 100_000, "batches": 10, "hot": 1000,
+    # Depth cut 24 -> 12: at 24 layers the f32 step needs 16.22 GB of a
+    # v5e's 15.75 GB HBM (XLA's compile-time accounting, chip run of PR
+    # 21: the layer scan saves f32[24, 4, 1024, 4096] residuals).
+    "gpt": {"vocab_size": 50304, "d_model": 1024, "n_heads": 16,
+            "n_layers": 12, "d_ff": 4096, "max_seq_len": 1024},
+    "gpt_cut": "12 of bench_gpt's 24 layers; every width as in bench.py",
+    "gpt_batch_per_chip": 4, "flash": (4, 1024, 16, 64),
+    "seqpool": (65536, 16, 16384), "serve_requests": 48, "link_mib": 256,
+    "auc_floor": 0.7,
+}
+# Rehearsal: same code, toy sizes, Pallas kernels interpreted.
+TOY = {
+    "slots": 26, "emb_dim": 16, "dense_dim": 13, "hidden": (32, 32),
+    "batch": 256, "store_keys": 40_000, "pass_keys": 20_000,
+    "new_keys": 500, "batches": 4, "hot": 50,
+    "gpt": {"vocab_size": 256, "d_model": 64, "n_heads": 2,
+            "n_layers": 2, "d_ff": 128, "max_seq_len": 128},
+    "gpt_cut": "toy", "gpt_batch_per_chip": 2, "flash": (1, 128, 2, 64),
+    "seqpool": (1024, 16, 256), "serve_requests": 32, "link_mib": 4,
+    "auc_floor": 0.6,
+}
+
+
+class Smoke:
+    """Runs the phases and keeps the report. Per phase it records wall
+    seconds, jax's own compile seconds and persistent-cache hits/misses
+    (jax.monitoring events), and each device's peak bytes in use."""
+
+    def __init__(self, rehearsal: bool, out_dir: str, cache_dir):
+        import jax
+        import jax.monitoring
+        self.rehearsal = rehearsal
+        self.cfg = TOY if rehearsal else FULL
+        self.out_dir = out_dir
+        self.cache_dir = cache_dir
+        self.devices = jax.devices()
+        self.report = {"ok": False, "rehearsal": rehearsal, "phases": {}}
+        self._acc = {"compile_s": 0.0, "hits": 0, "misses": 0}
+        jax.monitoring.register_event_duration_secs_listener(self._on_secs)
+        jax.monitoring.register_event_listener(self._on_event)
+
+    def _on_secs(self, event, duration_secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            self._acc["compile_s"] += duration_secs
+
+    def _on_event(self, event, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            self._acc["hits"] += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self._acc["misses"] += 1
+
+    def cache_entries(self):
+        if self.cache_dir is None or not os.path.isdir(self.cache_dir):
+            return 0
+        return len(os.listdir(self.cache_dir))
+
+    def memory(self, stat):
+        """One ``memory_stats()`` entry per device (None off the TPU)."""
+        return [(d.memory_stats() or {}).get(stat) for d in self.devices]
+
+    def phase(self, name, fn):
+        print(f"[chip_smoke] phase {name}: start", file=sys.stderr,
+              flush=True)
+        self._acc = {"compile_s": 0.0, "hits": 0, "misses": 0}
+        t0 = time.perf_counter()
+        rec = {"ok": False}
+        self.report["phases"][name] = rec
+        fn(rec)     # fills the live record: a failure keeps what it had
+        rec["seconds"] = round(time.perf_counter() - t0, 2)
+        rec["compile_seconds"] = round(self._acc["compile_s"], 2)
+        rec["cache_hits"] = self._acc["hits"]
+        rec["cache_misses"] = self._acc["misses"]
+        rec["peak_bytes_in_use"] = self.memory("peak_bytes_in_use")
+        rec["ok"] = True
+        print(f"[chip_smoke] phase {name}: ok in {rec['seconds']}s "
+              f"(compile {rec['compile_seconds']}s)", file=sys.stderr,
+              flush=True)
+
+    def write_report(self):
+        os.makedirs(self.out_dir, exist_ok=True)
+        name = ("chip_smoke_rehearsal.json" if self.rehearsal else
+                f"chip_smoke_report_{len(self.devices)}chip.json")
+        path = os.path.join(self.out_dir, name)
+        with open(path, "w") as f:
+            json.dump(self.report, f, indent=1, default=str)
+            f.write("\n")
+        return path
+
+
+def check(cond, msg):
+    """A smoke assertion that survives ``python -O``."""
+    if not cond:
+        raise AssertionError(msg)
+
+
+# ---------------------------------------------------------------------------
+# Data, generated from a seed (bench.py:_planted_labels/_gen_pass_files role)
+# ---------------------------------------------------------------------------
+
+def planted_labels(rng, hot_ids, target_rate=0.25, strength=2.0):
+    """Each hot key carries a latent +-1 weight (a hash bit); labels are
+    Bernoulli in that weight's logit. A learner that recovers per-key
+    weights pulls AUC well above 0.5; an embedding served to the wrong
+    row cannot."""
+    import numpy as np
+    h = (hot_ids * np.uint64(0x9E3779B97F4A7C15)) >> np.uint64(32)
+    sign = (h & np.uint64(1)).astype(np.float32) * 2.0 - 1.0
+    logit = sign * strength + np.log(target_rate / (1.0 - target_rate))
+    return (rng.random(hot_ids.shape[0]) < 1.0 / (1.0 + np.exp(-logit))
+            ).astype(np.int32)
+
+
+def gen_lines(rng, ids, cfg, hot):
+    """svm-format lines for an [n, slots - 1] id block: slot 0 is drawn
+    from the hot head and the label carries that key's planted signal;
+    the block fills the other slots."""
+    import numpy as np
+    n = ids.shape[0]
+    ids = np.concatenate([rng.choice(hot, n)[:, None], ids], axis=1)
+    labels = planted_labels(rng, ids[:, 0])
+    line = labels.astype("U1")
+    for j in range(cfg["slots"]):
+        line = np.char.add(line, f" s{j}:")
+        line = np.char.add(line, ids[:, j].astype("U20"))
+    dense = (rng.random((n, cfg["dense_dim"])) * 10000).astype(np.int32)
+    line = np.char.add(line, " d:0.")
+    line = np.char.add(line, dense[:, 0].astype("U5"))
+    for j in range(1, cfg["dense_dim"]):
+        line = np.char.add(line, ",0.")
+        line = np.char.add(line, dense[:, j].astype("U5"))
+    return line.tolist(), labels
+
+
+def gen_pass_files(tmpdir, tag, rng, pass_keys, cfg, hot):
+    """One part file per batch. Every key of ``pass_keys`` occurs at
+    least once (whole permutations are dealt out), so the pass table is
+    built for the full key set, as the bench's 64-batch pass does."""
+    import numpy as np
+    nb, batch, slots = cfg["batches"], cfg["batch"], cfg["slots"] - 1
+    need = nb * batch * slots
+    check(need >= pass_keys.size, "too few batches to cover the pass keys")
+    deal = np.concatenate([rng.permutation(pass_keys) for _ in range(
+        -(-need // pass_keys.size))])[:need].reshape(nb, batch, slots)
+    files = []
+    for b in range(nb):
+        lines, _ = gen_lines(rng, deal[b], cfg, hot)
+        path = os.path.join(tmpdir, f"{tag}-part-{b:05d}")
+        with open(path, "w") as f:
+            f.write("\n".join(lines) + "\n")
+        files.append(path)
+    return files
+
+
+# ---------------------------------------------------------------------------
+# Phase 1: identity, native build, host link
+# ---------------------------------------------------------------------------
+
+def phase_identity(sm: Smoke, out: dict):
+    import importlib.metadata as md
+
+    import jax
+    import numpy as np
+
+    from paddlebox_tpu.native.build import native_available
+    d0 = sm.devices[0]
+    device = {"platform": d0.platform, "device_kind": d0.device_kind,
+              "device_count": len(sm.devices)}
+    sm.report.update(device)
+    out.update({
+        **device,
+        "versions": {p: md.version(p) for p in (
+            "jax", "jaxlib", "libtpu", "flax", "optax", "numpy")},
+        "python": sys.version.split()[0],
+        "compile_cache_dir": sm.cache_dir,
+        "cache_entries_before": sm.cache_entries(),
+        "cpu_count": os.cpu_count(),
+    })
+    out["native_available"] = bool(native_available())
+    check(out["native_available"],
+          "the native host library did not build (g++ missing or "
+          "failing): every host-side stage would run its numpy fallback")
+
+    # Host link: one array each way, and the round trip of an empty call.
+    nbytes = sm.cfg["link_mib"] << 20
+    host = np.ones((nbytes // 4,), np.float32)
+    t0 = time.perf_counter()
+    dev = jax.block_until_ready(jax.device_put(host, d0))
+    h2d = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    back = np.asarray(dev)
+    d2h = time.perf_counter() - t0
+    check(back[-1] == 1.0, "D2H returned wrong data")
+    empty = jax.jit(lambda x: x + 1.0)
+    x = jax.block_until_ready(empty(jax.numpy.zeros((8,), np.float32)))
+    rtts = []
+    for _ in range(50):
+        t0 = time.perf_counter()
+        x = jax.block_until_ready(empty(x))
+        rtts.append(time.perf_counter() - t0)
+    out["host_link"] = {
+        "array_mib": sm.cfg["link_mib"],
+        "h2d_gb_per_s": round(nbytes / h2d / 1e9, 3),
+        "d2h_gb_per_s": round(nbytes / d2h / 1e9, 3),
+        "empty_call_round_trip_ms_median": round(
+            sorted(rtts)[len(rtts) // 2] * 1e3, 4),
+    }
+
+
+# ---------------------------------------------------------------------------
+# Phase 2: the four Pallas kernels, compiled, against jax.numpy
+# ---------------------------------------------------------------------------
+
+def phase_kernels(sm: Smoke, out: dict):
+    import jax
+    import jax.numpy as jnp
+
+    from paddlebox_tpu.embedding import TableConfig
+    from paddlebox_tpu.embedding.lookup import bucket_capacity
+    from paddlebox_tpu.embedding.table import plan_shards, table_widths
+    from paddlebox_tpu.ops.pallas_kernels.flash_attention import (
+        flash_attention, flash_attention_reference)
+    from paddlebox_tpu.ops.pallas_kernels.seqpool_cvm import (
+        seqpool_cvm_pallas)
+    from paddlebox_tpu.ops.pallas_kernels.sorted_gather import (
+        sorted_gather, sorted_stream_layout)
+    from paddlebox_tpu.ops.pallas_kernels.sorted_scatter import (
+        UCAP, sorted_scatter_accumulate)
+    from paddlebox_tpu.ops.seqpool import fused_seqpool_cvm
+    cfg, interp = sm.cfg, sm.rehearsal
+    dim, ke, kw = table_widths(TableConfig(dim=cfg["emb_dim"]))
+    w, pw, aw = dim + 3 + ke + kw, dim + 3, dim + 4
+    n_ids = cfg["batch"] * cfg["slots"]
+    out.update({"interpret": interp, "record_width": w, "pull_width": pw})
+
+    def sparse_pair(tag, n, block):
+        """sorted_gather (exact) and sorted_scatter_accumulate at one
+        (ids, table block) shape; rows past the block are the dropped
+        sentinel both kernels must zero / ignore."""
+        k0, k1, k2 = jax.random.split(jax.random.PRNGKey(block), 3)
+        rows = jax.random.randint(k0, (n,), 0, block + block // 64,
+                                  jnp.int32)
+        max_run = int(sorted_stream_layout(rows, block)[3])
+        rec = out[tag] = {"ids": n, "rows": block, "max_run": max_run}
+        check(max_run <= UCAP,
+              f"{tag}: max_run {max_run} > UCAP {UCAP} — the kernels "
+              f"would take their XLA branch and this check proves nothing")
+        table = jax.random.normal(k1, (block, w), jnp.float32)
+        got = sorted_gather(rows, table, width=pw, interpret=interp)
+        keep = rows < block
+        ref = jnp.where(keep[:, None],
+                        table[jnp.where(keep, rows, 0), :pw], 0.0)
+        g_err = rec["gather_max_err"] = float(jnp.max(jnp.abs(got - ref)))
+        check(g_err == 0.0, f"{tag}: sorted_gather max err {g_err} != 0")
+        del table, got, ref
+        pay = jax.random.normal(k2, (n, aw), jnp.float32)
+        acc = sorted_scatter_accumulate(rows, pay, block, interpret=interp)
+        ref = jnp.zeros((block, aw), jnp.float32).at[
+            jnp.where(keep, rows, block)].add(pay, mode="drop")
+        s_err = rec["scatter_max_err"] = float(jnp.max(jnp.abs(acc - ref)))
+        # f32 sums in another order: a few ulps of the largest cell.
+        s_tol = rec["scatter_tol"] = 1e-5 * float(jnp.max(jnp.abs(ref)))
+        check(s_err <= s_tol,
+              f"{tag}: sorted_scatter max err {s_err} > {s_tol}")
+
+    # One chip: all ids into the whole pass-table block.
+    sparse_pair("sparse_1chip", n_ids, plan_shards(cfg["pass_keys"], 1) + 1)
+    # What each of four chips compiles: its shard's block, serving the
+    # 4 x cap bucket cells its peers send.
+    sparse_pair("sparse_4chip_shard", 4 * bucket_capacity(n_ids // 4, 4),
+                plan_shards(cfg["pass_keys"], 4) + 1)
+
+    # flash attention forward + backward at bench_gpt's shape, default
+    # FLAGS_flash_block_q/k, against the XLA reference at full
+    # precision. Errors are relative to each tensor's largest value. The
+    # kernel's f32 dots may run as bf16 passes on the MXU, exactly as
+    # XLA's own default-precision matmuls do, so the bound is four times
+    # the XLA reference's default-precision error against its
+    # full-precision self, floored at bf16's 2^-8.
+    b, s, h, d = cfg["flash"]
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    q, k, v, wgt = (jax.random.normal(kk, (b, s, h, d), jnp.float32)
+                    for kk in ks)
+
+    def out_and_grads(attn):
+        def loss(q, k, v):
+            o = attn(q, k, v)
+            return jnp.sum(o * wgt), o
+        (_, o), g = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+        return dict(zip(("out", "dq", "dk", "dv"), (o,) + g))
+
+    def xla_ref(q, k, v):
+        return flash_attention_reference(q, k, v, causal=True)
+
+    got = out_and_grads(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, use_pallas=True, interpret=interp))
+    ref_default = out_and_grads(xla_ref)
+    with jax.default_matmul_precision("highest"):
+        ref = out_and_grads(xla_ref)
+
+    def rel(a, b):
+        return float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+
+    fl = out["flash_attention"] = {
+        "shape": [b, s, h, d], "causal": True, "rel_err": {},
+        "xla_default_precision_rel_err": {}, "tol": {}}
+    for name in got:
+        fl["rel_err"][name] = rel(got[name], ref[name])
+        fl["xla_default_precision_rel_err"][name] = rel(ref_default[name],
+                                                        ref[name])
+        fl["tol"][name] = max(
+            2.0 ** -8, 4 * fl["xla_default_precision_rel_err"][name])
+    for name in got:
+        check(bool(jnp.all(jnp.isfinite(got[name]))),
+              f"flash {name} not finite")
+        check(fl["rel_err"][name] <= fl["tol"][name],
+              f"flash {name} rel err {fl['rel_err'][name]} > "
+              f"{fl['tol'][name]}")
+    del got, ref, ref_default
+
+    # seqpool_cvm: reached by no model today (models pool with the XLA
+    # ops.seqpool path); checked here so the kernel is known to run.
+    n, d, rows = cfg["seqpool"]
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    emb = jax.random.normal(ks[0], (n, d), jnp.float32)
+    show = jax.random.uniform(ks[1], (n,), jnp.float32, 1.0, 9.0)
+    click = jax.random.uniform(ks[2], (n,), jnp.float32, 0.0, 1.0)
+    seg = jnp.sort(jax.random.randint(ks[3], (n,), 0, rows + 1, jnp.int32))
+    got = seqpool_cvm_pallas(emb, show, click, seg, rows, use_pallas=True,
+                             interpret=interp)
+    ref = fused_seqpool_cvm(emb, show, click, seg, rows)
+    err = rel(got, ref)
+    out["seqpool_cvm"] = {"shape": [n, d, rows], "rel_err": err,
+                          "note": "reached by no model today"}
+    check(err <= 1e-5, f"seqpool_cvm rel err {err} > 1e-5")
+
+
+# ---------------------------------------------------------------------------
+# Phase 3: DeepFM at full width, as a trainer
+# ---------------------------------------------------------------------------
+
+def deepfm_feed(cfg, batch_size):
+    from paddlebox_tpu.data.slots import DataFeedConfig, SlotConf
+    slots = tuple(SlotConf(f"s{i}", avg_len=1.0)
+                  for i in range(cfg["slots"]))
+    slots += (SlotConf("d", is_dense=True, dim=cfg["dense_dim"]),)
+    return DataFeedConfig(slots=slots, batch_size=batch_size,
+                          slot_capacity_slack=1.0)
+
+
+def deepfm_model(cfg):
+    from paddlebox_tpu.models import DeepFM
+    return DeepFM(slot_names=tuple(f"s{i}" for i in range(cfg["slots"])),
+                  emb_dim=cfg["emb_dim"], dense_dim=cfg["dense_dim"],
+                  hidden=cfg["hidden"])
+
+
+def make_trainer(sm: Smoke, devices):
+    """bench_deepfm's trainer (bench.py:446-467) over ``devices``, its
+    store prepopulated with the resident key set."""
+    import numpy as np
+
+    from paddlebox_tpu.embedding import DeviceFeatureStore, TableConfig
+    from paddlebox_tpu.parallel import HybridTopology, build_mesh
+    from paddlebox_tpu.train import CTRTrainer, TrainerConfig
+    cfg = sm.cfg
+    mesh = build_mesh(HybridTopology(dp=len(devices)), devices=devices)
+    hint = cfg["store_keys"] + cfg["new_keys"]
+    trainer = CTRTrainer(
+        deepfm_model(cfg), deepfm_feed(cfg, cfg["batch"]),
+        TableConfig(dim=cfg["emb_dim"], learning_rate=0.05), mesh=mesh,
+        config=TrainerConfig(auc_num_buckets=1 << 16,
+                             compute_dtype="bfloat16"),
+        store_factory=lambda c: DeviceFeatureStore(
+            c, mesh=mesh, capacity_hint=hint))
+    trainer.init(seed=0)
+    store = trainer.engine.groups[0].engine.store
+    store.ensure_rows(np.arange(1, cfg["store_keys"] + 1, dtype=np.uint64))
+    return trainer, store
+
+
+def load_dataset(feed, files, cls=None):
+    from paddlebox_tpu.data.dataset import Dataset
+    ds = (cls or Dataset)(feed, num_reader_threads=4)
+    ds.set_filelist(files)
+    ds.load_into_memory()
+    return ds
+
+
+def pipelined_dataset_class():
+    """A pass's Dataset that also plays the day loop's preloader
+    (DayRunner._start_preload): before its last batch it feeds the NEXT
+    pass's keys with an async build — the engine split-builds the
+    not-shared rows while this pass still trains — and holds that batch
+    until the early half is published, so end_pass finds it and runs the
+    fused boundary program. A real day loop races here; the smoke must
+    reach the fused program every time."""
+    from paddlebox_tpu.core import monitor
+    from paddlebox_tpu.data.dataset import Dataset
+
+    class PipelinedPass(Dataset):
+        next_keys = None        # [keys per width group] of the next pass;
+        engine = None           # None: a plain Dataset
+
+        def batches_sharded(self, num_shards, **kw):
+            it = super().batches_sharded(num_shards, **kw)
+            if self.next_keys is None:
+                yield from it
+                return
+            prev = next(it)
+            for batch in it:
+                yield prev
+                prev = batch
+            before = monitor.get("pass/split_builds")
+            self.engine.feed_pass(self.next_keys, async_build=True)
+            deadline = time.monotonic() + 300.0
+            while monitor.get("pass/split_builds") == before:
+                check(time.monotonic() < deadline,
+                      "the next pass's split build never published")
+                time.sleep(0.005)
+            yield prev
+
+    return PipelinedPass
+
+
+def phase_deepfm(sm: Smoke, out: dict, tmpdir):
+    import gc
+
+    import numpy as np
+
+    from paddlebox_tpu.core import flags, monitor
+    from paddlebox_tpu.embedding.table import plan_shards
+    cfg, ndev = sm.cfg, len(sm.devices)
+    want_mode = "interpret" if sm.rehearsal else "pallas"
+    rng = np.random.default_rng(0)
+    out.update({
+        "cut": f"resident store {cfg['store_keys']:,} keys (bench: "
+               f"50,000,000); {cfg['batches']} batches per pass (bench: "
+               f"64); widths, batch {cfg['batch']}, pass table "
+               f"{cfg['pass_keys']:,} keys as in bench.py deepfm",
+        "n_devices": ndev})
+
+    # Key sets: pass 2 shares half of pass 1's keys, draws most of the
+    # rest from the resident store and brings some the store never saw.
+    sk, pk, new = cfg["store_keys"], cfg["pass_keys"], cfg["new_keys"]
+    perm = rng.permutation(sk).astype(np.uint64) + np.uint64(1)
+    keys1 = perm[:pk]
+    keys2 = np.concatenate([
+        perm[pk // 2:pk // 2 + pk - new],
+        np.arange(sk + 1, sk + 1 + new, dtype=np.uint64)])
+    hot = perm[pk // 2:pk // 2 + cfg["hot"]]       # in both passes
+    files1 = gen_pass_files(tmpdir, "p1", rng, keys1, cfg, hot)
+    files2 = gen_pass_files(tmpdir, "p2", rng, keys2, cfg, hot)
+    workers = max(1, min(8, (os.cpu_count() or 2) - 1))
+    flags.set_flags({"ingest_workers": workers})
+    out["ingest_workers"] = workers
+    feed = deepfm_feed(cfg, cfg["batch"])
+
+    def run_pass1(devices, label):
+        """A fresh trainer from the same seed trains pass 1 again, from
+        the SAME loaded Dataset: worker processes deliver chunks in
+        arrival order, so a reload would reorder the batches."""
+        flags.resolved_kernels(reset=True)
+        trainer, store = make_trainer(sm, devices)
+        stats = trainer.train_pass(ds1)
+        rec = out[label] = {
+            "loss": float(stats["loss"]), "auc": float(stats["auc"]),
+            "lookup_overflow": int(stats["lookup_overflow"]),
+            "kernel_fallback": int(stats["kernel_fallback"]),
+            "resolved_kernels": flags.resolved_kernels()}
+        check(np.isfinite(rec["loss"]), f"{label}: loss not finite")
+        check(rec["lookup_overflow"] == 0, f"{label}: lookups overflowed")
+        return trainer, store, rec
+
+    # -- the Pallas run: two pipelined passes --------------------------
+    spawned0 = monitor.get("ingest/workers_spawned")
+    flags.resolved_kernels(reset=True)
+    trainer, store = make_trainer(sm, sm.devices)
+    ds1 = load_dataset(feed, files1, pipelined_dataset_class())
+    ds2 = load_dataset(feed, files2)
+    check(monitor.get("ingest/workers_spawned") > spawned0,
+          "the Dataset loads did not run in ingest worker processes")
+    eng = trainer.engine
+    ds1.engine = eng
+    ds1.next_keys = [ds2.pass_keys(slots=g.slots) for g in eng.groups]
+    check(ds1.next_keys[0].size == pk and ds1.num_instances
+          == cfg["batches"] * cfg["batch"], "generated data is short")
+    fused0 = monitor.get("device_store/boundary_fused")
+    early0 = monitor.get("device_store/early_rows")
+    new0 = monitor.get("device_store/new_keys")
+    stats1 = trainer.train_pass(ds1)
+    trainer.reset_metrics()                 # pass 2's AUC is its own
+    stats2 = trainer.train_pass(ds2, feed_keys=False)
+    resolved = out["resolved_kernels"] = flags.resolved_kernels()
+    passes = out["passes"] = []
+    for st in (stats1, stats2):
+        passes.append({
+            "steps": int(st["steps"]), "loss": float(st["loss"]),
+            "auc": float(st["auc"]),
+            "lookup_overflow": int(st["lookup_overflow"]),
+            "kernel_fallback": int(st["kernel_fallback"]),
+            "lookup_exchange_bytes": int(st["lookup_exchange_bytes"]),
+            "boundary": st["boundary"]})
+        check(np.isfinite(st["loss"]), "loss not finite")
+        check(st["steps"] == cfg["batches"], "pass lost batches")
+        check(st["lookup_overflow"] == 0, "sparse lookups overflowed")
+        check(st["kernel_fallback"] == 0,
+              "sorted-stream kernels fell back to XLA at run time")
+        check((st["lookup_exchange_bytes"] > 0) == (ndev > 1),
+              "lookup_exchange_bytes does not match the device count")
+    check(resolved.get("sparse_gather") == [want_mode]
+          and resolved.get("sparse_scatter") == [want_mode],
+          f"sparse kernels resolved to {resolved}, want {want_mode}")
+    out["boundary_fused"] = monitor.get("device_store/boundary_fused") - fused0
+    out["early_rows"] = monitor.get("device_store/early_rows") - early0
+    out["new_keys_inserted"] = monitor.get("device_store/new_keys") - new0
+    check(out["boundary_fused"] == 1,
+          "the fused end/begin boundary program did not run")
+    check(out["early_rows"] == pk - pk // 2,
+          "the split early build did not gather the not-shared rows")
+    check(out["new_keys_inserted"] == new,
+          "the split build did not insert the unseen keys")
+    check(passes[1]["auc"] > cfg["auc_floor"],
+          f"pass-2 AUC {passes[1]['auc']} <= floor {cfg['auc_floor']}: "
+          f"the sparse path is not learning the planted signal")
+    out["auc_floor"] = cfg["auc_floor"]
+
+    # Placement: the resident store and a pass table, shard by shard.
+    eng.feed_pass(ds1.next_keys, readonly=True)
+    table = eng.begin_pass()[0]
+    block = plan_shards(pk, ndev) + 1
+    check(table.vals.shape[0] == ndev * block,
+          f"pass table has {table.vals.shape[0]} rows, not the "
+          f"{ndev} x {block} the AOT checks pin")
+    store_devs = {s.device for s in store._parts[0].addressable_shards}
+    table_devs = {s.device for s in table.vals.addressable_shards}
+    in_use = sm.memory("bytes_in_use")
+    eng.abort_pass()
+    out["pass_table_rows_per_device"] = block
+    out["store_shard_devices"] = len(store_devs)
+    out["table_shard_devices"] = len(table_devs)
+    out["bytes_in_use"] = in_use
+    check(len(store_devs) == ndev and len(table_devs) == ndev,
+          "store / pass table shards do not cover every device")
+    if all(b is not None for b in in_use):
+        check(min(in_use) > 0 and max(in_use) <= 2 * min(in_use),
+              f"per-device bytes_in_use uneven: {in_use}")
+    export_dir = os.path.join(tmpdir, "export")
+    out["export"] = {k: v for k, v in trainer.export_serving(
+        export_dir).items() if k == "features"}
+    # Free the trainer's device memory (its store alone is gigabytes)
+    # before the next one is built.
+    ds1.next_keys = ds1.engine = None       # from here a plain Dataset
+    del trainer, store, eng, table, ds2, stats1, stats2
+    gc.collect()
+
+    # -- in-situ kernel check: XLA path, twice -------------------------
+    kernel_flags = flags.get_flags(["sparse_gather_kernel",
+                                    "sparse_scatter_kernel"])
+    flags.set_flags({"sparse_gather_kernel": "xla",
+                     "sparse_scatter_kernel": "xla"})
+    recs = []
+    for label in ("xla_run_a", "xla_run_b"):
+        t, s, rec = run_pass1(sm.devices, label)
+        check(rec["resolved_kernels"].get("sparse_gather") == ["xla"]
+              and rec["resolved_kernels"].get("sparse_scatter") == ["xla"],
+              f"{label}: did not run the XLA path")
+        recs.append(rec)
+        del t, s
+        gc.collect()
+    flags.set_flags(kernel_flags)
+    # Tolerance: four times XLA's difference with itself, floored at
+    # bf16's 2^-8 (the tower computes in bf16) relative for the mean
+    # loss and absolute for AUC.
+    eps = 2.0 ** -8
+    tol_loss = max(4 * abs(recs[0]["loss"] - recs[1]["loss"]),
+                   eps * abs(recs[0]["loss"]))
+    tol_auc = max(4 * abs(recs[0]["auc"] - recs[1]["auc"]), eps)
+    d_loss = abs(passes[0]["loss"] - recs[0]["loss"])
+    d_auc = abs(passes[0]["auc"] - recs[0]["auc"])
+    out["pallas_vs_xla"] = {
+        "pass1_loss_diff": d_loss, "tol_loss": tol_loss,
+        "pass1_auc_diff": d_auc, "tol_auc": tol_auc,
+        "xla_self_loss_diff": abs(recs[0]["loss"] - recs[1]["loss"]),
+        "xla_self_auc_diff": abs(recs[0]["auc"] - recs[1]["auc"])}
+    check(d_loss <= tol_loss and d_auc <= tol_auc,
+          f"Pallas and XLA kernel paths disagree: {out['pallas_vs_xla']}")
+
+    if ndev > 1:
+        # The same pass on ONE chip of this host: sharding the table
+        # must not change what is learned.
+        t, s, rec = run_pass1(sm.devices[:1], "one_chip_reference")
+        del t, s
+        gc.collect()
+        d_loss = abs(passes[0]["loss"] - rec["loss"])
+        d_auc = abs(passes[0]["auc"] - rec["auc"])
+        out["sharded_vs_one_chip"] = {
+            "pass1_loss_diff": d_loss, "tol_loss": tol_loss,
+            "pass1_auc_diff": d_auc, "tol_auc": tol_auc}
+        check(d_loss <= tol_loss and d_auc <= tol_auc,
+              f"dp={ndev} and one-chip runs disagree: "
+              f"{out['sharded_vs_one_chip']}")
+    return export_dir, keys2, hot
+
+
+# ---------------------------------------------------------------------------
+# Phase 4: the predict tier answers requests
+# ---------------------------------------------------------------------------
+
+def phase_predict(sm: Smoke, out: dict, export_dir, keys, hot):
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddlebox_tpu.data.parser import parse_lines
+    from paddlebox_tpu.metrics import (auc_accumulate, auc_compute,
+                                       auc_state_init)
+    from paddlebox_tpu.serving.batcher import pack_bucketed
+    from paddlebox_tpu.serving.predictor import load_serving_predictor
+    from paddlebox_tpu.serving.service import PredictClient, PredictServer
+    cfg = sm.cfg
+    feed = deepfm_feed(cfg, 64)
+    rng = np.random.default_rng(4)
+    pred = load_serving_predictor(deepfm_model(cfg), feed, export_dir)
+    server = PredictServer("127.0.0.1:0", pred)
+    client = PredictClient(server.endpoint)
+    try:
+        sizes = [1, 2, 3, 8, 17, 31, 33, 64] + rng.integers(
+            1, 65, cfg["serve_requests"] - 8).tolist()
+        probs, labels = [], []
+        for n in sizes:
+            ids = rng.choice(keys, (n, cfg["slots"] - 1))
+            lines, y = gen_lines(rng, ids, cfg, hot)
+            got = np.asarray(client.predict(lines))
+            want = np.asarray(pred.predict(pack_bucketed(
+                parse_lines(lines, feed), feed))[:n])
+            check(got.shape == (n,), f"reply shape {got.shape} for {n} rows")
+            check(np.all(np.isfinite(got)) and np.all((got > 0) & (got < 1)),
+                  "reply not a finite probability")
+            check(np.array_equal(got, want),
+                  "wire reply differs from the predictor's direct predict")
+            probs.append(got)
+            labels.append(y)
+        # The repo's own AUC (metrics/auc.py), as the trainer computes it.
+        auc = float(auc_compute(auc_accumulate(
+            auc_state_init(1 << 16), jnp.asarray(np.concatenate(probs)),
+            jnp.asarray(np.concatenate(labels), jnp.float32)))["auc"])
+        stats = client.stats()
+    finally:
+        client.close()
+        server.stop()
+        pred.close()
+    out.update({"requests": len(sizes), "rows": int(sum(sizes)),
+                "row_counts": f"{min(sizes)}..{max(sizes)}",
+                "bit_identical_to_direct_predict": True, "auc": auc,
+                "auc_floor": cfg["auc_floor"],
+                "server_batches": stats.get("batches")})
+    check(auc > cfg["auc_floor"],
+          f"served AUC {auc} <= floor {cfg['auc_floor']}")
+
+
+# ---------------------------------------------------------------------------
+# Phase 5: one dense model, two steps
+# ---------------------------------------------------------------------------
+
+def phase_gpt(sm: Smoke, out: dict):
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    import optax
+
+    from paddlebox_tpu.core import flags
+    from paddlebox_tpu.models.gpt import (GPTConfig, gpt_loss_fn, init_gpt,
+                                          make_gpt_train_step)
+    from paddlebox_tpu.parallel import HybridTopology, build_mesh
+    ndev = len(sm.devices)
+    cfg = GPTConfig(**sm.cfg["gpt"])
+    mesh = build_mesh(HybridTopology(dp=ndev))
+    params, specs = init_gpt(jax.random.PRNGKey(0), cfg, pp_stages=1)
+    opt = optax.adafactor(1e-3)
+    opt_state = opt.init(params)
+    bs, seq = sm.cfg["gpt_batch_per_chip"] * ndev, cfg.max_seq_len
+    rng = np.random.default_rng(0)
+    tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, (bs, seq)),
+                         jnp.int32)
+    targets = jnp.asarray(rng.integers(0, cfg.vocab_size, (bs, seq)),
+                          jnp.int32)
+
+    # The reference first (the step donates params): the same model's
+    # loss with ring attention — the XLA path at sp=1. Forward only: the
+    # loss a step reports is its forward's, and the ring backward saves
+    # [B, S, H, S] f32 scores per layer.
+    out.update({"config": sm.cfg["gpt"], "cut": sm.cfg["gpt_cut"],
+                "batch": bs, "seq": seq,
+                "optimizer": "adafactor",
+                "n_params": sum(int(np.prod(p.shape)) for p in
+                                jax.tree_util.tree_leaves(params))})
+    flags.resolved_kernels(reset=True)
+    ring = dataclasses.replace(cfg, attention="ring")
+    loss_ring = out["ring_reference_loss"] = float(jax.jit(gpt_loss_fn(
+        ring, mesh, specs))(params, tokens, targets))
+    check(flags.resolved_kernels(reset=True).get("gpt_attention")
+          == ["ring"], "the reference did not take ring attention")
+
+    step = make_gpt_train_step(cfg, mesh, specs, opt, num_microbatches=1)
+    losses = out["losses"] = []
+    for _ in range(2):
+        params, opt_state, loss = step(params, opt_state, tokens, targets)
+        losses.append(float(loss))
+    resolved = out["resolved_kernels"] = flags.resolved_kernels()
+    if sm.rehearsal:
+        want = {"gpt_attention": ["ring"]}       # 'auto' off the TPU
+    else:
+        want = {"gpt_attention": ["flash"], "flash_attention": ["pallas"]}
+    check(all(resolved.get(k) == v for k, v in want.items()),
+          f"attention resolved to {resolved}, want {want}")
+    check(all(np.isfinite(losses)), f"GPT losses not finite: {losses}")
+    check(losses[1] < losses[0],
+          f"GPT loss did not fall on the repeated batch: {losses}")
+    # bf16-pass matmuls on both sides: 2^-8 of the loss.
+    tol = out["tol"] = 2.0 ** -8 * abs(loss_ring)
+    check(abs(losses[0] - loss_ring) <= tol,
+          f"flash step-1 loss {losses[0]} vs ring {loss_ring}: > {tol}")
+
+
+# ---------------------------------------------------------------------------
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--rehearse-cpu", action="store_true",
+                    help="toy sizes on 2 virtual CPU devices, Pallas "
+                         "kernels interpreted; the report says so")
+    ap.add_argument("--out", default=os.path.join(HERE, "chiprun_out"),
+                    help="directory for the JSON report")
+    args = ap.parse_args(argv)
+
+    from paddlebox_tpu.core import flags
+    cache_dir = None
+    if args.rehearse_cpu:
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "")
+            + " --xla_force_host_platform_device_count=2").strip()
+    else:
+        cache_dir = flags.compilation_cache_dir()
+    import jax
+    dev = jax.devices()
+    if not args.rehearse_cpu and dev[0].platform != "tpu":
+        print(f"chip_smoke: needs a TPU, jax found {dev[0].platform!r} "
+              f"({dev[0].device_kind}); nothing was run", file=sys.stderr)
+        return 2
+    if args.rehearse_cpu:
+        flags.set_flags({"sparse_gather_kernel": "interpret",
+                         "sparse_scatter_kernel": "interpret"})
+
+    sm = Smoke(args.rehearse_cpu, args.out, cache_dir)
+    t0 = time.perf_counter()
+    try:
+        with tempfile.TemporaryDirectory(prefix="chip_smoke_") as tmpdir:
+            sm.phase("identity", lambda rec: phase_identity(sm, rec))
+            sm.phase("kernels", lambda rec: phase_kernels(sm, rec))
+            held = []
+            sm.phase("deepfm", lambda rec: held.extend(
+                phase_deepfm(sm, rec, tmpdir)))
+            sm.phase("predict", lambda rec: phase_predict(sm, rec, *held))
+            sm.phase("gpt", lambda rec: phase_gpt(sm, rec))
+        sm.report["facts"] = {
+            "total_seconds": round(time.perf_counter() - t0, 1),
+            "compile_seconds": round(sum(
+                p["compile_seconds"] for p in sm.report["phases"].values()),
+                1),
+            "cache_hits": sum(p["cache_hits"]
+                              for p in sm.report["phases"].values()),
+            "cache_misses": sum(p["cache_misses"]
+                                for p in sm.report["phases"].values()),
+            "cache_entries_after": sm.cache_entries(),
+        }
+        sm.report["ok"] = True
+    finally:
+        path = sm.write_report()
+        print(f"[chip_smoke] report: {path}", file=sys.stderr, flush=True)
+    print(json.dumps(sm.report, default=str))
+    print(json.dumps({"ok": True, "device": {
+        "platform": dev[0].platform, "kind": dev[0].device_kind,
+        "count": len(dev)}}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -21,32 +21,6 @@ re-designed TPU-first rather than ported:
 See SURVEY.md at the repo root for the full structural map of the reference.
 """
 
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    # jax < 0.5 ships shard_map under jax.experimental with the
-    # replication check named check_rep; the codebase targets the
-    # public jax.shard_map(check_vma=...) spelling. Adapt once here —
-    # every module (and the tests) imports this package first.
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    def _shard_map_compat(f, *, mesh, in_specs, out_specs,
-                          check_vma=True, **kw):
-        return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=check_vma,
-                               **kw)
-
-    _jax.shard_map = _shard_map_compat
-
-if not hasattr(_jax.lax, "axis_size"):
-    # jax < 0.4.38 has no lax.axis_size; psum of a python 1 folds to the
-    # static axis size at trace time (tuples of names included), which
-    # is exactly axis_size's contract inside shard_map bodies.
-    def _axis_size_compat(axis_name):
-        return _jax.lax.psum(1, axis_name)
-
-    _jax.lax.axis_size = _axis_size_compat
-
 from paddlebox_tpu.version import __version__
 
 # Core runtime (role of paddle/fluid/platform: flags, monitor, timers).

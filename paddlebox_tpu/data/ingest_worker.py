@@ -26,15 +26,29 @@ through ``Dataset._reader_errors``.
 
 from __future__ import annotations
 
+import os
 import queue
+import sys
 
 from paddlebox_tpu.data import shm_channel
 from paddlebox_tpu.data.slots import DataFeedConfig
 
 
+def _pin_cpu() -> None:
+    """One process per chip: the parent may hold the accelerator, and a
+    second process that initialises it fails or hangs. Nothing on the
+    parse path imports jax (tests/test_ingest.py pins that); this covers
+    a parent main module, re-imported here by spawn, that does."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax = sys.modules.get("jax")
+    if jax is not None:
+        jax.config.update("jax_platforms", "cpu")
+
+
 def worker_main(worker_id: int, parent_pid: int, load_id: int, task_q,
                 msg_q, config: DataFeedConfig) -> None:
     """Process entry point (spawn-safe: module-level, picklable args)."""
+    _pin_cpu()
     # Imported here, not at module top: the spawn child pays the package
     # import either way, but keeping the entry's import surface explicit
     # documents what the worker actually needs.

@@ -268,6 +268,19 @@ def compute_bucketing(table: PassTable, dev_rows: jax.Array,
     return bk + (cap, recv_rows, _stream_layout_for(recv_rows, block))
 
 
+def kernel_fallback(bucketing: Optional[Tuple]) -> jax.Array:
+    """int32 scalar, 1 when this step's shared sorted-stream layout
+    tripped the kernels' skew guard — a block's run of requests exceeded
+    the per-block budget (``max_run > UCAP``), so the pull gather and
+    push scatter consuming the layout took their XLA branch at run time
+    — else 0 (also 0 when no kernel layout is in play). The trainer sums
+    it into the pass stats next to ``lookup_overflow``."""
+    if bucketing is None or len(bucketing) != 6 or bucketing[5] is None:
+        return jnp.zeros((), jnp.int32)
+    from paddlebox_tpu.ops.pallas_kernels.sorted_scatter import UCAP
+    return (bucketing[5][3] > UCAP).astype(jnp.int32)
+
+
 def exchange_bytes(table: PassTable, n: int,
                    cap: Optional[int] = None) -> int:
     """Static per-device all-to-all bytes for one pull+push round over
@@ -332,9 +345,12 @@ def _gather_rows(vals: jax.Array, rows: jax.Array, width: int, block: int,
     the shared sorted-stream layout from compute_bucketing (one argsort
     serves this gather and the push scatter)."""
     mode = _kernel_mode("sparse_gather_kernel")
-    if mode is None or vals.shape[-1] > 128:
-        # Fused records wider than one 128-lane tile cannot stream
-        # through the kernel's VMEM blocks — serve them with XLA.
+    # Fused records wider than one 128-lane tile cannot stream through
+    # the kernel's VMEM blocks — serve them with XLA, and say so.
+    wide = mode is not None and vals.shape[-1] > 128
+    flags.note_kernel("sparse_gather",
+                      "xla:width>128" if wide else mode or "xla")
+    if mode is None or wide:
         return vals[rows, :width]
     from paddlebox_tpu.ops.pallas_kernels.sorted_gather import sorted_gather
     trash = block - 1
@@ -456,6 +472,7 @@ def _accumulate(rows: jax.Array, payload: jax.Array, block: int,
     shared sorted-stream layout from compute_bucketing (one argsort
     serves this scatter and the pull gather)."""
     mode = _kernel_mode("sparse_scatter_kernel")
+    flags.note_kernel("sparse_scatter", mode or "xla")
     if mode is None:
         acc = jnp.zeros((block, payload.shape[-1]), payload.dtype)
         return acc.at[rows].add(payload)

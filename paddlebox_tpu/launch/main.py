@@ -8,8 +8,10 @@ fault-tolerant restart, ``fleet/elastic/manager.py``).
 
 TPU-first: one process per HOST (jax owns all local chips), env contract
 ``PBX_COORDINATOR/PBX_NUM_PROCESSES/PBX_PROCESS_ID`` consumed by
-``paddlebox_tpu.distributed.initialize``. ``--nproc`` spawns N local
-processes (useful with forced host-platform device counts for tests).
+``paddlebox_tpu.distributed.initialize``. ``--nproc N`` spawns N local
+processes for CPU runs with forced host-platform device counts (tests);
+it is refused unless ``JAX_PLATFORMS=cpu``, because every local child
+would claim every local chip and a chip belongs to one process.
 """
 
 from __future__ import annotations
@@ -189,6 +191,14 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("script", help="training script")
     ap.add_argument("script_args", nargs=argparse.REMAINDER)
     args = ap.parse_args(argv)
+
+    if args.nproc > 1 and os.environ.get(
+            "JAX_PLATFORMS", "").lower() != "cpu":
+        ap.error("--nproc > 1 needs JAX_PLATFORMS=cpu: each local child "
+                 "would initialise every local accelerator chip, and a "
+                 "chip belongs to one process (the second child fails or "
+                 "hangs). On a TPU host run one process; it drives all "
+                 "local chips")
 
     if args.elastic_dir:
         return run_elastic(args)

@@ -30,6 +30,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
+from paddlebox_tpu.core import flags
 from paddlebox_tpu.parallel import pp as pplib
 from paddlebox_tpu.parallel import sp as splib
 from paddlebox_tpu.parallel import tp as tplib
@@ -150,6 +151,7 @@ def _block(p, x, cfg: GPTConfig, heads_local: int):
     use_flash = cfg.attention == "flash" or (
         cfg.attention == "auto" and sp_n == 1
         and jax.default_backend() == "tpu")
+    flags.note_kernel("gpt_attention", "flash" if use_flash else "ring")
     if use_flash:
         from paddlebox_tpu.ops.pallas_kernels import flash_attention
         attn = flash_attention(q, k, v, causal=True)

@@ -417,6 +417,8 @@ def flash_attention(q, k, v, *, causal: bool = False,
         scale = float(q.shape[-1]) ** -0.5
     if use_pallas is None:
         use_pallas = interpret or _flags.pallas_kernels_enabled()
+    _flags.note_kernel("flash_attention", "interpret" if interpret
+                       else "pallas" if use_pallas else "xla")
     if not use_pallas:
         return flash_attention_reference(q, k, v, causal=causal,
                                          scale=scale, q_offset=q_offset,

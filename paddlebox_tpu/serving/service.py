@@ -72,7 +72,7 @@ class PredictServer(rpc.FramedRPCServer):
             self.metrics, label=f"replica:{self.replica_id}")
         # SLO layer: server-side predict latency quantile digest (the
         # log-bucketed sketch — sub-ms CPU predicts and multi-second
-        # tunnel stalls both land within 1% relative error) + the
+        # stalls both land within 1% relative error) + the
         # rotating window snapshots behind the throughput gauge. The
         # digest is per-replica state; the registry copy under
         # serving/predict_ms merges across replicas via

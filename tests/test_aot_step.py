@@ -1,8 +1,11 @@
-"""The full CTR train/eval step must compile through the real XLA:TPU +
-Mosaic pipeline (compile-only PJRT topology) — program-level insurance
-the per-kernel AOT tests can't give (shard_map + donation + Pallas
-custom-call interactions). Runs tools/aot_check_step.py in a subprocess
-because it re-pins platforms at import time."""
+"""Everything the chip compiles must compile through the real XLA:TPU +
+Mosaic pipeline (compile-only PJRT topology): each Pallas kernel at its
+bench shapes, and the full CTR train/eval step — program-level insurance
+the per-kernel checks can't give (shard_map + donation + Pallas
+custom-call interactions). Each check is a tools/aot_check_*.py run in
+its own subprocess, one at a time: libtpu admits one process at a time,
+so no test here (or anywhere in the suite) loads it in the pytest
+process."""
 
 import os
 import subprocess
@@ -18,13 +21,25 @@ def _run_tool(name, timeout, *args):
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", name), *args],
         env=env, cwd=REPO, capture_output=True, text=True, timeout=timeout)
-    # The tools print this sentinel (and exit cleanly) when libtpu's AOT
-    # topology cannot initialize, whatever the underlying error text —
-    # substring-matching a specific jax message would rot.
-    if "TPU-AOT-TOPOLOGY-UNAVAILABLE" in proc.stdout:
-        pytest.skip("no TPU AOT topology available")
+    # The tools print this sentinel (and exit cleanly) only where libtpu
+    # is not installed. Any other failure to reach the compile-only
+    # topology — the multi-process lockfile included — is a failure.
+    if "TPU-AOT-NO-LIBTPU" in proc.stdout:
+        pytest.skip("libtpu is not installed")
     assert proc.returncode == 0, proc.stderr[-3000:]
     return proc.stdout
+
+
+@pytest.mark.slow
+def test_pallas_kernels_aot_compile_for_tpu():
+    """sorted_scatter, sorted_gather, flash_attention fwd+bwd and
+    seqpool_cvm at the shapes the benchmarks use."""
+    out = _run_tool("aot_check_kernels.py", 900)
+    assert out.count("AOT sorted_scatter") == 3
+    assert out.count("AOT sorted_gather") == 3
+    assert "AOT flash_attention fwd+bwd" in out
+    assert "AOT seqpool_cvm" in out
+    assert "PALLAS KERNELS TPU AOT COMPILE: OK" in out
 
 
 @pytest.mark.slow

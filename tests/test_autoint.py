@@ -13,6 +13,7 @@ from paddlebox_tpu.train import CTRTrainer, TrainerConfig
 SLOTS = ("a", "b")
 
 
+@pytest.mark.slow  # 6 s learn loop; the numpy-oracle parity test stays tier-1; tier-1 keeps its 870 s window (PR 21)
 def test_autoint_learns_interaction(tmp_path):
     mesh = build_mesh(HybridTopology(dp=8))
     feed = DataFeedConfig(

@@ -30,6 +30,7 @@ import sys
 import time
 
 import numpy as np
+import pytest
 
 from paddlebox_tpu.checkpoint.protocol import CheckpointProtocol
 from paddlebox_tpu.core import flags as flagmod
@@ -91,6 +92,7 @@ def _wait_healthy(router, want, timeout=180.0):
         f"fleet never reached {want} healthy: {router.fleet.replicas()}")
 
 
+@pytest.mark.slow  # 20 s multi-process chaos soak; tier-1 keeps its 870 s window
 def test_autopilot_chaos_soak_drill(tmp_path):
     # Replicated shard tier, populated with the deterministic model.
     cfg = TableConfig(name="emb", dim=DIM, learning_rate=0.1)

@@ -385,6 +385,7 @@ def test_chunked_copy_digest_identical(chunk):
         stop_shards(servers + [joiner])
 
 
+@pytest.mark.slow  # multi-process kill -9 drill; tier-1 keeps its 870 s window
 def test_kill9_between_chunk_windows_recovers(tmp_path):
     """SIGKILL between two chunk windows of one COPY segment (some
     windows applied, source not yet dropped): recovery through the

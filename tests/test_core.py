@@ -44,6 +44,39 @@ def test_builtin_flags_present():
     assert vals["check_nan_inf"] is False
 
 
+def test_resolved_kernels_records_what_ran():
+    flags.resolved_kernels(reset=True)
+    flags.note_kernel("site_a", "xla")
+    flags.note_kernel("site_a", "pallas")
+    flags.note_kernel("site_a", "xla")
+    flags.note_kernel("site_b", "xla:width>128")
+    assert flags.resolved_kernels(reset=True) == {
+        "site_a": ["pallas", "xla"], "site_b": ["xla:width>128"]}
+    assert flags.resolved_kernels() == {}
+
+
+def test_compilation_cache_dir_rule(monkeypatch):
+    """The environment's directory wins and nothing else is set; unset,
+    the cache is one fixed path inside the checkout."""
+    import jax
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    assert flags.compilation_cache_dir() == "/some/dir"
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        d = flags.compilation_cache_dir()
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert d == os.path.join(repo, ".jax_cache")
+        assert os.environ["JAX_COMPILATION_CACHE_DIR"] == d
+        assert jax.config.jax_compilation_cache_dir == d
+        assert flags.compilation_cache_dir() == d       # and stays put
+    finally:
+        # Tests keep the persistent cache off (CPU executables).
+        jax.config.update("jax_compilation_cache_dir", before)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+
+
 def test_monitor_counters():
     monitor.reset()
     monitor.add("ins_num", 100)

@@ -32,6 +32,7 @@ def test_build_rank_offset_respects_valid_and_cap():
     assert ro[5, 0] == 0                 # beyond max_rank positions drop
 
 
+@pytest.mark.slow  # 4 s learn loop; the op-level tests stay tier-1; tier-1 keeps its 870 s window (PR 21)
 def test_din_rank_learns_peer_signal():
     """Label = 1 iff the instance's OWN feature is weaker than its pv
     peer's — only visible through rank attention."""

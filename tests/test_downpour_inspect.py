@@ -33,6 +33,7 @@ def _make_batches(n_batches, cap=32, seed=0):
         yield {"ids": ids, "label": jnp.asarray([label])}
 
 
+@pytest.mark.slow  # 10 s; a trainer no benchmark config runs (ROADMAP D5); tier-1 keeps its 870 s window (PR 21)
 def test_downpour_learns_sparse_and_dense(ps):
     def loss_fn(dense, emb, w, batch):
         # score = mean(emb @ v) + sum(w)/cap + b

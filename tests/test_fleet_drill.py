@@ -15,6 +15,7 @@ import threading
 import time
 
 import numpy as np
+import pytest
 
 from paddlebox_tpu.core import flags as flagmod
 from paddlebox_tpu.embedding.table import TableConfig
@@ -63,6 +64,7 @@ def _wait_healthy(router, want, timeout=120.0):
         f"{router.fleet.replicas()}")
 
 
+@pytest.mark.slow  # multi-process kill -9 drill; tier-1 keeps its 870 s window
 def test_fleet_kill9_and_join_drill(tmp_path):
     # Shared shard tier, populated with a deterministic trained-model
     # stand-in every replica resolves against.

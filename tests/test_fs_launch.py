@@ -124,6 +124,19 @@ def test_hadoop_fs_error_surfaces(fake_hdfs):
 # elastic launch
 # ---------------------------------------------------------------------------
 
+def test_launch_refuses_local_procs_off_cpu(tmp_path, monkeypatch):
+    """A chip belongs to one process: --nproc > 1 is refused unless the
+    children are pinned to CPU."""
+    from paddlebox_tpu.launch.main import main
+    script = tmp_path / "worker.py"
+    script.write_text("raise SystemExit(7)\n")
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    with pytest.raises(SystemExit) as e:
+        main(["--nproc", "2", str(script)])
+    assert e.value.code == 2
+    assert main(["--nproc", "1", str(script)]) == 7
+
+
 def test_launch_elastic_single_host(tmp_path):
     """Elastic mode end-to-end on one host: ranks come from the lease
     table; the worker script records its env and exits."""

@@ -182,6 +182,28 @@ def _sorted_rows(ds):
             m.sparse_ids["item"], m.dense["dense0"]]
 
 
+def test_ingest_worker_never_reaches_a_device():
+    """One process per chip: a worker spawned while the parent holds the
+    accelerator must not initialise a backend. The parse path imports no
+    jax at all, and worker_main pins the platform to CPU for the case
+    where spawn re-imports a parent main module that does."""
+    import subprocess
+    import sys
+    code = (
+        "import sys\n"
+        "import paddlebox_tpu.data.ingest_worker as w\n"
+        "from paddlebox_tpu.data.dataset import _parse_block, _read_blocks\n"
+        "assert 'jax' not in sys.modules, 'parse path imports jax'\n"
+        "import jax\n"
+        "w._pin_cpu()\n"
+        "assert jax.config.jax_platforms == 'cpu'\n"
+        "assert jax.default_backend() == 'cpu'\n")
+    env = dict(os.environ, JAX_PLATFORMS="tpu")
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+
+
 def test_mp_ingest_worker_error_surfaces(tmp_path):
     files = _write_files(tmp_path, n_files=2)
     cfg = DataFeedConfig(slots=CFG.slots, batch_size=4,

@@ -210,6 +210,7 @@ def test_eval_pass_megastep_matches_k1(shard_13):
     np.testing.assert_allclose(e1["loss"], e4["loss"], rtol=1e-6)
 
 
+@pytest.mark.slow  # 7 s; auto-capacity is off by default and test_auto_capacity.py stays tier-1; tier-1 keeps its 870 s window (PR 21)
 def test_auto_capacity_ratchet_with_megastep(tmp_path):
     """Auto-capacity under K=4: pass 1 measures caps from the first
     STACKED block (before the scanned fn is built); a second pass over

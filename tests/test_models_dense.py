@@ -21,6 +21,7 @@ from paddlebox_tpu.parallel import HybridTopology, build_mesh
 
 # -- ResNet ------------------------------------------------------------------
 
+@pytest.mark.slow  # 20 s conv compile; tier-1 keeps its 870 s window (PR 21)
 def test_resnet18_forward_and_train_step():
     model = ResNet(depth=18, num_classes=10, width=8)
     params = model.init(jax.random.PRNGKey(0))
@@ -38,6 +39,7 @@ def test_resnet18_forward_and_train_step():
                                   np.asarray(params["stem_bn"]["mean"]))
 
 
+@pytest.mark.slow  # 10 s conv compile; tier-1 keeps its 870 s window (PR 21)
 def test_resnet50_shapes():
     model = ResNet(depth=50, num_classes=10, width=8)
     params = model.init(jax.random.PRNGKey(0))
@@ -83,6 +85,7 @@ BCFG = BertConfig(vocab_size=100, d_model=32, n_heads=4, n_layers=2,
                   d_ff=64, max_seq_len=32)
 
 
+@pytest.mark.slow  # 17 s compile-bound; tier-1 keeps its 870 s window
 def test_bert_mlm_dp_parity(devices8):
     """dp-sharded MLM loss == single-device loss (role of the reference's
     dist parity tests, test_dist_base.py)."""

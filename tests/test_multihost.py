@@ -359,6 +359,7 @@ def test_reshard_failure_rolls_back_and_retries(cluster2, tmp_path):
         joiner.stop()
 
 
+@pytest.mark.slow  # 12 s multi-process kill -9 drill; tier-1 keeps its 870 s window (PR 21)
 def test_kill9_mid_reshard_recovers_via_recovery_chain(tmp_path):
     """Subprocess drill: SIGKILL inside the reshard COPY phase, then a
     fresh cluster recovers through recovery_chain() — the content

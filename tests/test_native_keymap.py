@@ -84,6 +84,7 @@ def test_keymap_empty_batch():
     km.close()
 
 
+@pytest.mark.slow  # asserts a CPU wall-clock ratio: flakes under load
 @needs_native
 def test_native_faster_than_numpy_on_large_batch():
     """Smoke perf: native path should beat np.searchsorted on a realistic
@@ -109,6 +110,7 @@ def test_native_faster_than_numpy_on_large_batch():
     assert t_native < t_numpy * 1.0, (t_native, t_numpy)
 
 
+@pytest.mark.slow  # asserts a CPU wall-clock ratio: flakes under load
 @needs_native
 def test_native_dedup_perf_smoke():
     """dedup_keys picks native only with >=4 cores; either way the result

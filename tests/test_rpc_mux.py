@@ -360,6 +360,7 @@ def _spawn_echo(root, name):
     raise RuntimeError(f"echo worker {name} never advertised")
 
 
+@pytest.mark.slow  # 8 s multi-process kill -9 drill; tier-1 keeps its 870 s window (PR 21)
 def test_mux_kill9_idempotent_retry_and_resolve_failover(
         tmp_path, flag_reset):
     """kill -9 the server while mux calls are provably in flight: the

@@ -27,6 +27,7 @@ import threading
 import time
 
 import numpy as np
+import pytest
 
 from paddlebox_tpu.embedding.store import _FIELDS
 from paddlebox_tpu.embedding.table import TableConfig
@@ -109,6 +110,7 @@ def _digest(arrs) -> str:
     return h.hexdigest()
 
 
+@pytest.mark.slow  # multi-process kill -9 drill; tier-1 keeps its 870 s window
 def test_shard_host_kill9_under_train_and_predict_traffic(tmp_path):
     data = str(tmp_path / "data")
     _write_day(data, rows_per_split=96)

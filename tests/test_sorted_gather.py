@@ -1,6 +1,6 @@
 """sorted_gather (CopyForPull-class Pallas kernel) vs the XLA gather
 reference — interpret mode on CPU; the same code compiles for TPU
-(Mosaic AOT check in tests/test_pallas_aot.py). Covers the ISSUE's
+(Mosaic AOT check in tools/aot_check_kernels.py). Covers the ISSUE's
 parity matrix: uniform keys, skewed/hot-row fallback, trash rows, empty
 blocks, widths 8/16/40, non-BLOCK-multiple row counts (the production
 pow2+trash shape), the shared pull+push sort layout, and the lookup
@@ -68,6 +68,26 @@ def test_hot_row_falls_back_to_xla_gather():
     got = sorted_gather(jnp.asarray(rows), jnp.asarray(table), width=16,
                         interpret=True)
     np.testing.assert_array_equal(np.asarray(got), _ref(rows, table, 16))
+
+
+def test_hot_row_give_way_is_counted():
+    """The run-time give-way above is observable: lookup.kernel_fallback
+    reads the shared layout's max_run, so the trainer can sum it into
+    the pass stats (``kernel_fallback``) next to ``lookup_overflow``."""
+    from paddlebox_tpu.embedding.lookup import kernel_fallback
+    from paddlebox_tpu.ops.pallas_kernels.sorted_gather import (
+        sorted_stream_layout)
+    num_rows = 2 * BLOCK
+    hot = jnp.full((UCAP + 1,), 7, jnp.int32)
+    spread = jnp.arange(UCAP + 1, dtype=jnp.int32) * 4 % num_rows
+
+    def shared(rows):        # compute_bucketing's one-shard tuple
+        return (None, None, None, None, rows,
+                sorted_stream_layout(rows, num_rows))
+
+    assert int(kernel_fallback(shared(hot))) == 1
+    assert int(kernel_fallback(shared(spread))) == 0
+    assert int(kernel_fallback(None)) == 0      # no kernel layout in play
 
 
 def test_empty_blocks_and_tail_rows():

@@ -119,6 +119,7 @@ def test_heter_trainer_factory():
 # PipelineTrainer
 # ---------------------------------------------------------------------------
 
+@pytest.mark.slow  # 4 s; a trainer no benchmark config runs (ROADMAP D5); tier-1 keeps its 870 s window (PR 21)
 def test_pipeline_trainer_learns(devices8):
     mesh = build_mesh(HybridTopology(pp=8))
     rng = np.random.default_rng(0)

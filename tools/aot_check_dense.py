@@ -27,12 +27,11 @@ jax.config.update("jax_platforms", "cpu")
 
 import jax.numpy as jnp  # noqa: E402
 import optax  # noqa: E402
-from jax.experimental import topologies  # noqa: E402
 from jax.sharding import Mesh, NamedSharding  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
 
-from tools._aot_common import sds  # noqa: E402
+from tools._aot_common import sds, tpu_topology  # noqa: E402
 
 
 def check_resnet(sh) -> None:
@@ -88,12 +87,8 @@ def check_bert(sh) -> None:
 
 
 def main() -> None:
-    try:
-        topo = topologies.get_topology_desc("v5e:2x2x1", "tpu")
-    except Exception as e:  # noqa: BLE001 - any init failure means no AOT
-        # Sentinel for CI: environments without libtpu's AOT topology
-        # (matched by tests/test_aot_step.py to SKIP, not fail).
-        print(f"TPU-AOT-TOPOLOGY-UNAVAILABLE: {e!r}")
+    topo = tpu_topology("v5e:2x2x1")
+    if topo is None:
         return
     sh = NamedSharding(Mesh([topo.devices[0]], ("d",)), P())
     check_bert(sh)

@@ -1,0 +1,97 @@
+"""AOT Mosaic-compile every Pallas kernel at its bench shapes — no TPU needed.
+
+The interpret-mode tests prove the kernels' math; they prove nothing
+about whether Mosaic accepts their memory ops (alignment/tiling rules
+only the real TPU pipeline enforces — r03 shipped two kernels that were
+interpret-correct and Mosaic-rejected: the sorted scatter's unaligned
+DMA offsets and the flash attention's (1, block_q) row-stat blocks).
+jax's compile-only PJRT topology compiles for TPU with no TPU attached:
+
+    python tools/aot_check_kernels.py
+
+Runs as its own process (tests/test_aot_step.py) because libtpu admits
+one process at a time. Compiling is not executing: chip_smoke.py phase 2
+runs the same kernels at the same shapes on the chip.
+"""
+
+import os
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import Mesh, NamedSharding  # noqa: E402
+from jax.sharding import PartitionSpec as P  # noqa: E402
+
+from tools._aot_common import tpu_topology  # noqa: E402
+
+# (updates, payload width, rows incl. trash) — bench_deepfm push,
+# bench_wide_deep push, and the tiny probe shape.
+SCATTER_SHAPES = [
+    (425_984, 20, 4_194_305),
+    (163_840, 12, 1_048_577),
+    (64, 8, 9000),
+]
+
+# (requests, pull width, table width, rows incl. trash) — bench_deepfm
+# pull (426K ids from the [4M, W] fused table; rows NOT a multiple of
+# the kernel BLOCK, so this also pins Mosaic's padded tail-block fetch)
+# and the tiny probe shape.
+GATHER_SHAPES = [
+    (425_984, 16, 20, 4_194_305),
+    (425_984, 40, 40, 4_194_305),
+    (64, 8, 9, 9000),
+]
+
+
+def main() -> None:
+    from paddlebox_tpu.ops.pallas_kernels.flash_attention import (
+        flash_attention)
+    from paddlebox_tpu.ops.pallas_kernels.seqpool_cvm import (
+        seqpool_cvm_pallas)
+    from paddlebox_tpu.ops.pallas_kernels.sorted_gather import sorted_gather
+    from paddlebox_tpu.ops.pallas_kernels.sorted_scatter import (
+        sorted_scatter_accumulate)
+
+    topo = tpu_topology("v5e:2x2x1")
+    if topo is None:
+        return
+    sh = NamedSharding(Mesh([topo.devices[0]], ("d",)), P())
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    for n, aw, rows_n in SCATTER_SHAPES:
+        jax.jit(lambda r, p: sorted_scatter_accumulate(r, p, rows_n)).lower(
+            sds((n,), jnp.int32), sds((n, aw), jnp.float32)).compile()
+        print(f"AOT sorted_scatter [{n} x {aw}] -> {rows_n}: OK", flush=True)
+
+    for n, pw, w, rows_n in GATHER_SHAPES:
+        jax.jit(lambda r, t: sorted_gather(r, t, width=pw)).lower(
+            sds((n,), jnp.int32), sds((rows_n, w), jnp.float32)).compile()
+        print(f"AOT sorted_gather [{n}] <- [{rows_n} x {w}] width {pw}: OK",
+              flush=True)
+
+    # bench_gpt's shape: [4, 1024, 16, 64], causal, with gradients.
+    q = sds((4, 1024, 16, 64), jnp.float32)
+    jax.jit(jax.grad(
+        lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                        use_pallas=True).sum(),
+        argnums=(0, 1, 2))).lower(q, q, q).compile()
+    print("AOT flash_attention fwd+bwd [4, 1024, 16, 64]: OK", flush=True)
+
+    n, d, rows = 65536, 16, 16384
+    sc = sds((n,), jnp.float32)
+    jax.jit(lambda e, s, c, g: seqpool_cvm_pallas(
+        e, s, c, g, rows, use_pallas=True)).lower(
+        sds((n, d), jnp.float32), sc, sc, sds((n,), jnp.int32)).compile()
+    print(f"AOT seqpool_cvm [{n} x {d}] -> {rows}: OK", flush=True)
+
+    print("PALLAS KERNELS TPU AOT COMPILE: OK")
+
+
+if __name__ == "__main__":
+    main()

@@ -33,12 +33,11 @@ jax.config.update("jax_platforms", "cpu")
 
 import jax.numpy as jnp  # noqa: E402
 import optax  # noqa: E402
-from jax.experimental import topologies  # noqa: E402
 
 from paddlebox_tpu.parallel import HybridTopology, build_mesh  # noqa: E402
 
 
-from tools._aot_common import sds  # noqa: E402
+from tools._aot_common import sds, tpu_topology  # noqa: E402
 
 
 def check_gpt_hybrid(topo) -> None:
@@ -146,12 +145,8 @@ def check_device_store_sharded(topo) -> None:
 
 
 def main() -> None:
-    try:
-        topo = topologies.get_topology_desc("v5e:2x2x1", "tpu")
-    except Exception as e:  # noqa: BLE001 - any init failure means no AOT
-        # Sentinel for CI: environments without libtpu's AOT topology
-        # (matched by tests/test_aot_step.py to SKIP, not fail).
-        print(f"TPU-AOT-TOPOLOGY-UNAVAILABLE: {e!r}")
+    topo = tpu_topology("v5e:2x2x1")
+    if topo is None:
         return
     check_gpt_hybrid(topo)
     check_ctr_dp4(topo)

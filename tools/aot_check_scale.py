@@ -45,11 +45,10 @@ jax.config.update("jax_platforms", "cpu")
 
 import jax.numpy as jnp  # noqa: E402
 import optax  # noqa: E402
-from jax.experimental import topologies  # noqa: E402
 from jax.sharding import Mesh  # noqa: E402
 
 
-from tools._aot_common import sds  # noqa: E402
+from tools._aot_common import sds, tpu_topology  # noqa: E402
 
 
 def check_ctr_multislice(topo, n_slices: int, dp: int) -> None:
@@ -149,10 +148,8 @@ def main() -> None:
     ap.add_argument("--chips", type=int, default=64, choices=(64, 256))
     args = ap.parse_args()
     name = {64: "v5e:8x8x1", 256: "v5e:16x16x1"}[args.chips]
-    try:
-        topo = topologies.get_topology_desc(name, "tpu")
-    except Exception as e:  # noqa: BLE001 - any init failure means no AOT
-        print(f"TPU-AOT-TOPOLOGY-UNAVAILABLE: {e!r}")
+    topo = tpu_topology(name)
+    if topo is None:
         return
     if args.chips == 64:
         check_ctr_multislice(topo, n_slices=4, dp=16)
