@@ -46,7 +46,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from paddlebox_tpu.core import faults, log, monitor
+from paddlebox_tpu.core import faults, log, monitor, trace
 from paddlebox_tpu.embedding import lifecycle
 from paddlebox_tpu.embedding.table import (PassTable, TableConfig,
                                            extract_pass_values_host,
@@ -529,28 +529,30 @@ class DeviceFeatureStore:
             return self._ensure_rows_locked(keys)
 
     def _ensure_rows_locked(self, keys: np.ndarray) -> np.ndarray:
-        k = np.ascontiguousarray(keys, np.uint64)
-        base = self._index.size
-        if base == 0 and k.size and native_store.is_sorted_unique_nonzero(k):
-            # Fresh-build bypass (sorted-run store build, round 13):
-            # pass-key arrays arrive sorted unique (dedup_keys /
-            # run-merge output), so the first build skips the serial
-            # find-or-insert walk — bulk placement parallelizes and the
-            # rows (0..n-1 in input order) are bit-identical to upsert
-            # on an empty index.
-            rows = self._index.bulk_build(k)
-            self._append_rows_locked(k, 0, int(k.size))
-            monitor.add("device_store/new_keys", int(k.size))
-            monitor.add("device_store/bulk_builds", 1)
+        with trace.span("store/ensure_rows", keys=int(np.size(keys))):
+            k = np.ascontiguousarray(keys, np.uint64)
+            base = self._index.size
+            if (base == 0 and k.size
+                    and native_store.is_sorted_unique_nonzero(k)):
+                # Fresh-build bypass (sorted-run store build, round 13):
+                # pass-key arrays arrive sorted unique (dedup_keys /
+                # run-merge output), so the first build skips the serial
+                # find-or-insert walk — bulk placement parallelizes and the
+                # rows (0..n-1 in input order) are bit-identical to upsert
+                # on an empty index.
+                rows = self._index.bulk_build(k)
+                self._append_rows_locked(k, 0, int(k.size))
+                monitor.add("device_store/new_keys", int(k.size))
+                monitor.add("device_store/bulk_builds", 1)
+                return rows
+            rows, n_new = self._index.upsert(k)
+            if n_new:
+                new_keys = k[rows >= base]
+                # upsert assigns new rows in input order, so new_keys (input
+                # order) aligns with rows base..base+n_new-1.
+                self._append_rows_locked(new_keys, base, n_new)
+                monitor.add("device_store/new_keys", int(n_new))
             return rows
-        rows, n_new = self._index.upsert(k)
-        if n_new:
-            new_keys = k[rows >= base]
-            # upsert assigns new rows in input order, so new_keys (input
-            # order) aligns with rows base..base+n_new-1.
-            self._append_rows_locked(new_keys, base, n_new)
-            monitor.add("device_store/new_keys", int(n_new))
-        return rows
 
     @property
     def _template_row(self) -> np.ndarray:
@@ -772,27 +774,28 @@ class DeviceFeatureStore:
         ONLY the selected pass positions (all with valid store rows);
         pads request the scratch slot and place at the trash row. cap is
         pow2-stable like _bucket_exact's."""
-        s = self.num_shards
-        m = sel_pos.size
-        rs = rows[sel_pos]
-        store_shard = (rs % s).astype(np.int64)
-        store_slot = (rs // s).astype(np.int64)
-        pass_shard = (sel_pos % sp).astype(np.int64)
-        pass_local = (sel_pos // sp).astype(np.int64)
-        counts = np.zeros((sp, s), np.int64)
-        np.add.at(counts, (pass_shard, store_shard), 1)
-        cap = _pow2(max(int(counts.max()) if m else 1, 1))
-        req = np.full((sp, s, cap), self._cap, np.int64)
-        place = np.full((sp, s, cap), rps, np.int64)
-        order = np.lexsort((store_shard, pass_shard))
-        gs = pass_shard[order] * s + store_shard[order]
-        starts = np.searchsorted(gs, np.arange(sp * s))
-        pos = np.arange(m) - starts[gs]
-        req[pass_shard[order], store_shard[order], pos] = \
-            store_slot[order]
-        place[pass_shard[order], store_shard[order], pos] = \
-            pass_local[order]
-        return req.astype(np.int32), place.astype(np.int32), cap
+        with trace.span("store/bucket", rows=int(sel_pos.size)):
+            s = self.num_shards
+            m = sel_pos.size
+            rs = rows[sel_pos]
+            store_shard = (rs % s).astype(np.int64)
+            store_slot = (rs // s).astype(np.int64)
+            pass_shard = (sel_pos % sp).astype(np.int64)
+            pass_local = (sel_pos // sp).astype(np.int64)
+            counts = np.zeros((sp, s), np.int64)
+            np.add.at(counts, (pass_shard, store_shard), 1)
+            cap = _pow2(max(int(counts.max()) if m else 1, 1))
+            req = np.full((sp, s, cap), self._cap, np.int64)
+            place = np.full((sp, s, cap), rps, np.int64)
+            order = np.lexsort((store_shard, pass_shard))
+            gs = pass_shard[order] * s + store_shard[order]
+            starts = np.searchsorted(gs, np.arange(sp * s))
+            pos = np.arange(m) - starts[gs]
+            req[pass_shard[order], store_shard[order], pos] = \
+                store_slot[order]
+            place[pass_shard[order], store_shard[order], pos] = \
+                pass_local[order]
+            return req.astype(np.int32), place.astype(np.int32), cap
 
     def _merge_rows_locked(self, block_vals: jax.Array, rows: np.ndarray,
                            sel_pos: np.ndarray, rps: int,
@@ -878,26 +881,28 @@ class DeviceFeatureStore:
         slot=-1/local=-1 to be sentineled by the caller; cap pow2-stable
         across passes.
         """
-        s = self.num_shards
-        valid = rows >= 0
-        store_shard = np.where(valid, rows % s, np.arange(n) % s
-                               ).astype(np.int64)
-        store_slot = np.where(valid, rows // s, self._cap).astype(np.int64)
-        pass_shard = (np.arange(n) % sp).astype(np.int64)
-        pass_local = (np.arange(n) // sp).astype(np.int64)
-        counts = np.zeros((sp, s), np.int64)
-        np.add.at(counts, (pass_shard, store_shard), 1)
-        cap = _pow2(max(int(counts.max()) if n else 1, 1))
-        slot = np.full((sp, s, cap), -1, np.int64)
-        local = np.full((sp, s, cap), -1, np.int64)
-        order = np.lexsort((store_shard, pass_shard))
-        gs = pass_shard[order] * s + store_shard[order]
-        starts = np.searchsorted(gs, np.arange(sp * s))
-        pos = np.arange(n) - starts[gs]
-        slot[pass_shard[order], store_shard[order], pos] = store_slot[order]
-        local[pass_shard[order], store_shard[order], pos] = \
-            pass_local[order]
-        return slot, local, counts, cap
+        with trace.span("store/bucket", rows=int(n)):
+            s = self.num_shards
+            valid = rows >= 0
+            store_shard = np.where(valid, rows % s, np.arange(n) % s
+                                   ).astype(np.int64)
+            store_slot = np.where(valid, rows // s, self._cap).astype(np.int64)
+            pass_shard = (np.arange(n) % sp).astype(np.int64)
+            pass_local = (np.arange(n) // sp).astype(np.int64)
+            counts = np.zeros((sp, s), np.int64)
+            np.add.at(counts, (pass_shard, store_shard), 1)
+            cap = _pow2(max(int(counts.max()) if n else 1, 1))
+            slot = np.full((sp, s, cap), -1, np.int64)
+            local = np.full((sp, s, cap), -1, np.int64)
+            order = np.lexsort((store_shard, pass_shard))
+            gs = pass_shard[order] * s + store_shard[order]
+            starts = np.searchsorted(gs, np.arange(sp * s))
+            pos = np.arange(n) - starts[gs]
+            slot[pass_shard[order], store_shard[order], pos] = \
+                store_slot[order]
+            local[pass_shard[order], store_shard[order], pos] = \
+                pass_local[order]
+            return slot, local, counts, cap
 
     def _gather_pass_locked(self, rows: np.ndarray, n: int, rps: int,
                             sp: int, missing: Optional[np.ndarray] = None,

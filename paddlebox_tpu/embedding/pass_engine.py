@@ -21,7 +21,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from paddlebox_tpu.core import (faults, flags, log, monitor,
-                                pipeline_stats, timers)
+                                pipeline_stats, timers, trace)
 from paddlebox_tpu.embedding.store import FeatureStore
 from paddlebox_tpu.embedding.table import (PassTable, TableConfig,
                                            build_pass_table_host,
@@ -96,7 +96,9 @@ class PassEngine:
                readonly: bool = False) -> None:
         try:
             faults.faultpoint("pass_engine/build")
-            with self.timers.scope("feed_pass"):
+            with self.timers.scope("feed_pass"), \
+                    trace.span("build/pass_table",
+                               pass_id=self._pass_id + 1):
                 # Key dedup can overlap the active pass... (native
                 # multi-threaded dedup, role of PreBuildTask,
                 # ps_gpu_wrapper.cc:114; numpy fallback inside). Keys
@@ -262,7 +264,8 @@ class PassEngine:
         # as the authoritative numbers; this feed keeps the raw
         # occupancy view (trace_report) consistent with them.
         with self.timers.scope("feed_wait"), \
-                pipeline_stats.GLOBAL.blocked_up("boundary"):
+                pipeline_stats.GLOBAL.blocked_up("boundary"), \
+                trace.span("build/boundary_wait"):
             while True:
                 if pending.cancel.is_set():
                     raise PassBuildCancelled(

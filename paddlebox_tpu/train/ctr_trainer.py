@@ -901,7 +901,8 @@ class CTRTrainer:
         if feed_keys:
             eng.feed_pass([dataset.pass_keys(slots=g.slots)
                            for g in eng.groups], readonly=True)
-        tables = eng.begin_pass()
+        with trace.span("pass/begin_pass"):
+            tables = eng.begin_pass()
         auc = self._auc_init()
         rep = (NamedSharding(self.mesh, P())
                if self.mesh is not None else None)
@@ -1206,7 +1207,8 @@ class CTRTrainer:
                 # blocked_up on the device stage: the consumer (and so
                 # the device's supply of new blocks) starved waiting on
                 # the host pipeline — the device_idle_frac numerator.
-                with pipeline_stats.GLOBAL.blocked_up("device"):
+                with pipeline_stats.GLOBAL.blocked_up("device"), \
+                        trace.span("pass/feed_wait"):
                     item = q.get()
                 pipeline_stats.GLOBAL.sample_queue("producer_queue",
                                                    q.qsize())
@@ -1368,10 +1370,12 @@ class CTRTrainer:
                      "per-step dispatch) — running K=1", k_disp)
             k_disp = 1
         if feed_keys:
-            with self.timers.scope("feed_pass"):
+            with self.timers.scope("feed_pass"), \
+                    trace.span("pass/feed_pass"):
                 eng.feed_pass([dataset.pass_keys(slots=g.slots)
                                for g in eng.groups])
-        tables = eng.begin_pass()
+        with trace.span("pass/begin_pass"):
+            tables = eng.begin_pass()
         params, opt_state = self.params, self.opt_state
         auc = self.auc_state
         if mode == "async" and self._async_dense is None:

@@ -217,25 +217,33 @@ class DayRunner:
         # thread, so day_load overlapping a training window shows up in
         # that pass's verdict exactly like the reference's
         # PreLoadIntoMemory overlap would.
-        with pipeline_stats.GLOBAL.busy("day_load"):
+        with pipeline_stats.GLOBAL.busy("day_load"), \
+                trace.span("ingest/load", day=day, pass_id=pass_id):
             ds.load_into_memory()
         if self.shuffle:
             # Deterministic digest — hash(str) is randomized per
             # process, which would make recovery replays and per-rank
             # batch orders irreproducible.
             import zlib
-            ds.local_shuffle(seed=zlib.crc32(f"{day}:{pass_id}".encode()))
+            with trace.span("ingest/shuffle", day=day, pass_id=pass_id):
+                ds.local_shuffle(
+                    seed=zlib.crc32(f"{day}:{pass_id}".encode()))
         return ds
 
-    def _feed_keys(self, ds: Dataset, *, async_build: bool = True) -> None:
+    def _feed_keys(self, ds: Dataset, day: str, pass_id: int, *,
+                   async_build: bool = True) -> None:
         """Register an online pass's keys. Defaults to the async build:
         with the split-key early build the engine overlaps everything it
         legally can with the active pass (and with the dataset work of
         THIS thread when no pass is active) — the serial build is only
         for callers that need the build's errors raised here."""
         eng = self.trainer.engine
-        eng.feed_pass([ds.pass_keys(slots=g.slots) for g in eng.groups],
-                      async_build=async_build)
+        with trace.span("ingest/pass_keys", day=day, pass_id=pass_id):
+            keys = [ds.pass_keys(slots=g.slots) for g in eng.groups]
+        # Parked on the engine's pending slot until the active pass's
+        # begin_pass frees it.
+        with trace.span("ingest/feed_pass", day=day, pass_id=pass_id):
+            eng.feed_pass(keys, async_build=async_build)
 
     def _start_preload(self, day: str, pass_id: int, files: List[str]):
         """Background: load pass k+1's data and kick its table build while
@@ -251,7 +259,7 @@ class DayRunner:
             try:
                 faults.faultpoint("day_runner/preload")
                 out["ds"] = self._load_dataset(day, pass_id, files)
-                self._feed_keys(out["ds"], async_build=True)
+                self._feed_keys(out["ds"], day, pass_id, async_build=True)
             except BaseException as e:
                 out["error"] = e
 
@@ -491,7 +499,9 @@ class DayRunner:
         try:
             for i, (pass_id, files) in enumerate(jobs):
                 if preloaded is not None:
-                    preloaded["thread"].join()
+                    with trace.span("day/preload_join", day=day,
+                                    pass_id=pass_id):
+                        preloaded["thread"].join()
                     self._inflight_preload = None
                     if preloaded["error"] is not None:
                         raise preloaded["error"]
@@ -501,7 +511,7 @@ class DayRunner:
                         # this preload's table build — re-feed from the
                         # (still loaded) dataset so begin_pass has a
                         # fresh build against the rolled-back store.
-                        self._feed_keys(ds)
+                        self._feed_keys(ds, day, pass_id)
                 elif self.pipeline_passes:
                     # First pass of the day: load + feed here so training
                     # can begin while the NEXT pass preloads. Async build
@@ -509,7 +519,7 @@ class DayRunner:
                     # surfaces there, inside the same try as every other
                     # pass failure.
                     ds = self._load_dataset(day, pass_id, files)
-                    self._feed_keys(ds)
+                    self._feed_keys(ds, day, pass_id)
                     feed_keys = False
                 else:
                     ds, feed_keys = None, True

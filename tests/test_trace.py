@@ -32,9 +32,12 @@ def test_thread_safety_all_events_land():
     tr.enable()
     n_threads, n_spans = 8, 200
     errors = []
+    # all workers alive at once: real contention, and idents that differ
+    start = threading.Barrier(n_threads)
 
     def worker(i):
         try:
+            start.wait(timeout=30)
             for j in range(n_spans):
                 with tr.span(f"t{i}", j=j):
                     pass
