@@ -257,6 +257,7 @@ def phase_identity(sm: Smoke, out: dict):
 def phase_kernels(sm: Smoke, out: dict):
     import jax
     import jax.numpy as jnp
+    import numpy as np
 
     from paddlebox_tpu.embedding import TableConfig
     from paddlebox_tpu.embedding.lookup import bucket_capacity
@@ -266,7 +267,7 @@ def phase_kernels(sm: Smoke, out: dict):
     from paddlebox_tpu.ops.pallas_kernels.seqpool_cvm import (
         seqpool_cvm_pallas)
     from paddlebox_tpu.ops.pallas_kernels.sorted_gather import (
-        sorted_gather, sorted_stream_layout)
+        sorted_gather, sorted_stream_layout, stream_tier)
     from paddlebox_tpu.ops.pallas_kernels.sorted_scatter import (
         UCAP, sorted_scatter_accumulate)
     from paddlebox_tpu.ops.seqpool import fused_seqpool_cvm
@@ -276,18 +277,27 @@ def phase_kernels(sm: Smoke, out: dict):
     n_ids = cfg["batch"] * cfg["slots"]
     out.update({"interpret": interp, "record_width": w, "pull_width": pw})
 
-    def sparse_pair(tag, n, block):
+    def sparse_pair(tag, n, block, hot=0):
         """sorted_gather (exact) and sorted_scatter_accumulate at one
         (ids, table block) shape; rows past the block are the dropped
-        sentinel both kernels must zero / ignore."""
-        k0, k1, k2 = jax.random.split(jax.random.PRNGKey(block), 3)
+        sentinel both kernels must zero / ignore. ``hot`` requests go to
+        one row (day_zipf's hottest key: 73K of a step's 426K ids), a
+        run over the per-block budget that the kernels serve themselves:
+        the gather once, the scatter in several staging windows."""
+        k0, k1, k2, k3 = jax.random.split(jax.random.PRNGKey(block + hot), 4)
         rows = jax.random.randint(k0, (n,), 0, block + block // 64,
                                   jnp.int32)
-        max_run = int(sorted_stream_layout(rows, block)[3])
-        rec = out[tag] = {"ids": n, "rows": block, "max_run": max_run}
-        check(max_run <= UCAP,
-              f"{tag}: max_run {max_run} > UCAP {UCAP} — the kernels "
-              f"would take their XLA branch and this check proves nothing")
+        rows = jax.random.permutation(k3, rows.at[:hot].set(block // 3))
+        layout = sorted_stream_layout(rows, block)
+        tier = int(stream_tier(layout))
+        rec = out[tag] = {"ids": n, "rows": block, "hot": hot,
+                          "max_run": int(layout[3]),
+                          "max_distinct_run": int(layout[4]), "tier": tier}
+        check(tier == (1 if hot else 0),
+              f"{tag}: max_run {rec['max_run']}, max_distinct_run "
+              f"{rec['max_distinct_run']} against UCAP {UCAP} select "
+              f"tier {tier}: the gather would not run the path this "
+              f"check is for (2 = its XLA branch)")
         table = jax.random.normal(k1, (block, w), jnp.float32)
         got = sorted_gather(rows, table, width=pw, interpret=interp)
         keep = rows < block
@@ -297,17 +307,25 @@ def phase_kernels(sm: Smoke, out: dict):
         check(g_err == 0.0, f"{tag}: sorted_gather max err {g_err} != 0")
         del table, got, ref
         pay = jax.random.normal(k2, (n, aw), jnp.float32)
-        acc = sorted_scatter_accumulate(rows, pay, block, interpret=interp)
-        ref = jnp.zeros((block, aw), jnp.float32).at[
-            jnp.where(keep, rows, block)].add(pay, mode="drop")
-        s_err = rec["scatter_max_err"] = float(jnp.max(jnp.abs(acc - ref)))
-        # f32 sums in another order: a few ulps of the largest cell.
-        s_tol = rec["scatter_tol"] = 1e-5 * float(jnp.max(jnp.abs(ref)))
+        acc = np.asarray(
+            sorted_scatter_accumulate(rows, pay, block, interpret=interp))
+        # float32 adds in request order on the host: the kernel's own
+        # order within a row (the sort is stable).
+        ref = np.zeros((block + 1, aw), np.float32)
+        np.add.at(ref, np.minimum(np.asarray(rows), block), np.asarray(pay))
+        ref = ref[:block]
+        s_err = rec["scatter_max_err"] = float(np.max(np.abs(acc - ref)))
+        s_tol = rec["scatter_tol"] = 1e-5 * float(np.max(np.abs(ref)))
         check(s_err <= s_tol,
               f"{tag}: sorted_scatter max err {s_err} > {s_tol}")
 
     # One chip: all ids into the whole pass-table block.
-    sparse_pair("sparse_1chip", n_ids, plan_shards(cfg["pass_keys"], 1) + 1)
+    one_chip = plan_shards(cfg["pass_keys"], 1) + 1
+    sparse_pair("sparse_1chip", n_ids, one_chip)
+    # The same under day_zipf's skew (one chip runs no dedup in front of
+    # the kernels).
+    sparse_pair("sparse_1chip_hot_row", n_ids, one_chip,
+                hot=max(n_ids * 73 // 426, UCAP + 1))
     # What each of four chips compiles: its shard's block, serving the
     # 4 x cap bucket cells its peers send.
     sparse_pair("sparse_4chip_shard", 4 * bucket_capacity(n_ids // 4, 4),

@@ -103,7 +103,8 @@ def emit_pass_report(kind: str, *, steps: int, samples: int,
             if isinstance(v, (int, float)):
                 reg.set_gauge(f"pass/{kind}_{k}", float(v))
         for k in ("dispatch_blocks", "host_syncs", "lookup_overflow",
-                  "kernel_fallback", "lookup_exchange_bytes"):
+                  "kernel_fallback", "kernel_hot_served",
+                  "lookup_exchange_bytes"):
             v = stats.get(k)
             if isinstance(v, (int, float)):
                 reg.set(f"pass/{kind}_{k}", int(v))

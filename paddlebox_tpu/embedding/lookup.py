@@ -268,17 +268,36 @@ def compute_bucketing(table: PassTable, dev_rows: jax.Array,
     return bk + (cap, recv_rows, _stream_layout_for(recv_rows, block))
 
 
-def kernel_fallback(bucketing: Optional[Tuple]) -> jax.Array:
-    """int32 scalar, 1 when this step's shared sorted-stream layout
-    tripped the kernels' skew guard — a block's run of requests exceeded
-    the per-block budget (``max_run > UCAP``), so the pull gather and
-    push scatter consuming the layout took their XLA branch at run time
-    — else 0 (also 0 when no kernel layout is in play). The trainer sums
-    it into the pass stats next to ``lookup_overflow``."""
+def _stream_tier_is(bucketing: Optional[Tuple], tier: int) -> jax.Array:
+    """int32 scalar, 1 when the step's shared sorted-stream layout is
+    served by ``tier`` (``sorted_gather.stream_tier``), 0 otherwise and
+    when no kernel layout is in play."""
     if bucketing is None or len(bucketing) != 6 or bucketing[5] is None:
         return jnp.zeros((), jnp.int32)
-    from paddlebox_tpu.ops.pallas_kernels.sorted_scatter import UCAP
-    return (bucketing[5][3] > UCAP).astype(jnp.int32)
+    from paddlebox_tpu.ops.pallas_kernels.sorted_gather import stream_tier
+    return (stream_tier(bucketing[5]) == tier).astype(jnp.int32)
+
+
+def kernel_hot_served(bucketing: Optional[Tuple]) -> jax.Array:
+    """int32 scalar, 1 when a block's run in this step's shared
+    sorted-stream layout exceeded the kernels' per-block budget
+    (``max_run > UCAP``: a hot row, repeated without dedup — the
+    one-chip path hands the raw ids to the kernels) and the kernels
+    served it: the pull gather once per distinct row, the push scatter
+    in several staging windows. Else 0. The trainer sums it into the
+    pass stats next to ``kernel_fallback``."""
+    return _stream_tier_is(bucketing, 1)
+
+
+def kernel_fallback(bucketing: Optional[Tuple]) -> jax.Array:
+    """int32 scalar, 1 when a sorted-stream kernel gave way to XLA at
+    run time on this step's shared layout: some block was asked for
+    more than UCAP DISTINCT rows (``max_distinct_run > UCAP``), which
+    the pull gather hands to the XLA gather (its third tier; the push
+    scatter serves every run length and never gives way). Else 0, also
+    when no kernel layout is in play. The trainer sums it into the pass
+    stats next to ``lookup_overflow``."""
+    return _stream_tier_is(bucketing, 2)
 
 
 def exchange_bytes(table: PassTable, n: int,
@@ -341,7 +360,7 @@ def _gather_rows(vals: jax.Array, rows: jax.Array, width: int, block: int,
     (block - 1: padding/overflow requests) are DROPPED to zeros — the
     trash row's pull columns are zero by contract (apply_accumulated
     keeps them so), so the result is identical while the concentrated
-    padding run stays off the kernel's per-block budget. ``layout`` is
+    padding run stays out of the kernel's stream. ``layout`` is
     the shared sorted-stream layout from compute_bucketing (one argsort
     serves this gather and the push scatter)."""
     mode = _kernel_mode("sparse_gather_kernel")
@@ -372,13 +391,16 @@ def pull_local(table: PassTable, dev_rows: jax.Array, *, axis: str,
     The capacity contract (`bucket_capacity`): with dedup (default) a
     cell holds a UNIQUE id, so repetition — the realistic skew in CTR
     data, where a hot key can be 30%+ of a batch — cannot overflow a
-    bucket at all; what remains is uniform-hash spread of unique ids
+    BUCKET at all; what remains is uniform-hash spread of unique ids
     (~3e-5 per bucket at default slack, less any margin given away via
     ``embedding_unique_frac``). Overflows remain counted, honest drops
     (contrast: the reference's HeterComm never drops,
     heter_comm_inl.h:273 — it re-walks; we trade bounded drop odds for
     static shapes and expose the count). Single shard: one sliced
-    gather, no collective, no possible overflow.
+    gather, no collective, no bucket and so no possible overflow — but
+    also no dedup: the raw ids reach the sorted-stream kernels, which
+    serve a hot row's run themselves (``kernel_hot_served``; a request
+    is never dropped for repetition).
     """
     num_shards = table.num_shards
     block = table.rows_per_shard + 1
@@ -467,8 +489,8 @@ def _accumulate(rows: jax.Array, payload: jax.Array, block: int,
     PROFILE.md) or the XLA scatter. Trash-row entries (row == block-1:
     padding/overflow, all-zero or count-only payload) are dropped on the
     kernel path — apply_accumulated re-zeroes the trash row either way,
-    and concentrating every padding lane on one row is exactly the skew
-    the kernel's per-block budget must not pay for. ``layout`` is the
+    and every padding lane on one row is a run the kernel would walk
+    for nothing. ``layout`` is the
     shared sorted-stream layout from compute_bucketing (one argsort
     serves this scatter and the pull gather)."""
     mode = _kernel_mode("sparse_scatter_kernel")

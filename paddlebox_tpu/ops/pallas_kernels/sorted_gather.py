@@ -21,13 +21,27 @@ that as the padding/trash sentinel, mirroring the scatter's drop
 semantics (the lookup trash row carries zero pull columns, so dropping
 is value-identical to gathering it).
 
-Skew guard: per-block request counts are data-dependent; if any block's
-run exceeds the static per-block budget (a pathologically hot row,
-requested > UCAP times without dedup), ``lax.cond`` falls back to the
-XLA gather — the kernel itself never reads past its budget. The budget,
-block size, and DMA alignment constants are the scatter's: the two
-kernels must agree for one argsort + one ``starts`` table to serve both
-(``embedding/lookup.py`` shares the layout per width group per step).
+Skew: per-block request counts are data-dependent, and on one chip the
+rows arrive un-deduplicated (a Zipf(1.2) CTR batch asks 73K of its 426K
+requests of one row). A repeated row is the same bytes however often it
+is asked for, and the result already ends in a fan-out (one staging-slot
+index per request), so three tiers, selected by what the layout shows:
+
+  1. every block's run fits the per-block budget (``max_run <= UCAP``):
+     the sorted stream is served as it is;
+  2. else every block's run of DISTINCT rows fits
+     (``max_distinct_run <= UCAP``): the stream is compacted to its
+     distinct rows, the same kernel serves each once, and duplicates
+     share a staging slot — a skewed batch is fewer row reads than a
+     uniform one;
+  3. else (more than UCAP distinct rows asked of one BLOCK-row block:
+     tables of a few blocks with tens of thousands of requests) the XLA
+     gather, kept as the exactness net.
+
+The kernel itself never reads past its budget. The budget, block size
+and DMA alignment constants are the scatter's: the two kernels must
+agree for one argsort to serve both (``embedding/lookup.py`` shares the
+layout per width group per step).
 """
 
 from __future__ import annotations
@@ -42,31 +56,60 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from paddlebox_tpu.ops.pallas_kernels.sorted_scatter import (
-    ALIGN, BLOCK, UCAP, WINDOW)
+    ALIGN, BLOCK, UCAP, WINDOW, block_starts)
+
+
+def _distinct_rank(sorted_rows: jax.Array, n: int) -> jax.Array:
+    """[n] int32: how many distinct rows precede sorted rank s's row —
+    its position once every run of equal rows is served once."""
+    heads = jnp.concatenate([jnp.ones((1,), jnp.int32),
+                             (sorted_rows[1:n] != sorted_rows[:n - 1]
+                              ).astype(jnp.int32)])
+    return jnp.cumsum(heads) - 1
 
 
 def sorted_stream_layout(rows: jax.Array, num_rows: int) -> Tuple[
-        jax.Array, jax.Array, jax.Array, jax.Array]:
+        jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
     """The per-(rows, num_rows) sort layout BOTH sorted-stream kernels
     consume: (sorted_rows [n+WINDOW] incl. the sentinel pad, order [n],
-    starts [nblocks+1], max_run []). Computing it once per width group
-    and passing it to ``sorted_gather`` (pull) and
+    starts [nblocks+1], max_run [], max_distinct_run []). Computing it
+    once per width group and passing it to ``sorted_gather`` (pull) and
     ``sorted_scatter_accumulate`` (push) makes the step pay the argsort
     once instead of twice — rows >= num_rows are remapped to the
     one-past-the-last-block sentinel so they sort past every block
     boundary and count toward no block's run (the scatter's exact
-    dropped-row convention)."""
+    dropped-row convention). ``max_distinct_run`` (the most distinct
+    rows any block is asked for) is counted only when ``max_run``
+    exceeds the budget; below it ``max_run`` bounds it and stands in."""
     rows = rows.astype(jnp.int32)
+    n = rows.shape[0]
     rows_pad = -(-num_rows // BLOCK) * BLOCK
     rows = jnp.where(rows >= num_rows, rows_pad, rows)
     order = jnp.argsort(rows).astype(jnp.int32)
     sorted_rows = jnp.concatenate(
         [rows[order], jnp.full((WINDOW,), rows_pad, jnp.int32)])
-    nblocks = rows_pad // BLOCK
-    boundaries = jnp.arange(nblocks + 1, dtype=jnp.int32) * BLOCK
-    starts = jnp.searchsorted(sorted_rows, boundaries).astype(jnp.int32)
+    starts = block_starts(sorted_rows, rows_pad // BLOCK)
     max_run = jnp.max(starts[1:] - starts[:-1])
-    return sorted_rows, order, starts, max_run
+
+    def count_distinct():
+        # distinct rows among sorted ranks [0, i), read at block starts
+        before = jnp.concatenate([
+            jnp.zeros((1,), jnp.int32),
+            _distinct_rank(sorted_rows, n) + 1])[starts]
+        return jnp.max(before[1:] - before[:-1])
+
+    max_distinct_run = lax.cond(max_run > UCAP, count_distinct,
+                                lambda: max_run)
+    return sorted_rows, order, starts, max_run, max_distinct_run
+
+
+def stream_tier(layout: Tuple) -> jax.Array:
+    """int32 scalar: which of ``sorted_gather``'s tiers serves a layout
+    (module docstring) — 0 the stream as it is, 1 its distinct rows (a
+    run over the budget, served by the kernels all the same), 2 the XLA
+    gather."""
+    return ((layout[3] > UCAP).astype(jnp.int32)
+            + (layout[4] > UCAP).astype(jnp.int32))
 
 
 def _kernel(starts_ref, rows_ref, tbl_ref, out_ref, rows_s, sem):
@@ -110,8 +153,7 @@ def _sorted_gather_blocks(sorted_rows: jax.Array, table: jax.Array,
     lands at slots [b*UCAP, b*UCAP + run_len) in sorted order."""
     num_rows, w = table.shape
     nblocks = -(-num_rows // BLOCK)
-    boundaries = jnp.arange(nblocks + 1, dtype=jnp.int32) * BLOCK
-    starts = jnp.searchsorted(sorted_rows, boundaries).astype(jnp.int32)
+    starts = block_starts(sorted_rows, nblocks)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -147,8 +189,9 @@ def sorted_gather(rows: jax.Array, table: jax.Array, *,
     (entries >= num_rows yield zeros); table [num_rows, W<=128] float32;
     width <= W selects the leading pull slice. ``layout`` is an optional
     precomputed ``sorted_stream_layout(rows, num_rows)`` (the push
-    scatter shares it). Falls back to the XLA gather when a block's
-    request run exceeds the kernel budget (hot row)."""
+    scatter shares it). A hot row's run is served by the kernel once per
+    distinct row; only more than UCAP distinct rows asked of one block
+    goes to the XLA gather (module docstring: the three tiers)."""
     n = rows.shape[0]
     num_rows, w = table.shape
     pw = w if width is None else width
@@ -164,24 +207,26 @@ def sorted_gather(rows: jax.Array, table: jax.Array, *,
     nblocks = rows_pad // BLOCK
     if layout is None:
         layout = sorted_stream_layout(rows, num_rows)
-    sorted_rows, order, starts, max_run = layout
+    sorted_rows, order, starts = layout[:3]
     if sorted_rows.shape[0] != n + WINDOW or starts.shape[0] != nblocks + 1:
         raise ValueError(
             f"shared layout shapes {sorted_rows.shape[0]}/"
             f"{starts.shape[0]} do not match rows/table "
             f"({n + WINDOW}/{nblocks + 1}) — it was built for different "
             f"(rows, num_rows)")
+    srows = sorted_rows[:n]
+    blk = jnp.minimum(srows // BLOCK, nblocks)
 
-    def pallas_path(_):
-        staged = _sorted_gather_blocks(sorted_rows, table, pw, interpret)
-        # Slot of sorted rank s: its block's slot base + its rank within
-        # the block's run. Sentinel (dropped) entries get the
+    def served(stream, rank, stream_starts):
+        """The kernel over ``stream`` (ascending rows, sentinel-padded),
+        fanned out to request order: sorted rank s reads the staging
+        slot of stream position ``rank[s]``."""
+        staged = _sorted_gather_blocks(stream, table, pw, interpret)
+        # Slot of a stream position: its block's slot base + its rank
+        # within the block's run. Sentinel (dropped) entries get the
         # one-past-the-end slot, turned into zeros after the gather.
         nslots = nblocks * UCAP
-        s = jnp.arange(n, dtype=jnp.int32)
-        srows = sorted_rows[:n]
-        blk = jnp.minimum(srows // BLOCK, nblocks)
-        slot = blk * UCAP + (s - starts[blk])
+        slot = blk * UCAP + (rank - stream_starts[blk])
         slot = jnp.where(srows < num_rows, slot, nslots)
         # Inverse permute: order maps sorted rank -> original position,
         # so one small int32 scatter routes every slot index home and
@@ -190,9 +235,23 @@ def sorted_gather(rows: jax.Array, table: jax.Array, *,
         picked = staged[jnp.minimum(idx, nslots - 1)]
         return jnp.where((idx < nslots)[:, None], picked, 0.0)
 
-    def xla_path(_):
+    def stream_path():
+        return served(sorted_rows, jnp.arange(n, dtype=jnp.int32), starts)
+
+    def distinct_path():
+        # Compact the stream to its distinct rows (ascending, sentinel
+        # behind them; static shape: at worst all n are distinct).
+        # Every member of a run writes the run's row to the run's rank,
+        # so the scatter's duplicates agree.
+        drank = _distinct_rank(sorted_rows, n)
+        drows = jnp.full((n + WINDOW,), rows_pad, jnp.int32
+                         ).at[drank].set(srows)
+        return served(drows, drank, block_starts(drows, nblocks))
+
+    def xla_path():
         keep = rows < num_rows
         safe = jnp.where(keep, rows, 0)
         return jnp.where(keep[:, None], table[safe, :pw], 0.0)
 
-    return lax.cond(max_run <= UCAP, pallas_path, xla_path, operand=None)
+    return lax.switch(stream_tier(layout),
+                      (stream_path, distinct_path, xla_path))

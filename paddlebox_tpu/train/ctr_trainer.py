@@ -36,7 +36,8 @@ from paddlebox_tpu.data.slots import DataFeedConfig, SlotBatch
 from paddlebox_tpu.embedding import TableConfig, make_sparse_optimizer
 from paddlebox_tpu.embedding.grouped import GroupedEngine
 from paddlebox_tpu.embedding.lookup import (compute_bucketing,
-                                            kernel_fallback, pull_local,
+                                            kernel_fallback,
+                                            kernel_hot_served, pull_local,
                                             push_local,
                                             record_exchange_stats)
 from paddlebox_tpu.metrics import (AucState, auc_accumulate, auc_compute,
@@ -721,15 +722,18 @@ class CTRTrainer:
             probs = jax.nn.sigmoid(logits)
             auc = auc_of(auc, probs, labels, valid)
             loss_global = lax.psum(loss, raxes)
-            # Dropped-lookup observability, one [2] device vector so the
-            # pass fetches both with one sync: bucket-overflow ids that
+            # Sparse-path observability, one [3] device vector so the
+            # pass fetches all with one sync: bucket-overflow ids that
             # degraded to zero-embedding pulls and dropped grads this
-            # step, and width groups whose sorted-stream kernels gave way
-            # to XLA at run time (hot-row skew guard) — each summed over
-            # devices and width groups.
+            # step, width groups whose pull gather gave way to XLA at run
+            # time (a block asked for more distinct rows than the kernel
+            # budget), and width groups in which the sorted-stream
+            # kernels served a hot row's over-budget run themselves —
+            # each summed over devices and width groups.
             overflow_global = lax.psum(jnp.stack([
                 sum(p["overflow"][0] for p in pulled),
-                sum(kernel_fallback(bk) for bk in bucketings)]), raxes)
+                sum(kernel_fallback(bk) for bk in bucketings),
+                sum(kernel_hot_served(bk) for bk in bucketings)]), raxes)
             out = (tuple(new_tables), params, opt_state, auc, loss_global,
                    overflow_global)
             if external_dense:
@@ -1634,9 +1638,10 @@ class CTRTrainer:
         with self.timers.scope("sync"):
             # graftlint: allow-sync(pass-end stat fetch inside the sync scope)
             of = (np.asarray(overflow_sum) if overflow_sum is not None
-                  else (0, 0))
+                  else (0, 0, 0))
             stats["lookup_overflow"] = int(of[0])
             stats["kernel_fallback"] = int(of[1])
+            stats["kernel_hot_served"] = int(of[2])
         # Static per-device all-to-all bytes for one pull+push round —
         # what dedup + FLAGS_embedding_unique_frac shrink (the dedup-
         # before-exchange observable; heter_comm.h:192 transfers merged
@@ -1665,10 +1670,14 @@ class CTRTrainer:
         if stats["kernel_fallback"]:
             monitor.add("embedding/kernel_fallback",
                         stats["kernel_fallback"])
-            log.warning("sorted-stream kernels gave way to XLA in %d "
+            log.warning("the sorted-stream gather gave way to XLA in %d "
                         "(step, width group, device) cells this pass — a "
-                        "hot row's run exceeded the per-block budget",
+                        "table block was asked for more distinct rows "
+                        "than the kernel's per-block budget",
                         stats["kernel_fallback"])
+        if stats["kernel_hot_served"]:
+            monitor.add("embedding/kernel_hot_served",
+                        stats["kernel_hot_served"])
         stats["seg_cache_hit_rate"] = self._seg_cache_rate()
         stats["boundary"] = self._boundary_delta(boundary_base)
         wall_s = time.perf_counter() - pass_t0

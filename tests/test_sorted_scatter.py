@@ -7,7 +7,9 @@ import numpy as np
 import pytest
 
 from paddlebox_tpu.ops.pallas_kernels.sorted_scatter import (
-    BLOCK, UCAP, sorted_scatter_accumulate)
+    BLOCK, UCAP, WINDOW, sorted_scatter_accumulate)
+from tests.test_sorted_gather import (_skew_block_edge, _skew_hot_sentinel,
+                                      _skew_zipf, zipf_rows)
 
 
 def _ref(rows, payload, num_rows):
@@ -50,24 +52,75 @@ def test_sentinel_rows_dropped():
                                rtol=1e-5, atol=1e-5)
 
 
-def test_hot_row_falls_back_to_xla_scatter():
-    """More than UCAP updates on one row: the kernel budget would
-    overflow, so the cond must take the exact XLA path."""
+def _sequential(rows, payload, num_rows):
+    """float32 adds in request order: what the kernel must equal bit
+    for bit (a stable sort keeps request order within a row)."""
+    out = np.zeros((num_rows + 1, payload.shape[1]), np.float32)
+    np.add.at(out, np.minimum(rows, num_rows), payload)
+    return out[:num_rows]
+
+
+def test_hot_row_run_walked_in_windows():
+    """A run of 3 * UCAP + 17 updates on one row — several staging
+    windows — is the sequential float32 sum in request order, bit for
+    bit; there is no XLA branch to give way to. Against the float64 sum
+    that sequence is good to 1e-5 relative (12K float32 adds of [0, 1)
+    values: each rounds at the partial sum's ulp)."""
     rng = np.random.default_rng(2)
     num_rows = BLOCK
-    n = UCAP + 2048
+    n = 3 * UCAP + 17
     rows = np.full((n,), 7, np.int32)        # everything hits row 7
-    payload = rng.normal(size=(n, 4)).astype(np.float32)
+    payload = rng.random(size=(n, 4)).astype(np.float32)
+    got = np.asarray(sorted_scatter_accumulate(
+        jnp.asarray(rows), jnp.asarray(payload), num_rows, interpret=True))
+    np.testing.assert_array_equal(got, _sequential(rows, payload, num_rows))
+    np.testing.assert_allclose(got[7], payload.astype(np.float64).sum(0),
+                               rtol=1e-5)
+    assert not got[:7].any() and not got[8:].any()
+
+
+def _one_row(rng):
+    return 2 * BLOCK, np.full((2 * WINDOW + 5,), BLOCK + 5, np.int32)
+
+
+def _run_at(off, cnt):
+    """`off` updates in block 0 (so block 1's run starts `off` past an
+    ALIGN boundary of the stream), then `cnt` on one row of block 1."""
+    def make(rng):
+        rows = np.concatenate([
+            rng.integers(0, BLOCK, off), np.full((cnt,), BLOCK + 9),
+            rng.integers(2 * BLOCK, 3 * BLOCK, 50)]).astype(np.int32)
+        return 3 * BLOCK, rng.permutation(rows)
+    return make
+
+
+@pytest.mark.parametrize("make", [
+    _skew_zipf, _one_row, _skew_hot_sentinel, _skew_block_edge,
+    _run_at(0, UCAP), _run_at(0, 2 * UCAP), _run_at(0, WINDOW),
+    _run_at(0, 2 * WINDOW), _run_at(1000, UCAP),
+    _run_at(1000, WINDOW - 1000), _run_at(1000, WINDOW - 999),
+    _run_at(1023, 3 * UCAP + 17)],
+    ids=["zipf", "one_row", "hot_sentinel", "block_edge", "ends_at_ucap",
+         "ends_at_2ucap", "ends_at_window", "ends_at_2windows",
+         "offset_one_window", "offset_ends_at_window",
+         "offset_straddles_window", "offset_many_windows"])
+def test_skewed_updates_are_the_sequential_sum(make):
+    rng = np.random.default_rng(12)
+    num_rows, rows = make(rng)
+    payload = rng.normal(size=(rows.shape[0], 12)).astype(np.float32)
     got = sorted_scatter_accumulate(jnp.asarray(rows),
                                     jnp.asarray(payload), num_rows,
                                     interpret=True)
-    ref = _ref(rows, payload, num_rows)
-    np.testing.assert_allclose(np.asarray(got), ref, rtol=1e-4, atol=1e-4)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  _sequential(rows, payload, num_rows))
 
 
-def test_push_local_kernel_path_matches_xla(monkeypatch):
+@pytest.mark.parametrize("skew", [False, True], ids=["uniform", "zipf"])
+def test_push_local_kernel_path_matches_xla(skew):
     """Full push_local through the Pallas (interpret) accumulate equals
-    the XLA-scatter path — table values, states, and stats."""
+    the XLA-scatter path — table values, states, and stats. Under Zipf
+    rows (no dedup on one shard) the hottest row's run is over the
+    staging budget and the kernel walks it in windows."""
     import jax.numpy as jnp
     from paddlebox_tpu.core import flags as flagmod
     from paddlebox_tpu.embedding.lookup import push_local
@@ -80,8 +133,12 @@ def test_push_local_kernel_path_matches_xla(monkeypatch):
     w_width = d + 3 + ke + kw
     vals = rng.normal(size=(rps + 1, w_width)).astype(np.float32)
     vals[rps, :d + 3] = 0.0          # trash row pull columns zero
-    n = 256
-    rows = rng.integers(0, rps, n).astype(np.int32)
+    n = 30_000 if skew else 256
+    if skew:
+        rows = zipf_rows(rng, n, rps)
+        assert np.bincount(rows).max() > UCAP
+    else:
+        rows = rng.integers(0, rps, n).astype(np.int32)
     rows[::5] = rps                  # padding entries -> trash row
     g_emb = rng.normal(size=(n, d)).astype(np.float32)
     g_w = rng.normal(size=(n,)).astype(np.float32)
@@ -107,18 +164,19 @@ def test_push_local_kernel_path_matches_xla(monkeypatch):
     b = run("interpret")
     # Trash-row optimizer state may differ (kernel drops trash updates;
     # the XLA path counts them) — everything consumable must match.
-    np.testing.assert_allclose(b[:rps], a[:rps], rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(b[:rps], a[:rps], atol=1e-6,
+                               rtol=1e-6 if skew else 1e-5)
     np.testing.assert_allclose(b[rps, :d + 3], a[rps, :d + 3], atol=0)
 
 
 def test_sentinel_stays_off_the_books_at_non_multiple_num_rows():
     """num_rows NOT a multiple of BLOCK + thousands of concentrated
     sentinel entries: they must neither corrupt the result nor count
-    toward any block's run (which would permanently force the XLA
-    fallback)."""
+    toward any block's run (the last block would walk every padding
+    lane's update for nothing)."""
     rng = np.random.default_rng(4)
     num_rows = BLOCK + 1           # rows_per_shard+1 shape, the real case
-    n = 9000                       # > UCAP sentinels if they clustered
+    n = 9000                       # > UCAP sentinels, clustered
     rows = rng.integers(0, num_rows, n).astype(np.int32)
     rows[::2] = num_rows           # half the entries are padding
     payload = rng.normal(size=(n, 6)).astype(np.float32)

@@ -127,10 +127,10 @@ def test_ctr_megastep_one_scan_unchanged_per_step_budget():
 def _walk_eqns(jaxpr, in_cond=False):
     """Yield (primitive_name, eqn, inside_cond_branch) over the whole
     program. ``inside_cond_branch`` marks ops that exist only in a
-    lax.cond arm — the sorted-stream kernels keep their exact XLA
-    fallback there (the hot-row guard), and the budget below must
-    distinguish the fallback's table-sized gather/scatter from one on
-    the hot path."""
+    lax.cond / lax.switch arm — the sorted-stream gather keeps its
+    exact XLA net there (more distinct rows asked of one block than the
+    kernel budget), and the budget below must distinguish that arm's
+    table-sized gather from one on the hot path."""
     for eqn in jaxpr.eqns:
         yield eqn.primitive.name, eqn, in_cond
         inner_cond = in_cond or eqn.primitive.name == "cond"
@@ -145,10 +145,12 @@ def test_ctr_step_pallas_mode_no_table_gather_scatter_one_sort():
     """The Pallas sorted-stream pair (sparse_gather_kernel +
     sparse_scatter_kernel = pallas) must leave ZERO XLA gathers reading
     the table and ZERO XLA scatters building the [block, aw] grad
-    accumulator on the hot path (the exact fallbacks live inside the
-    hot-row lax.cond arms only), and the shared pull+push layout must
-    pay exactly ONE argsort per width group — the whole point of
-    sharing compute_bucketing's stream layout."""
+    accumulator on the hot path (the gather's exact XLA net lives
+    inside a lax.switch arm only; the scatter has no XLA arm at all),
+    and the shared pull+push layout must pay exactly ONE sort per width
+    group, arms included — the whole point of sharing
+    compute_bucketing's stream layout; the gather's distinct tier
+    compacts its stream without a second one."""
     import jax.tree_util as jtu
 
     from paddlebox_tpu.core import flags as flagmod
@@ -196,7 +198,7 @@ def test_ctr_step_pallas_mode_no_table_gather_scatter_one_sort():
             if prim == "sort":
                 sorts += 1
             if in_cond or not eqn.invars:
-                continue  # the hot-row fallback arm, by design
+                continue  # the gather's XLA net arm, by design
             shp = tuple(getattr(eqn.invars[0], "aval", None).shape
                         if hasattr(eqn.invars[0], "aval") else ())
             if prim == "gather" and shp == (block, w):
