@@ -19,7 +19,11 @@ Phases (a failure in any is fatal; nothing is retried on another path):
              PredictClient over the wire, bit-identical to direct predict
 5. gpt       bench.py's GPT config: two train steps on the flash path,
              step-1 loss against the ring (XLA) attention of the same model
-6. facts     compile seconds / cache hits / peak memory per phase
+6. nemotron  a three-layer M*E stack (Mamba-2, grouped-query attention,
+             latent experts) at published widths: the scan kernels against
+             the sequential recurrence, grouped-head flash against its
+             reference, two train steps with no dropped assignment
+7. facts     compile seconds / cache hits / peak memory per phase
 
 The report goes to <out>/chip_smoke_report.json and to stdout; the last
 line of stdout is {"ok": true, "device": {...}}. This script claims no
@@ -51,6 +55,12 @@ FULL = {
     "gpt_batch_per_chip": 4, "flash": (4, 1024, 16, 64),
     "seqpool": (65536, 16, 16384), "serve_requests": 48, "link_mib": 256,
     "auc_floor": 0.7,
+    # benchmarks/configs/nemotron3_super_120b.json cut to one layer of
+    # each kind and 2,048 positions; every width is the published one
+    # (NemotronHConfig's defaults).
+    "nemotron": {"vocab_size": 16384, "pattern": "M*E",
+                 "experts_held": (0, 8)},
+    "nemotron_seq": 2048,
 }
 # Rehearsal: same code, toy sizes, Pallas kernels interpreted.
 TOY = {
@@ -62,6 +72,16 @@ TOY = {
     "gpt_cut": "toy", "gpt_batch_per_chip": 2, "flash": (1, 128, 2, 64),
     "seqpool": (1024, 16, 256), "serve_requests": 32, "link_mib": 4,
     "auc_floor": 0.6,
+    "nemotron": {"vocab_size": 256, "hidden_size": 64, "pattern": "M*E",
+                 "mamba_num_heads": 4, "mamba_head_dim": 32,
+                 "ssm_state_size": 16, "n_groups": 2, "chunk_size": 16,
+                 "num_attention_heads": 4, "num_key_value_heads": 2,
+                 "head_dim": 16, "n_routed_experts": 8,
+                 "experts_held": (0, 2), "num_experts_per_tok": 2,
+                 "moe_latent_size": 32, "moe_intermediate_size": 48,
+                 "moe_shared_expert_intermediate_size": 64,
+                 "kernels": "interpret"},
+    "nemotron_seq": 48,
 }
 
 
@@ -787,6 +807,146 @@ def phase_gpt(sm: Smoke, out: dict):
 
 
 # ---------------------------------------------------------------------------
+# Phase 6: the hybrid state-space / attention / expert stack
+# ---------------------------------------------------------------------------
+
+def phase_nemotron(sm: Smoke, out: dict):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    import optax
+
+    from paddlebox_tpu.core import flags
+    from paddlebox_tpu.models.nemotron_h import (
+        NemotronHConfig, init_nemotron_h, make_nemotron_h_train_step)
+    from paddlebox_tpu.ops.pallas_kernels import (
+        flash_attention, flash_attention_reference)
+    from paddlebox_tpu.ops.pallas_kernels.ssd_scan import (
+        ssd_scan, ssd_scan_reference)
+    from paddlebox_tpu.parallel import HybridTopology, build_mesh
+    ndev = len(sm.devices)
+    cfg = NemotronHConfig(**sm.cfg["nemotron"])
+    seq, interp = sm.cfg["nemotron_seq"], sm.rehearsal
+    out.update({"config": {k: getattr(cfg, k) for k in (
+        "pattern", "hidden_size", "mamba_num_heads", "mamba_head_dim",
+        "ssm_state_size", "n_groups", "chunk_size", "num_attention_heads",
+        "num_key_value_heads", "head_dim", "n_routed_experts",
+        "experts_held", "num_experts_per_tok", "moe_latent_size",
+        "moe_intermediate_size", "moe_shared_expert_intermediate_size",
+        "vocab_size")}, "seq": seq})
+
+    def rel(a, b):
+        return float(jnp.linalg.norm((a - b).ravel())
+                     / jnp.linalg.norm(b.ravel()))
+
+    def out_and_grads(fn, args, wgt):
+        def loss(*a):
+            o = fn(*a)
+            return jnp.sum(o * wgt), o
+        (_, o), g = jax.jit(jax.value_and_grad(
+            loss, argnums=tuple(range(len(args))), has_aux=True))(*args)
+        return (o,) + g
+
+    # The scan kernels at the layer's shapes against the recurrence run
+    # one position at a time. The kernels round their matmul operands to
+    # bfloat16 (state and decay stay float32): 2^-7 of each tensor's norm;
+    # 2^-6 for the step sizes' gradient and 2^-4 for the per-head decay
+    # rates', which are small sums over every position of large terms of
+    # both signs.
+    h, p = cfg.mamba_num_heads, cfg.mamba_head_dim
+    g, n = cfg.n_groups, cfg.ssm_state_size
+    ks = jax.random.split(jax.random.PRNGKey(21), 7)
+    args = (jax.random.normal(ks[0], (1, seq, h, p)),
+            jax.nn.softplus(jax.random.normal(ks[1], (1, seq, h)) - 3.0),
+            -jnp.exp(jax.random.uniform(ks[2], (h,), minval=0.0,
+                                        maxval=2.7)),
+            jax.random.normal(ks[3], (1, seq, g, n)) * n ** -0.5,
+            jax.random.normal(ks[4], (1, seq, g, n)),
+            jax.random.normal(ks[5], (h,)))
+    wgt = jax.random.normal(ks[6], (1, seq, h, p))
+    got = out_and_grads(lambda *a: ssd_scan(
+        *a, chunk=cfg.chunk_size, use_pallas=True, interpret=interp),
+        args, wgt)
+    with jax.default_matmul_precision("highest"):
+        want = out_and_grads(ssd_scan_reference, args, wgt)
+    sc = out["ssd_scan"] = {"shape": [1, seq, h, p, g, n], "rel_err": {},
+                            "tol": {}}
+    for name, a, b in zip(("y", "dx", "ddt", "da", "db", "dc", "dd"),
+                          got, want):
+        check(bool(jnp.all(jnp.isfinite(a))), f"ssd_scan {name} not finite")
+        sc["rel_err"][name] = rel(a, b)
+        sc["tol"][name] = {"ddt": 2.0 ** -6, "da": 2.0 ** -4}.get(
+            name, 2.0 ** -7)
+        check(sc["rel_err"][name] <= sc["tol"][name],
+              f"ssd_scan {name} rel err {sc['rel_err'][name]} > "
+              f"{sc['tol'][name]}")
+    del got, want, args
+
+    # Grouped-head flash at the layer's shapes against the XLA reference
+    # at full precision; the bound as in the kernels phase.
+    hq, hkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                   cfg.head_dim)
+    ks = jax.random.split(jax.random.PRNGKey(22), 4)
+    qkv = (jax.random.normal(ks[0], (1, seq, hq, hd)),
+           jax.random.normal(ks[1], (1, seq, hkv, hd)),
+           jax.random.normal(ks[2], (1, seq, hkv, hd)))
+    wgt = jax.random.normal(ks[3], (1, seq, hq, hd))
+
+    def xla_ref(q, k, v):
+        return flash_attention_reference(q, k, v, causal=True)
+    got = out_and_grads(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, use_pallas=True, interpret=interp), qkv, wgt)
+    ref_default = out_and_grads(xla_ref, qkv, wgt)
+    with jax.default_matmul_precision("highest"):
+        ref = out_and_grads(xla_ref, qkv, wgt)
+    fl = out["flash_attention_gqa"] = {"shape": [1, seq, hq, hkv, hd],
+                                       "rel_err": {}, "tol": {}}
+    for name, a, d, b in zip(("out", "dq", "dk", "dv"), got, ref_default,
+                             ref):
+        check(bool(jnp.all(jnp.isfinite(a))), f"gqa flash {name} not finite")
+        fl["rel_err"][name] = rel(a, b)
+        fl["tol"][name] = max(2.0 ** -8, 4 * rel(d, b))
+        check(fl["rel_err"][name] <= fl["tol"][name],
+              f"gqa flash {name} rel err {fl['rel_err'][name]} > "
+              f"{fl['tol'][name]}")
+    del got, ref, ref_default, qkv
+
+    # Two train steps through the normal path.
+    mesh = build_mesh(HybridTopology(dp=ndev))
+    params, specs = init_nemotron_h(jax.random.PRNGKey(0), cfg)
+    opt = optax.adafactor(1e-3)
+    opt_state = opt.init(params)
+    rng = np.random.default_rng(0)
+    toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (ndev, seq + 1)),
+                       jnp.int32)
+    out["n_params"] = sum(int(np.prod(p.shape))
+                          for p in jax.tree_util.tree_leaves(params))
+    flags.resolved_kernels(reset=True)
+    step = make_nemotron_h_train_step(cfg, mesh, specs, opt)
+    losses = out["losses"] = []
+    load = None
+    for _ in range(2):
+        params, opt_state, loss, aux = step(params, opt_state, toks[:, :-1],
+                                            toks[:, 1:])
+        losses.append(float(loss))
+        load = np.asarray(aux["load"])
+        check(int(np.asarray(aux["dropped"]).sum()) == 0,
+              f"dropped assignments: {np.asarray(aux['dropped'])}")
+    out["load_last_step"] = load.tolist()
+    resolved = out["resolved_kernels"] = flags.resolved_kernels()
+    mode = "interpret" if sm.rehearsal else "pallas"
+    want = {"nemotron_ssd": [mode], "nemotron_attention": [mode],
+            "ssd_scan": [mode], "flash_attention": [mode],
+            "nemotron_moe_dispatch": ["sort_ragged_dot"]}
+    check(all(resolved.get(k) == v for k, v in want.items()),
+          f"kernels resolved to {resolved}, want {want}")
+    check(load.sum() > 0, "no held expert served any assignment")
+    check(all(np.isfinite(losses)), f"losses not finite: {losses}")
+    check(losses[1] < losses[0],
+          f"loss did not fall on the repeated batch: {losses}")
+
+
+# ---------------------------------------------------------------------------
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
@@ -827,6 +987,7 @@ def main(argv=None) -> int:
                 phase_deepfm(sm, rec, tmpdir)))
             sm.phase("predict", lambda rec: phase_predict(sm, rec, *held))
             sm.phase("gpt", lambda rec: phase_gpt(sm, rec))
+            sm.phase("nemotron", lambda rec: phase_nemotron(sm, rec))
         sm.report["facts"] = {
             "total_seconds": round(time.perf_counter() - t0, 1),
             "compile_seconds": round(sum(

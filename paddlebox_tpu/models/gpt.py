@@ -31,6 +31,7 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from paddlebox_tpu.core import flags
+from paddlebox_tpu.models.train_step import make_train_step
 from paddlebox_tpu.parallel import pp as pplib
 from paddlebox_tpu.parallel import sp as splib
 from paddlebox_tpu.parallel import tp as tplib
@@ -391,16 +392,4 @@ def make_gpt_train_step(cfg: GPTConfig, mesh: Mesh, specs: Dict,
         raise ValueError(f"unknown pipeline schedule {schedule!r}; "
                          "choose 'gpipe', '1f1b', or 'interleaved_1f1b'")
 
-    def step(params, opt_state, tokens, targets):
-        loss, grads = vg(params, tokens, targets)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = jax.tree.map(lambda p, u: p + u, params, updates)
-        return params, opt_state, loss
-
-    # out_shardings (a (params, opt_state, loss) pytree) lets a caller
-    # pin the outputs — the ZeRO bench path shards opt_state over dp and
-    # must pin params replicated, or the sharded state inputs would leak
-    # their sharding into p+u (accidental ZeRO-3).
-    jit_kw = {} if out_shardings is None else {
-        "out_shardings": out_shardings}
-    return jax.jit(step, donate_argnums=(0, 1), **jit_kw)
+    return make_train_step(vg, optimizer, out_shardings=out_shardings)

@@ -1,0 +1,328 @@
+"""Hybrid state-space / attention / expert decoder (``nemotron_h``).
+
+A stack of single-mixer blocks ``x <- x + mixer(RMSNorm(x))`` whose mixer
+is chosen per layer by a pattern string: ``M`` Mamba-2, ``*`` grouped-query
+attention, ``E`` a latent mixture of experts with one shared expert.
+
+- ``M``: ``[z | xBC | dt] = x W_in``; ``xBC <- silu(causal depthwise
+  conv(xBC) + b)``; ``xBC -> x_h, B, C``; ``dt <- softplus(dt + dt_bias)``;
+  the selective scan (``ops/pallas_kernels/ssd_scan.py``); ``y <-
+  RMSNorm_grouped(y * silu(z))``; ``y W_out``.
+- ``*``: causal softmax attention, more query heads than key/value heads
+  (``ops/pallas_kernels/flash_attention.py``), no biases, no rotary
+  embedding.
+- ``E``: sigmoid scores over all routed experts, the top k chosen
+  (``parallel/moe.py``); the routed experts live in a latent space
+  (``W_down``, ``W_up``) and the layer computes the part of the routed sum
+  that the experts it holds (``experts_held``) give; the shared expert
+  sees the full-width input; squared ReLU.
+
+Built like ``models/gpt.py``: one ``shard_map`` over the hybrid mesh,
+vocabulary-parallel embedding and cross entropy over ``mp``, batch over
+the data axes; every other weight is whole on every device. The layers
+differ, so they are a Python list (no ``lax.scan`` over equal blocks), and
+each is rematerialised (``jax.checkpoint``): what a layer keeps for its
+backward pass is its input.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.sharding import Mesh, PartitionSpec as P
+
+from paddlebox_tpu.core import flags, trace
+from paddlebox_tpu.models.gpt import _data_axes
+from paddlebox_tpu.models.train_step import make_train_step
+from paddlebox_tpu.ops.pallas_kernels.flash_attention import flash_attention
+from paddlebox_tpu.ops.pallas_kernels.ssd_scan import ssd_scan
+from paddlebox_tpu.parallel import moe as moelib
+from paddlebox_tpu.parallel import tp as tplib
+
+
+@dataclasses.dataclass(frozen=True)
+class NemotronHConfig:
+    vocab_size: int = 131072
+    hidden_size: int = 4096
+    # one letter per layer: M Mamba-2, * attention, E experts
+    pattern: str = "MEMEMEM*EME"
+    # depth of the published model: scales the out-projections' initial
+    # values (rescale_prenorm_residual) whatever part of it is built
+    num_hidden_layers: int = 88
+    norm_eps: float = 1e-5
+    mamba_num_heads: int = 128
+    mamba_head_dim: int = 64
+    ssm_state_size: int = 128
+    n_groups: int = 8
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 2
+    head_dim: int = 128
+    n_routed_experts: int = 512         # what the router scores
+    experts_held: Tuple[int, int] = (0, 512)    # (first, count) built here
+    num_experts_per_tok: int = 22
+    moe_latent_size: int = 1024
+    moe_intermediate_size: int = 2688
+    moe_shared_expert_intermediate_size: int = 5376
+    routed_scaling_factor: float = 5.0
+    # "auto": the Pallas kernels on a TPU, their XLA references elsewhere;
+    # "interpret": the kernels through the Pallas interpreter (tests);
+    # "xla": the references
+    kernels: str = "auto"
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        return self.mamba_inner + 2 * self.n_groups * self.ssm_state_size
+
+
+def _kernel_mode(cfg: NemotronHConfig) -> Dict:
+    if cfg.kernels not in ("auto", "interpret", "xla"):
+        raise ValueError(f"unknown kernels mode {cfg.kernels!r}; choose "
+                         "from 'auto', 'interpret', 'xla'")
+    interpret = cfg.kernels == "interpret"
+    use = interpret or (cfg.kernels == "auto"
+                        and flags.pallas_kernels_enabled())
+    return {"use_pallas": use, "interpret": interpret,
+            "name": "interpret" if interpret else "pallas" if use
+            else "xla"}
+
+
+# -- parameters --------------------------------------------------------------
+
+def _normal(key, shape, scale=0.02):
+    return jax.random.normal(key, shape, jnp.float32) * scale
+
+
+def _init_mamba(key, cfg: NemotronHConfig, out_scale):
+    d, di, h = cfg.hidden_size, cfg.mamba_inner, cfg.mamba_num_heads
+    k = jax.random.split(key, 5)
+    # step sizes log-uniform in [time_step_min, time_step_max], stored
+    # through the inverse of softplus
+    dt = jnp.exp(jax.random.uniform(k[2], (h,)) * (
+        math.log(cfg.time_step_max) - math.log(cfg.time_step_min))
+        + math.log(cfg.time_step_min))
+    dt = jnp.maximum(dt, cfg.time_step_floor)
+    return {
+        "norm": jnp.ones((d,)),
+        "w_in": _normal(k[0], (d, di + cfg.conv_dim + h)),
+        "conv_w": jax.random.uniform(
+            k[1], (cfg.conv_kernel, cfg.conv_dim), jnp.float32, -1.0, 1.0)
+        * cfg.conv_kernel ** -0.5,
+        "conv_b": jnp.zeros((cfg.conv_dim,)),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "a_log": jnp.log(jax.random.uniform(k[3], (h,), jnp.float32,
+                                            1.0, 16.0)),
+        "d": jnp.ones((h,)),
+        "gnorm": jnp.ones((di,)),
+        "w_out": _normal(k[4], (di, d), out_scale),
+    }
+
+
+def _init_attention(key, cfg: NemotronHConfig, out_scale):
+    d, hd = cfg.hidden_size, cfg.head_dim
+    k = jax.random.split(key, 4)
+    return {
+        "norm": jnp.ones((d,)),
+        "wq": _normal(k[0], (d, cfg.num_attention_heads * hd)),
+        "wk": _normal(k[1], (d, cfg.num_key_value_heads * hd)),
+        "wv": _normal(k[2], (d, cfg.num_key_value_heads * hd)),
+        "wo": _normal(k[3], (cfg.num_attention_heads * hd, d), out_scale),
+    }
+
+
+def _init_experts(key, cfg: NemotronHConfig, out_scale):
+    d, lat = cfg.hidden_size, cfg.moe_latent_size
+    inner, shared = (cfg.moe_intermediate_size,
+                     cfg.moe_shared_expert_intermediate_size)
+    held = cfg.experts_held[1]
+    k = jax.random.split(key, 7)
+    return {
+        "norm": jnp.ones((d,)),
+        "gate": _normal(k[0], (d, cfg.n_routed_experts)),
+        # e_score_correction_bias: steers the choice, takes no gradient
+        "bias": jnp.zeros((cfg.n_routed_experts,)),
+        "w_down": _normal(k[1], (d, lat)),
+        "w_up": _normal(k[2], (lat, d), out_scale),
+        "w1": _normal(k[3], (held, lat, inner)),
+        "w2": _normal(k[4], (held, inner, lat), out_scale),
+        "ws1": _normal(k[5], (d, shared)),
+        "ws2": _normal(k[6], (shared, d), out_scale),
+    }
+
+
+_INIT = {"M": _init_mamba, "*": _init_attention, "E": _init_experts}
+
+
+def init_nemotron_h(rng: jax.Array, cfg: NemotronHConfig
+                    ) -> Tuple[Dict, Dict]:
+    """Returns (params, partition_specs); ``params["layers"]`` is a list
+    with one dict per letter of ``cfg.pattern``. normal(0, 0.02) weights,
+    out-projections scaled by 1 / sqrt(num_hidden_layers)."""
+    unknown = set(cfg.pattern) - set(_INIT)
+    if unknown:
+        raise ValueError(f"pattern {cfg.pattern!r} has letters "
+                         f"{sorted(unknown)}; known: M, *, E")
+    with trace.span("nemotron_h/init", layers=len(cfg.pattern)):
+        keys = jax.random.split(rng, len(cfg.pattern) + 2)
+        out_scale = 0.02 / math.sqrt(cfg.num_hidden_layers)
+        params = {
+            "embed": _normal(keys[-2], (cfg.vocab_size, cfg.hidden_size)),
+            "layers": [_INIT[letter](keys[i], cfg, out_scale)
+                       for i, letter in enumerate(cfg.pattern)],
+            "norm_f": jnp.ones((cfg.hidden_size,)),
+            "head": _normal(keys[-1], (cfg.hidden_size, cfg.vocab_size)),
+        }
+        specs = jax.tree.map(lambda _: P(), params)
+        specs["embed"] = P("mp", None)      # vocabulary-parallel
+        specs["head"] = P(None, "mp")
+    return params, specs
+
+
+# -- layers ------------------------------------------------------------------
+
+def _rms(x, gain, eps):
+    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+    return x * lax.rsqrt(var + eps) * gain
+
+
+def _dot(x, w):
+    return jnp.dot(x, w, preferred_element_type=jnp.float32)
+
+
+def _relu2(x):
+    return jnp.square(jax.nn.relu(x))
+
+
+def _mamba(p, h, cfg: NemotronHConfig):
+    b, s, _ = h.shape
+    di, gn = cfg.mamba_inner, cfg.n_groups * cfg.ssm_state_size
+    heads, k = cfg.mamba_num_heads, cfg.conv_kernel
+    z, xbc, dt = jnp.split(_dot(h, p["w_in"]), [di, di + cfg.conv_dim],
+                           axis=-1)
+    # causal depthwise convolution: position t sees t-k+1 .. t
+    padded = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0)))
+    xbc = jax.nn.silu(sum(padded[:, i:i + s] * p["conv_w"][i]
+                          for i in range(k)) + p["conv_b"])
+    xs, bm, cm = jnp.split(xbc, [di, di + gn], axis=-1)
+    mode = _kernel_mode(cfg)
+    flags.note_kernel("nemotron_ssd", mode["name"])
+    y = ssd_scan(
+        xs.reshape(b, s, heads, cfg.mamba_head_dim),
+        jax.nn.softplus(dt + p["dt_bias"]), -jnp.exp(p["a_log"]),
+        bm.reshape(b, s, cfg.n_groups, cfg.ssm_state_size),
+        cm.reshape(b, s, cfg.n_groups, cfg.ssm_state_size), p["d"],
+        chunk=cfg.chunk_size, use_pallas=mode["use_pallas"],
+        interpret=mode["interpret"])
+    y = y.reshape(b, s, di) * jax.nn.silu(z)
+    y = _rms(y.reshape(b, s, cfg.n_groups, di // cfg.n_groups),
+             p["gnorm"].reshape(cfg.n_groups, -1), cfg.norm_eps)
+    return _dot(y.reshape(b, s, di), p["w_out"]), None
+
+
+def _attention(p, h, cfg: NemotronHConfig):
+    b, s, _ = h.shape
+    hd = cfg.head_dim
+    q = _dot(h, p["wq"]).reshape(b, s, cfg.num_attention_heads, hd)
+    kk = _dot(h, p["wk"]).reshape(b, s, cfg.num_key_value_heads, hd)
+    v = _dot(h, p["wv"]).reshape(b, s, cfg.num_key_value_heads, hd)
+    mode = _kernel_mode(cfg)
+    flags.note_kernel("nemotron_attention", mode["name"])
+    attn = flash_attention(q, kk, v, causal=True,
+                           use_pallas=mode["use_pallas"],
+                           interpret=mode["interpret"])
+    return _dot(attn.reshape(b, s, -1), p["wo"]), None
+
+
+def _experts(p, h, cfg: NemotronHConfig):
+    b, s, d = h.shape
+    x = h.reshape(b * s, d)
+    idx, weights = moelib.topk_sigmoid_router(
+        x, p["gate"], p["bias"], k=cfg.num_experts_per_tok,
+        scaling=cfg.routed_scaling_factor)
+
+    def held_experts(rows, sizes):
+        return lax.ragged_dot(_relu2(lax.ragged_dot(rows, p["w1"], sizes)),
+                              p["w2"], sizes)
+    flags.note_kernel("nemotron_moe_dispatch", "sort_ragged_dot")
+    routed, counts = moelib.dropless_dispatch(
+        _dot(x, p["w_down"]), idx, weights, cfg.experts_held, held_experts)
+    y = _dot(routed, p["w_up"]) + _dot(_relu2(_dot(x, p["ws1"])), p["ws2"])
+    return y.reshape(b, s, d), counts
+
+
+_MIXER = {"M": _mamba, "*": _attention, "E": _experts}
+
+
+def nemotron_h_loss_fn(cfg: NemotronHConfig, mesh: Mesh, specs: Dict):
+    """Builds ``loss(params, tokens, targets) -> (mean cross entropy,
+    aux)``, shard_mapped over the hybrid mesh. tokens, targets ``[B, S]``
+    int32, B sharded over the data axes. ``aux`` counts what the expert
+    layers served, one row per ``E`` layer, summed over the data axes:
+    ``load`` ``[layers, held]`` assignments per held expert, ``dropped``
+    ``[layers]`` (0: the dispatch has no capacity)."""
+    for axis in ("pp", "sp", "ep"):
+        if int(mesh.shape[axis]) > 1:
+            raise ValueError(
+                f"nemotron_h on a mesh with {axis}={mesh.shape[axis]}: the "
+                "stack is one pipeline stage, the scan needs its sequence "
+                "whole and the expert layer has no exchange yet")
+    daxes = _data_axes(mesh)
+
+    def layer(letter):
+        def apply(lp, x):
+            y, counts = _MIXER[letter](lp, _rms(x, lp["norm"],
+                                                cfg.norm_eps), cfg)
+            return x + y, counts
+        return jax.checkpoint(apply)
+
+    def body(params, tokens, targets):
+        x = tplib.vocab_parallel_embedding(
+            {"table": params["embed"]}, tokens, axis="mp")
+        served = []
+        for letter, lp in zip(cfg.pattern, params["layers"]):
+            x, counts = layer(letter)(lp, x)
+            if counts is not None:
+                served.append(counts)
+        logits = _dot(_rms(x, params["norm_f"], cfg.norm_eps),
+                      params["head"])
+        losses = tplib.parallel_cross_entropy(logits, targets, axis="mp")
+        total = lax.psum(jnp.sum(losses), daxes)
+        count = lax.psum(jnp.asarray(losses.size, jnp.float32), daxes)
+        held = cfg.experts_held[1]
+        aux = {
+            "load": lax.psum(jnp.stack(
+                [c.load for c in served]) if served
+                else jnp.zeros((0, held), jnp.int32), daxes),
+            "dropped": lax.psum(jnp.stack(
+                [c.dropped for c in served]) if served
+                else jnp.zeros((0,), jnp.int32), daxes),
+        }
+        return total / count, aux
+
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(specs, P(daxes, None), P(daxes, None)),
+                         out_specs=(P(), P()), check_vma=False)
+
+
+def make_nemotron_h_train_step(cfg: NemotronHConfig, mesh: Mesh,
+                               specs: Dict, optimizer):
+    """Jitted ``(params, opt_state, tokens, targets) -> (params,
+    opt_state, loss, aux)`` with donation; ``aux`` as
+    ``nemotron_h_loss_fn`` returns it."""
+    with trace.span("nemotron_h/build_step", layers=len(cfg.pattern)):
+        vg = jax.value_and_grad(nemotron_h_loss_fn(cfg, mesh, specs),
+                                has_aux=True)
+        return make_train_step(vg, optimizer, has_aux=True)

@@ -18,6 +18,13 @@ flash backward.
 ``q_offset``/``k_offset`` shift the *global* positions used for causal
 masking, so the same kernel serves ring attention's per-step blocks
 (``parallel/sp.py``) where each device holds a rotated K/V shard.
+
+Grouped heads (H query heads over H_kv < H key/value heads, query head h
+reading key/value head ``h // (H / H_kv)``): K and V stay ``[B * H_kv, S,
+D]`` in HBM and the block index maps send each query head to its group's
+block, so nothing is repeated; the dK/dV kernel's inner grid axis runs
+over the group's query heads as well as the query blocks and sums them in
+its VMEM accumulators.
 """
 
 from __future__ import annotations
@@ -131,6 +138,7 @@ def _flash_fwd_call(qoff, koff, sk_real, q3, k3, v3, *, scale, causal,
     sk = k3.shape[1]
     nq, nk = sq // block_q, sk // block_k
     grid = (bh, nq, nk)
+    group = bh // k3.shape[0]           # query heads per key/value head
     smem = functools.partial(pl.BlockSpec, (1, 1),
                              memory_space=pltpu.SMEM)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
@@ -144,9 +152,11 @@ def _flash_fwd_call(qoff, koff, sk_real, q3, k3, v3, *, scale, causal,
             smem(lambda b, i, j: (0, 0)),
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0),
+            pl.BlockSpec((1, block_k, d),
+                         lambda b, i, j: (b // group, j, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0),
+            pl.BlockSpec((1, block_k, d),
+                         lambda b, i, j: (b // group, j, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=[
@@ -229,10 +239,12 @@ def _dq_kernel(qoff_ref, koff_ref, kreal_ref, q_ref, k_ref, v_ref,
 
 def _dkv_kernel(qoff_ref, koff_ref, kreal_ref, q_ref, k_ref, v_ref,
                 do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_acc,
-                dv_acc, *, scale, causal, block_q, block_k):
-    ki, qi, nq = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
+                dv_acc, *, scale, causal, block_q, block_k, nq):
+    # the inner axis runs over (query head of the group, query block)
+    ki, step, steps = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
+    qi = step % nq
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -251,7 +263,7 @@ def _dkv_kernel(qoff_ref, koff_ref, kreal_ref, q_ref, k_ref, v_ref,
         q = q_ref[0].astype(jnp.float32)
         dk_acc[:] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(step == steps - 1)
     def _():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
@@ -274,6 +286,7 @@ def _flash_dq_call(qoff, koff, sk_real, q3, k3, v3, do3, lse3, delta3, *,
     """The dq kernel's call and nothing else (see ``_flash_fwd_call``)."""
     bh, sq, d = q3.shape
     nq, nk = sq // block_q, k3.shape[1] // block_k
+    group = bh // k3.shape[0]
     smem, qspec, rspec = _bwd_specs(d)
     return pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
@@ -283,8 +296,8 @@ def _flash_dq_call(qoff, koff, sk_real, q3, k3, v3, do3, lse3, delta3, *,
             smem(lambda b, i, j: (0, 0)), smem(lambda b, i, j: (0, 0)),
             smem(lambda b, i, j: (0, 0)),
             qspec(block_q, lambda b, i, j: (b, i, 0)),
-            qspec(block_k, lambda b, i, j: (b, j, 0)),
-            qspec(block_k, lambda b, i, j: (b, j, 0)),
+            qspec(block_k, lambda b, i, j: (b // group, j, 0)),
+            qspec(block_k, lambda b, i, j: (b // group, j, 0)),
             qspec(block_q, lambda b, i, j: (b, i, 0)),
             rspec(block_q, lambda b, i, j: (b, 0, i)),
             rspec(block_q, lambda b, i, j: (b, 0, i)),
@@ -302,27 +315,28 @@ def _flash_dkv_call(qoff, koff, sk_real, q3, k3, v3, do3, lse3, delta3, *,
                     scale, causal, block_q, block_k, interpret):
     """The dk/dv kernel's call and nothing else (see ``_flash_fwd_call``)."""
     bh, sq, d = q3.shape
-    sk = k3.shape[1]
+    bkv, sk = k3.shape[:2]
     nq, nk = sq // block_q, sk // block_k
+    group = bh // bkv
     smem, qspec, rspec = _bwd_specs(d)
     return pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k),
-        grid=(bh, nk, nq),
+                          block_q=block_q, block_k=block_k, nq=nq),
+        grid=(bkv, nk, group * nq),
         in_specs=[
             smem(lambda b, j, i: (0, 0)), smem(lambda b, j, i: (0, 0)),
             smem(lambda b, j, i: (0, 0)),
-            qspec(block_q, lambda b, j, i: (b, i, 0)),
+            qspec(block_q, lambda b, j, i: (b * group + i // nq, i % nq, 0)),
             qspec(block_k, lambda b, j, i: (b, j, 0)),
             qspec(block_k, lambda b, j, i: (b, j, 0)),
-            qspec(block_q, lambda b, j, i: (b, i, 0)),
-            rspec(block_q, lambda b, j, i: (b, 0, i)),
-            rspec(block_q, lambda b, j, i: (b, 0, i)),
+            qspec(block_q, lambda b, j, i: (b * group + i // nq, i % nq, 0)),
+            rspec(block_q, lambda b, j, i: (b * group + i // nq, 0, i % nq)),
+            rspec(block_q, lambda b, j, i: (b * group + i // nq, 0, i % nq)),
         ],
         out_specs=[qspec(block_k, lambda b, j, i: (b, j, 0)),
                    qspec(block_k, lambda b, j, i: (b, j, 0))],
-        out_shape=[jax.ShapeDtypeStruct((bh, sk, d), k3.dtype),
-                   jax.ShapeDtypeStruct((bh, sk, d), v3.dtype)],
+        out_shape=[jax.ShapeDtypeStruct((bkv, sk, d), k3.dtype),
+                   jax.ShapeDtypeStruct((bkv, sk, d), v3.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
@@ -419,6 +433,9 @@ def flash_attention_reference(q, k, v, *, causal: bool = False,
     d = q.shape[-1]
     if scale is None:
         scale = float(d) ** -0.5
+    group = q.shape[2] // k.shape[2]
+    if group > 1:
+        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
     s = jnp.einsum("bqhd,bkhd->bqhk", q, k,
                    preferred_element_type=jnp.float32) * scale
     if causal:
@@ -438,7 +455,9 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     block_k: Optional[int] = None,
                     use_pallas: Optional[bool] = None,
                     interpret: bool = False) -> jax.Array:
-    """Flash attention over [B, S, H, D] tensors (differentiable).
+    """Flash attention over [B, S, H, D] tensors (differentiable); k and
+    v may carry fewer heads ``[B, S, H_kv, D]`` with H a multiple of H_kv
+    (grouped-query attention).
 
     ``use_pallas=None`` auto-selects: the Pallas kernel on TPU backends,
     the XLA reference elsewhere (``interpret=True`` forces the kernel in
@@ -465,6 +484,9 @@ def flash_attention(q, k, v, *, causal: bool = False,
                                          scale=scale, q_offset=q_offset,
                                          k_offset=k_offset)
     b, sq, h, d = q.shape
+    if h % k.shape[2] or k.shape[2] != v.shape[2]:
+        raise ValueError(f"{h} query heads do not group over "
+                         f"{k.shape[2]} key / {v.shape[2]} value heads")
     bq = _pick_block(max(sq, 1), block_q)
     bk = _pick_block(max(k.shape[1], 1), block_k)
     qoff = jnp.full((1, 1), q_offset, jnp.int32)

@@ -10,11 +10,20 @@ TPU-first: GShard-style static-shape dispatch — position-in-expert via
 cumsum over one-hot assignments, fixed capacity buffers, one all_to_all
 out and one back (replacing brpc/NCCL global_scatter/global_gather). The
 einsum-heavy dispatch/combine maps onto the MXU.
+
+Beside it, for layers with hundreds of experts and tens per token (where
+a ``[T, E, C]`` one-hot cannot exist): ``topk_sigmoid_router`` and
+``dropless_dispatch``, which sorts the assignments by expert and runs the
+experts as one grouped product over contiguous row segments. The layer is
+told which experts it holds (``held = (first, count)``): it routes over
+all of them, normalises over all chosen and computes the held part, which
+is what a chip of an expert-parallel deployment does between the two
+exchanges. No capacity, so no assignment is ever dropped.
 """
 
 from __future__ import annotations
 
-from typing import Callable, Dict, Tuple
+from typing import Callable, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -109,3 +118,84 @@ def moe_layer(gate_w: jax.Array, expert_params: Dict[str, jax.Array],
     y = jnp.einsum("tec,ecf->tf", combine.astype(returned.dtype), returned,
                    preferred_element_type=jnp.float32)
     return y.astype(x.dtype), aux
+
+
+# -- top-k sigmoid routing, dropless sort/segment dispatch -------------------
+
+def topk_sigmoid_router(x: jax.Array, gate_w: jax.Array, bias: jax.Array,
+                        *, k: int, scaling: float = 1.0
+                        ) -> Tuple[jax.Array, jax.Array]:
+    """Sigmoid scores over all experts, the ``k`` largest of ``score +
+    bias`` chosen, weighted by the scores themselves (the bias steers the
+    choice only, DeepSeek-V3's bias-corrected routing).
+
+    x [T, F]; gate_w [F, E]; bias [E]. Returns (idx [T, k] int32,
+    weights [T, k] float32 = scaling * s / sum of the chosen s). Scores
+    are float32 at full matmul precision: a choice between near-equal
+    experts must not turn on the MXU's bfloat16 pass.
+    """
+    f32 = jnp.float32
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(f32), gate_w.astype(f32), precision=lax.Precision.HIGHEST,
+        preferred_element_type=f32))
+    _, idx = lax.top_k(scores + lax.stop_gradient(bias.astype(f32)), k)
+    chosen = jnp.take_along_axis(scores, idx, axis=-1)
+    chosen = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+    return idx, chosen * scaling
+
+
+class DispatchCounts(NamedTuple):
+    """What one call of ``dropless_dispatch`` served."""
+    load: jax.Array       # [count] assignments per held expert
+    # [] held assignments whose rows no served block added to the result,
+    # counted where the rows are added: 0 unless a block was skipped
+    dropped: jax.Array
+
+
+def dropless_dispatch(u: jax.Array, idx: jax.Array, weights: jax.Array,
+                      held: Tuple[int, int],
+                      rows_fn: Callable[[jax.Array, jax.Array], jax.Array]
+                      ) -> Tuple[jax.Array, DispatchCounts]:
+    """Weighted sum over each token's chosen experts, restricted to the
+    experts ``first <= e < first + count`` this device holds.
+
+    u [T, F]; idx, weights [T, k]; ``rows_fn(rows [R, F], sizes [count])
+    -> [R, F]`` applies expert ``i`` to the ``sizes[i]`` consecutive rows
+    of its segment (``lax.ragged_dot`` over stacked expert weights).
+    Returns (r [T, F], counts).
+
+    Assignments are sorted by held expert (the others sort last and are
+    never touched) and served in blocks of T rows; a block past the last
+    held assignment is skipped by ``lax.cond``, so the work follows the
+    load and the static bound ``T * min(k, count)`` costs nothing.
+    """
+    t, k = idx.shape
+    first, count = held
+    flat_e, flat_w = idx.reshape(t * k), weights.reshape(t * k)
+    local = jnp.where((flat_e >= first) & (flat_e < first + count),
+                      flat_e - first, count)
+    order = jnp.argsort(local, stable=True).astype(jnp.int32)
+    load = jnp.sum(local[:, None] == jnp.arange(count)[None, :], axis=0,
+                   dtype=jnp.int32)
+    ends = jnp.cumsum(load)
+    starts, n_held = ends - load, ends[-1]
+    acc = (jnp.zeros(u.shape, jnp.float32), jnp.zeros((), jnp.int32))
+    for lo in range(0, t * min(k, count), t):
+        sizes = jnp.clip(ends - lo, 0, t) - jnp.clip(starts - lo, 0, t)
+
+        def serve(acc, lo=lo, sizes=sizes):
+            out, served = acc
+            sel = order[lo:lo + t]
+            tok = sel // k
+            # Rows past the last held assignment belong to no segment: a
+            # grouped product leaves them unwritten (whatever the buffer
+            # held), forward and in its transposes. Select them away on
+            # both sides, so that neither the values nor the cotangents
+            # of such rows go anywhere.
+            live = ((lo + jnp.arange(t)) < n_held)[:, None]
+            y = rows_fn(jnp.where(live, u[tok], 0.0), sizes)
+            y = jnp.where(live, y, 0.0) * flat_w[sel][:, None]
+            return out.at[tok].add(y), served + jnp.sum(live, dtype=jnp.int32)
+        acc = lax.cond(lo < n_held, serve, lambda a: a, acc)
+    out, served = acc
+    return out.astype(u.dtype), DispatchCounts(load, n_held - served)

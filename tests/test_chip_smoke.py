@@ -37,7 +37,7 @@ def test_rehearsal_runs_every_phase(tmp_path):
     assert report["ok"] is True and report["rehearsal"] is True
     assert report["platform"] == "cpu"
     assert list(report["phases"]) == [
-        "identity", "kernels", "deepfm", "predict", "gpt"]
+        "identity", "kernels", "deepfm", "predict", "gpt", "nemotron"]
     assert all(p["ok"] for p in report["phases"].values())
     deepfm = report["phases"]["deepfm"]
     assert deepfm["boundary_fused"] == 1
@@ -48,6 +48,11 @@ def test_rehearsal_runs_every_phase(tmp_path):
     assert all(p["kernel_fallback"] == 0 and p["lookup_overflow"] == 0
                for p in deepfm["passes"])
     assert report["phases"]["predict"]["bit_identical_to_direct_predict"]
+    nemotron = report["phases"]["nemotron"]
+    assert nemotron["resolved_kernels"]["nemotron_ssd"] == ["interpret"]
+    assert nemotron["losses"][1] < nemotron["losses"][0]
+    assert set(nemotron["ssd_scan"]["rel_err"]) == {
+        "y", "dx", "ddt", "da", "db", "dc", "dd"}
     # The rehearsal never uses the persistent compile cache.
     assert report["phases"]["identity"]["compile_cache_dir"] is None
 

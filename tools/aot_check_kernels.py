@@ -55,6 +55,7 @@ def main() -> None:
     from paddlebox_tpu.ops.pallas_kernels.sorted_gather import sorted_gather
     from paddlebox_tpu.ops.pallas_kernels.sorted_scatter import (
         sorted_scatter_accumulate)
+    from paddlebox_tpu.ops.pallas_kernels.ssd_scan import ssd_scan
 
     topo = tpu_topology("v5e:2x2x1")
     if topo is None:
@@ -82,6 +83,32 @@ def main() -> None:
                                         use_pallas=True).sum(),
         argnums=(0, 1, 2))).lower(q, q, q).compile()
     print("AOT flash_attention fwd+bwd [4, 1024, 16, 64]: OK", flush=True)
+
+    # The hybrid stack's kernels at published widths and the benchmark
+    # cell's 8,192 positions (benchmarks/configs/nemotron3_super_120b.json):
+    # 32 query heads over 2 key/value heads of 128, and the Mamba-2 scan
+    # with 128 heads of 64, state 128, 8 groups, chunks of 128, at both
+    # operand precisions.
+    q = sds((1, 8192, 32, 128), jnp.float32)
+    kv = sds((1, 8192, 2, 128), jnp.float32)
+    jax.jit(jax.grad(
+        lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                        use_pallas=True).sum(),
+        argnums=(0, 1, 2))).lower(q, kv, kv).compile()
+    print("AOT flash_attention grouped fwd+bwd [1, 8192, 32/2, 128]: OK",
+          flush=True)
+    scan_args = (sds((1, 8192, 128, 64), jnp.float32),
+                 sds((1, 8192, 128), jnp.float32), sds((128,), jnp.float32),
+                 sds((1, 8192, 8, 128), jnp.float32),
+                 sds((1, 8192, 8, 128), jnp.float32),
+                 sds((128,), jnp.float32))
+    for mxu in (jnp.bfloat16, jnp.float32):
+        jax.jit(jax.grad(
+            lambda *a: ssd_scan(*a, chunk=128, use_pallas=True,
+                                mxu_dtype=mxu).sum(),
+            argnums=tuple(range(6)))).lower(*scan_args).compile()
+        print(f"AOT ssd_scan fwd+bwd [1, 8192, 128, 64] "
+              f"{jnp.dtype(mxu).name}: OK", flush=True)
 
     n, d, rows = 65536, 16, 16384
     sc = sds((n,), jnp.float32)
