@@ -412,11 +412,13 @@ define_flag("ingest_file_retries", 1,
             "pipe_command) are never retried — they would fail again")
 define_flag("ingest_key_runs", True,
             "dedup each loaded chunk's keys into per-slot sorted runs "
-            "DURING ingest and serve pass_keys() as a linear k-way "
-            "merge of those runs (the sorted-run store build feed) "
-            "instead of one end-of-load sort over every id. False = the "
-            "r02 behavior (np.unique at feed time); results are "
-            "bit-identical either way")
+            "DURING ingest and unite them as they arrive (a helper "
+            "thread of the load merges runs of like size, the way a "
+            "log-structured tree compacts), so every slot's key set is "
+            "whole when the last chunk is in and pass_keys() only "
+            "unites the slots asked for, instead of one end-of-load "
+            "sort over every id. False = the r02 behavior (np.unique at "
+            "feed time); results are bit-identical either way")
 define_flag("wuauc_spill_records", 4_000_000,
             "per-user-AUC raw records held in RAM before spilling to "
             "uid-hash bucket files on disk (bounds eval-pass host memory; "

@@ -27,7 +27,7 @@ from typing import Callable, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from paddlebox_tpu.core import faults, flags, log, monitor
+from paddlebox_tpu.core import faults, flags, log, monitor, trace
 from paddlebox_tpu.data.channel import Channel, ClosedChannelError
 from paddlebox_tpu.data.columnar import ColumnarChunk, instances_to_chunk
 from paddlebox_tpu.data.parser import parse_lines
@@ -188,14 +188,19 @@ class Dataset:
         self._preload_threads: List[threading.Thread] = []
         self._reader_errors: List[BaseException] = []
         self._lock = threading.Lock()
-        # Sorted-run pass-key collection (round 13): per-slot sorted
-        # unique key runs, one per loaded chunk, deduped DURING ingest so
-        # pass_keys() is a linear k-way merge instead of one giant
-        # end-of-load sort. Valid only while every loaded chunk passed
-        # through _drain and no key-set-changing op ran.
-        self._key_runs: Dict[str, List[np.ndarray]] = {}
+        # Sorted-run pass-key collection: each loaded chunk's slots are
+        # deduped into sorted runs and united into their slot's merger
+        # WHILE the load runs (a helper thread of _drain), so when the
+        # last chunk is in, every slot holds its key set and pass_keys()
+        # only unites the slots asked for. Valid only while every loaded
+        # chunk passed through _drain and no key-set-changing op ran.
+        self._key_mergers: Dict[str, "SortedRunMerger"] = {}
         self._key_zero: Dict[str, bool] = {}
         self._key_runs_valid = True
+        # [runs handed to the mergers, runs folded into another by the
+        # time their load returned]: the merger's engagement (monitor
+        # ingest/key_runs, ingest/key_runs_merged_in_load).
+        self._key_run_counts = [0, 0]
         # Live ingest worker processes (multi-process path) — exposed so
         # tests/drills can kill one mid-load.
         self._ingest_procs: List = []
@@ -505,32 +510,76 @@ class Dataset:
         self._preload_threads = []
         self._raise_reader_errors()
 
-    def _collect_key_runs(self, chunk: ColumnarChunk) -> None:
-        """Dedup the chunk's per-slot keys into sorted runs DURING the
-        load (overlapping ingest) so pass_keys() becomes a linear k-way
-        merge instead of one end-of-load np.unique over every id (the
-        r02 feed-time sort). Bit-parity: merge(runs) == np.unique(concat)
-        — dedup_keys drops the 0 sentinel, so a seen-zero flag restores
-        it for the slots where the old path would have reported it."""
+    def _collect_key_runs(self, chunk: ColumnarChunk) -> int:
+        """Dedup the chunk's per-slot keys into sorted runs and unite
+        each into its slot's merger, so that pass_keys() has no merge
+        over every loaded id left to do. Returns the runs handed in.
+        Bit-parity: merge(runs) == np.unique(concat) — dedup_keys drops
+        the 0 sentinel, so a seen-zero flag restores it for the slots
+        where the exact path would have reported it."""
         from paddlebox_tpu.native.keymap_py import dedup_keys
-        runs: List[Tuple[str, np.ndarray, bool]] = []
+        from paddlebox_tpu.native.store_py import SortedRunMerger
+        added = 0
         for s, ids in chunk.sparse_ids.items():
-            if ids.size:
-                runs.append((s, dedup_keys(ids), bool((ids == 0).any())))
-        with self._lock:
-            if not self._key_runs_valid:
-                return
-            for s, run, zero in runs:
-                if run.size:
-                    self._key_runs.setdefault(s, []).append(run)
+            if not ids.size:
+                continue
+            run = dedup_keys(ids)
+            zero = run.size < ids.size and bool((ids == 0).any())
+            with self._lock:
+                if not self._key_runs_valid:
+                    return added
+                merger = self._key_mergers.get(s)
+                if merger is None:
+                    merger = self._key_mergers[s] = SortedRunMerger()
                 if zero:
                     self._key_zero[s] = True
+            # Outside the lock: one load's helper is the mergers' only
+            # writer, and pass_keys() reads them after the load.
+            merger.add_run(run)
+            added += bool(run.size)
+        return added
+
+    def _merge_key_runs(self, chunks: "queue.SimpleQueue") -> None:
+        """The load's key helper (a thread of _drain's, gone when _drain
+        returns): takes each chunk as it is drained, folds its runs in,
+        and when the channel has closed unites what each slot still
+        holds. The dedup and the merges are native calls that release
+        the GIL, so they run beside the parse and the drain."""
+        added = 0
+        try:
+            while True:
+                chunk = chunks.get()
+                if chunk is None:       # _drain's last word
+                    break
+                with trace.span("ingest/key_merge", rows=chunk.num_rows):
+                    added += self._collect_key_runs(chunk)
+            with self._lock:
+                mergers = list(self._key_mergers.values())
+            with trace.span("ingest/key_merge", slots=len(mergers)):
+                for m in mergers:
+                    m.merge()
+            merged = max(0, added - sum(m.num_runs for m in mergers))
+            with self._lock:
+                self._key_run_counts[0] += added
+                self._key_run_counts[1] += merged
+            monitor.add("ingest/key_runs", added)
+            monitor.add("ingest/key_runs_merged_in_load", merged)
+        except BaseException as e:  # surfaced by load_into_memory/wait
+            self._invalidate_key_runs()
+            with self._lock:
+                self._reader_errors.append(e)
 
     def _invalidate_key_runs(self) -> None:
         with self._lock:
             self._key_runs_valid = False
-            self._key_runs = {}
+            self._key_mergers = {}
             self._key_zero = {}
+
+    def key_run_counts(self) -> Tuple[int, int]:
+        """(key runs the loads handed to the mergers, runs already folded
+        into another when their load returned) since the last clear()."""
+        with self._lock:
+            return tuple(self._key_run_counts)
 
     def _drain(self, ch: Channel) -> None:
         sink = self.key_sink
@@ -543,12 +592,19 @@ class Dataset:
                     self._quality = quality.SlotHealthCollector()
                 qc = self._quality
         local: List[ColumnarChunk] = []
+        helper = None
+        if collect:
+            keyq: "queue.SimpleQueue" = queue.SimpleQueue()
+            helper = threading.Thread(target=self._merge_key_runs,
+                                      args=(keyq,), daemon=True,
+                                      name="pbx-ingest-keys")
+            helper.start()
         try:
             while True:
                 chunk = ch.get()
                 local.append(chunk)
                 if collect:
-                    self._collect_key_runs(chunk)
+                    keyq.put(chunk)
                 if qc is not None:
                     qc.observe_chunk(chunk)
                 if sink is not None:
@@ -557,15 +613,17 @@ class Dataset:
                         sink(keys)
         except ClosedChannelError:
             pass
+        finally:
+            if helper is not None:
+                keyq.put(None)
+                helper.join()
         with self._lock:
             self._chunks.extend(local)
             self._merged = None
-            if local and not collect:
-                # Runs no longer cover every loaded chunk — pass_keys
-                # falls back to the exact merged-sort path.
-                self._key_runs_valid = False
-                self._key_runs = {}
-                self._key_zero = {}
+        if local and not collect:
+            # Runs no longer cover every loaded chunk — pass_keys falls
+            # back to the exact merged-sort path.
+            self._invalidate_key_runs()
 
     def _merge(self) -> ColumnarChunk:
         with self._lock:
@@ -838,26 +896,27 @@ class Dataset:
         ``slots`` restricts to the given sparse slots — used by dim-grouped
         embedding engines that feed each width group its own key set.
 
-        Fast path (round 13): when the per-chunk sorted runs collected
-        during ingest still cover everything loaded, this is a linear
-        k-way merge of those runs — no end-of-load sort. Any operation
+        Fast path: while the runs united during the load still cover
+        everything loaded, every slot holds its finished key set and
+        this unites the slots asked for — log2(slots) levels over
+        distinct keys, not a merge over every loaded id. Any operation
         that changed the key set (global shuffle, chunk restore, disk
         reload) falls back to the exact merged-sort path."""
         with self._lock:
             runs_ok = self._key_runs_valid
             if runs_ok:
-                names = (list(self._key_runs) if slots is None
-                         else [s for s in slots if s in self._key_runs])
-                runs = [r for s in names for r in self._key_runs[s]]
+                names = (list(self._key_mergers) if slots is None
+                         else [s for s in slots if s in self._key_mergers])
+                mergers = [self._key_mergers[s] for s in names]
                 seen_zero = any(self._key_zero.get(s, False)
                                 for s in (self._key_zero if slots is None
                                           else slots))
         if runs_ok:
             from paddlebox_tpu.native.store_py import SortedRunMerger
-            merger = SortedRunMerger()
-            for r in runs:
-                merger.add_run(r)
-            keys = merger.merge()
+            union = SortedRunMerger()
+            for m in mergers:
+                union.add_run(m.merge())
+            keys = union.merge()
             if seen_zero:
                 keys = np.concatenate(
                     [np.zeros((1,), np.uint64), keys])
@@ -892,9 +951,10 @@ class Dataset:
         with self._lock:
             self._chunks.clear()
             self._merged = None
-            self._key_runs = {}
+            self._key_mergers = {}
             self._key_zero = {}
             self._key_runs_valid = True
+            self._key_run_counts = [0, 0]
             self._quality = None
         # Chunk finalizers unlink their shm segments as the refs die;
         # nothing else to do here (gc-immediate under CPython).

@@ -152,6 +152,9 @@ def load_library() -> Optional[ctypes.CDLL]:
         lib.pbx_merge_sorted.restype = None
         lib.pbx_merge_sorted.argtypes = [u64p, ctypes.c_int64, u64p,
                                          ctypes.c_int64, u64p, i64p]
+        lib.pbx_union_sorted.restype = ctypes.c_int64
+        lib.pbx_union_sorted.argtypes = [u64p, ctypes.c_int64, u64p,
+                                         ctypes.c_int64, u64p]
         lib.pbx_init_uniform.restype = None
         lib.pbx_init_uniform.argtypes = [u64p, ctypes.c_int64,
                                          ctypes.c_int64, ctypes.c_uint64,

@@ -439,6 +439,60 @@ void pbx_merge_sorted(const uint64_t* old_keys, int64_t n,
   });
 }
 
+// Union of two sorted unique key arrays (a[n], b[m], which may share
+// keys) into out (room for n + m); returns how many it holds. Threaded as
+// pbx_merge_sorted is: each thread owns an equal slice of b and the range
+// of a that sorts into it (a key equal to one of the slice's lies in that
+// range), writes its union where the slice would start were nothing
+// shared, and the slices are then packed to the left in order.
+int64_t pbx_union_sorted(const uint64_t* a, int64_t n, const uint64_t* b,
+                         int64_t m, uint64_t* out) {
+  if (m == 0 || n == 0) {
+    const uint64_t* src = m == 0 ? a : b;
+    if (n + m) std::memcpy(out, src, (n + m) * sizeof(uint64_t));
+    return n + m;
+  }
+  int nt = num_threads_for(n + m);
+  std::vector<int64_t> b_lo(nt + 1), a_lo(nt + 1), count(nt);
+  for (int t = 0; t <= nt; ++t) {
+    b_lo[t] = t * m / nt;
+    a_lo[t] = (t == 0) ? 0
+              : (t == nt ? n
+                 : std::lower_bound(a, a + n, b[b_lo[t]]) - a);
+  }
+  parallel_chunks(nt, nt, [&](int, int64_t tlo, int64_t thi) {
+    for (int64_t t = tlo; t < thi; ++t) {
+      int64_t ia = a_lo[t], ib = b_lo[t];
+      const int64_t ea = a_lo[t + 1], eb = b_lo[t + 1];
+      uint64_t* w = out + ia + ib;
+      const uint64_t* w0 = w;
+      while (ia < ea && ib < eb) {
+        const uint64_t x = a[ia], y = b[ib];
+        *w++ = x < y ? x : y;
+        ia += (x <= y);
+        ib += (y <= x);
+      }
+      if (ia < ea) {
+        std::memcpy(w, a + ia, (ea - ia) * sizeof(uint64_t));
+        w += ea - ia;
+      }
+      if (ib < eb) {
+        std::memcpy(w, b + ib, (eb - ib) * sizeof(uint64_t));
+        w += eb - ib;
+      }
+      count[t] = w - w0;
+    }
+  });
+  int64_t total = count[0];
+  for (int t = 1; t < nt; ++t) {
+    if (count[t])
+      std::memmove(out + total, out + a_lo[t] + b_lo[t],
+                   count[t] * sizeof(uint64_t));
+    total += count[t];
+  }
+  return total;
+}
+
 // Deterministic per-key uniform init (store.py _per_key_uniform contract):
 // out[i, j] = uniform(-scale, scale) from a murmur3-finalizer hash of
 // (key's low 32 bits, column j+1, seed) — order-independent; bit-exact

@@ -43,55 +43,62 @@ def is_sorted_unique_nonzero(keys: np.ndarray) -> bool:
 
 
 def merge_unique(a: np.ndarray, b: np.ndarray) -> np.ndarray:
-    """Union of two SORTED UNIQUE key arrays, O(n + m) — ss_locate drops
-    b's duplicates, merge_sorted interleaves the disjoint remainder (both
-    threaded native with exact numpy fallbacks)."""
+    """Union of two SORTED UNIQUE key arrays, O(n + m): one threaded
+    two-pointer pass (native ``pbx_union_sorted``); ``np.union1d``
+    without the library."""
     a = np.ascontiguousarray(a, np.uint64)
     b = np.ascontiguousarray(b, np.uint64)
     if a.size == 0:
         return b.copy() if b.base is not None else b
     if b.size == 0:
         return a
-    found, _ = ss_locate(a, b)
-    b_new = b[~found] if found.any() else b
-    if b_new.size == 0:
-        return a
-    merged, _ = merge_sorted(a, b_new)
-    return merged
+    lib = load_library()
+    if lib is not None:
+        out = np.empty((a.size + b.size,), np.uint64)
+        n = int(lib.pbx_union_sorted(_p(a, _u64p), a.size, _p(b, _u64p),
+                                     b.size, _p(out, _u64p)))
+        # ``out`` is ours alone: give back the room the shared keys left
+        out.resize((n,), refcheck=False)
+        return out
+    return np.union1d(a, b)
 
 
 class SortedRunMerger:
-    """Accumulates sorted unique key runs (one per ingest chunk) and
-    k-way merges them on demand — the sorted-run store build (round 13):
-    each chunk's dedup overlaps ingest, and the final merge is linear
-    instead of one giant end-of-pass sort. ``merge()`` is a balanced
-    pairwise tree (O(N log k) with k runs), bit-identical to
+    """Unites sorted unique key runs AS THEY ARRIVE, the way a
+    log-structured tree compacts: a new run is merged into the newest
+    held run while that one is at most twice its size, so the held runs
+    shrink by halves down the stack (never more than log2 N of them) and
+    a key is merged O(log k) times over k runs — the work of a balanced
+    tree, done while the producer is still producing. ``merge()`` unites
+    what is held, smallest first; the result is bit-identical to
     ``np.unique(concat(runs))``."""
 
     def __init__(self):
         self._runs: list = []
 
     def add_run(self, sorted_unique: np.ndarray) -> None:
-        if sorted_unique.size:
-            self._runs.append(np.ascontiguousarray(sorted_unique,
-                                                   np.uint64))
+        if not sorted_unique.size:
+            return
+        run = np.ascontiguousarray(sorted_unique, np.uint64)
+        runs = self._runs
+        while runs and runs[-1].size <= 2 * run.size:
+            run = merge_unique(runs.pop(), run)
+        runs.append(run)
 
     @property
     def num_runs(self) -> int:
+        """Runs held: what ``merge()`` still has to unite."""
         return len(self._runs)
 
     def merge(self) -> np.ndarray:
         runs = self._runs
         if not runs:
             return np.empty((0,), np.uint64)
-        while len(runs) > 1:
-            nxt = [merge_unique(runs[i], runs[i + 1])
-                   for i in range(0, len(runs) - 1, 2)]
-            if len(runs) % 2:
-                nxt.append(runs[-1])
-            runs = nxt
-        self._runs = runs
-        return runs[0]
+        run = runs.pop()
+        while runs:
+            run = merge_unique(runs.pop(), run)
+        runs.append(run)
+        return run
 
     def clear(self) -> None:
         self._runs = []

@@ -207,8 +207,13 @@ class DayRunner:
 
     # -- day loop ----------------------------------------------------------
 
-    def _load_dataset(self, day: str, pass_id: int,
-                      files: List[str]) -> Dataset:
+    def _load_dataset(self, day: str, pass_id: int, files: List[str], *,
+                      feed: bool) -> Dataset:
+        """Load a pass's files, hand the engine its keys (``feed``),
+        then shuffle: the one order every pass takes, preloaded or not.
+        A local shuffle permutes the rows of one key set, so the keys
+        are whole when the last chunk is in (the load unites them as it
+        goes) and the pass table builds while the rows are permuted."""
         faults.faultpoint("day_runner/load")
         ds = Dataset(self.feed_config,
                      num_reader_threads=self.num_reader_threads)
@@ -220,37 +225,38 @@ class DayRunner:
         with pipeline_stats.GLOBAL.busy("day_load"), \
                 trace.span("ingest/load", day=day, pass_id=pass_id):
             ds.load_into_memory()
+        if feed:
+            self._feed_keys(ds, day, pass_id)
         if self.shuffle:
             # Deterministic digest — hash(str) is randomized per
             # process, which would make recovery replays and per-rank
             # batch orders irreproducible.
             import zlib
             with trace.span("ingest/shuffle", day=day, pass_id=pass_id):
+                faults.faultpoint("day_runner/shuffle")
                 ds.local_shuffle(
                     seed=zlib.crc32(f"{day}:{pass_id}".encode()))
         return ds
 
-    def _feed_keys(self, ds: Dataset, day: str, pass_id: int, *,
-                   async_build: bool = True) -> None:
-        """Register an online pass's keys. Defaults to the async build:
-        with the split-key early build the engine overlaps everything it
-        legally can with the active pass (and with the dataset work of
-        THIS thread when no pass is active) — the serial build is only
-        for callers that need the build's errors raised here."""
+    def _feed_keys(self, ds: Dataset, day: str, pass_id: int) -> None:
+        """Register an online pass's keys with the async build: with the
+        split-key early build the engine overlaps everything it legally
+        can with the active pass, and with the shuffle of THIS thread;
+        begin_pass joins the build and raises its errors."""
         eng = self.trainer.engine
         with trace.span("ingest/pass_keys", day=day, pass_id=pass_id):
             keys = [ds.pass_keys(slots=g.slots) for g in eng.groups]
         # Parked on the engine's pending slot until the active pass's
         # begin_pass frees it.
         with trace.span("ingest/feed_pass", day=day, pass_id=pass_id):
-            eng.feed_pass(keys, async_build=async_build)
+            eng.feed_pass(keys, async_build=True)
 
     def _start_preload(self, day: str, pass_id: int, files: List[str]):
-        """Background: load pass k+1's data and kick its table build while
-        pass k trains. feed_pass blocks until pass k's begin_pass frees
-        the pending slot, and the build's store pull is internally
-        sequenced after pass k's end_pass write-back (split pull: only
-        the shared-key intersection waits)."""
+        """Background: load pass k+1's data, kick its table build and
+        shuffle while pass k trains. feed_pass blocks until pass k's
+        begin_pass frees the pending slot, and the build's store pull is
+        internally sequenced after pass k's end_pass write-back (split
+        pull: only the shared-key intersection waits)."""
         import threading
 
         out = {"ds": None, "error": None}
@@ -258,8 +264,8 @@ class DayRunner:
         def body():
             try:
                 faults.faultpoint("day_runner/preload")
-                out["ds"] = self._load_dataset(day, pass_id, files)
-                self._feed_keys(out["ds"], day, pass_id, async_build=True)
+                out["ds"] = self._load_dataset(day, pass_id, files,
+                                               feed=True)
             except BaseException as e:
                 out["error"] = e
 
@@ -409,12 +415,22 @@ class DayRunner:
         quality.GLOBAL.set_pass_context(day, pass_id, override=False)
         with self.timers.scope("load"), \
                 trace.span("day/load", day=day, pass_id=pass_id):
-            ds = dataset if dataset is not None else self._load_dataset(
-                day, pass_id, files)
+            if dataset is None:
+                # Not preloaded (an unpipelined day, a retry's replay):
+                # the same load -> keys -> feed -> shuffle, on this thread.
+                ds = self._load_dataset(day, pass_id, files, feed=feed_keys)
+                feed_keys = False
+            else:
+                ds = dataset
         self.trainer.reset_metrics()
         with self.timers.scope("train"), \
                 trace.span("day/train", day=day, pass_id=pass_id):
             stats = self.trainer.train_pass(ds, feed_keys=feed_keys)
+        if "pass_report" in stats:
+            # How far the pass's keys were united under its load.
+            runs, merged = ds.key_run_counts()
+            stats["pass_report"].update(
+                ingest_key_runs=runs, ingest_key_runs_merged_in_load=merged)
         if self.is_rank0:
             # Only rank 0 writes model files — N ranks racing
             # savez on one shared path would corrupt the npz.
@@ -518,8 +534,7 @@ class DayRunner:
                     # (the default): begin_pass joins it; a build error
                     # surfaces there, inside the same try as every other
                     # pass failure.
-                    ds = self._load_dataset(day, pass_id, files)
-                    self._feed_keys(ds, day, pass_id)
+                    ds = self._load_dataset(day, pass_id, files, feed=True)
                     feed_keys = False
                 else:
                     ds, feed_keys = None, True

@@ -6,6 +6,7 @@ production configuration (GPU-resident PS thesis) end to end."""
 import os
 
 import numpy as np
+import pytest
 
 from paddlebox_tpu.data import DataFeedConfig, SlotConf
 from paddlebox_tpu.embedding import DeviceFeatureStore, TableConfig
@@ -89,3 +90,110 @@ def test_eval_pass_does_not_grow_device_store(tmp_path):
     stats = trainer.eval_pass(ds2)
     assert np.isfinite(stats["loss"])
     assert trainer.engine.store.num_features == n_after_train
+
+
+# -- keys first: the engine is fed before the shuffle (PR 29) ----------------
+
+def _keys_after_the_shuffle(self, day, pass_id, files, *, feed):
+    """The order the day loop had: load, shuffle, then keys and feed."""
+    import zlib
+
+    from paddlebox_tpu.data.dataset import Dataset
+    ds = Dataset(self.feed_config,
+                 num_reader_threads=self.num_reader_threads)
+    ds.set_filelist(files)
+    ds.load_into_memory()
+    ds.local_shuffle(seed=zlib.crc32(f"{day}:{pass_id}".encode()))
+    if feed:
+        self._feed_keys(ds, day, pass_id)
+    return ds
+
+
+def _day_with_everything_recorded(tmp_path, name, pipeline, old_order,
+                                  monkeypatch):
+    import jax
+    data_root = str(tmp_path / "data")
+    if not os.path.isdir(data_root):
+        _write_day(data_root, "20260701", [0, 1, 2])
+    trainer, runner = _make_runner(data_root, str(tmp_path / name),
+                                   build_mesh(HybridTopology(dp=8)))
+    runner.pipeline_passes = pipeline
+    if old_order:
+        monkeypatch.setattr(DayRunner, "_load_dataset",
+                            _keys_after_the_shuffle)
+    fed, rows = [], []
+    feed_pass = trainer.engine.feed_pass
+    train_pass = trainer.train_pass
+
+    def spy_feed(keys, **kw):
+        fed.append([np.array(k) for k in keys])
+        return feed_pass(keys, **kw)
+
+    def spy_train(ds, **kw):
+        merged = ds._merge()
+        rows.append({s: (merged.sparse_ids[s].copy(),
+                         merged.sparse_offsets[s].copy()) for s in SLOTS})
+        return train_pass(ds, **kw)
+    monkeypatch.setattr(trainer.engine, "feed_pass", spy_feed)
+    monkeypatch.setattr(trainer, "train_pass", spy_train)
+    stats = runner.train_day("20260701")
+    monkeypatch.undo()
+    store = trainer.engine.store
+    keys = np.sort(store.key_stats()[0])
+    return {"stats": stats, "fed": fed, "rows": rows, "keys": keys,
+            "vals": store.pull_for_pass(keys),
+            "dense": [np.asarray(x) for x in jax.tree.leaves(
+                (trainer.params, trainer.opt_state))]}
+
+
+@pytest.mark.parametrize("pipeline", [True, False],
+                         ids=["pipelined", "unpipelined"])
+def test_keys_first_day_is_bit_identical_to_keys_after_shuffle(
+        tmp_path, monkeypatch, pipeline):
+    new = _day_with_everything_recorded(tmp_path, "new", pipeline, False,
+                                        monkeypatch)
+    old = _day_with_everything_recorded(tmp_path, "old", pipeline, True,
+                                        monkeypatch)
+    assert len(new["stats"]) == len(old["stats"]) == 3
+    for a, b in zip(new["stats"], old["stats"]):
+        assert (a["steps"], a["loss"], a["auc"]) == \
+            (b["steps"], b["loss"], b["auc"])
+    # each pass's one file is one chunk: a run a slot, nothing to fold
+    assert [(s["pass_report"]["ingest_key_runs"],
+             s["pass_report"]["ingest_key_runs_merged_in_load"])
+            for s in new["stats"]] == [(len(SLOTS), 0)] * 3
+    # the same key set to the engine, the same rows in the same order
+    assert len(new["fed"]) == len(old["fed"]) == 3
+    for a, b in zip(new["fed"], old["fed"]):
+        for ka, kb in zip(a, b):
+            np.testing.assert_array_equal(ka, kb)
+    for a, b in zip(new["rows"], old["rows"]):
+        for s in SLOTS:
+            np.testing.assert_array_equal(a[s][0], b[s][0])
+            np.testing.assert_array_equal(a[s][1], b[s][1])
+    np.testing.assert_array_equal(new["keys"], old["keys"])
+    for f in old["vals"]:
+        np.testing.assert_array_equal(new["vals"][f], old["vals"][f])
+    for a, b in zip(new["dense"], old["dense"]):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_shuffle_fault_in_a_preload_leaves_no_pending_build(tmp_path):
+    """A preload that fails in its shuffle has already fed the engine:
+    the error leaves through the join and the build is cancelled."""
+    from paddlebox_tpu.core import faults
+    data_root = str(tmp_path / "data")
+    _write_day(data_root, "20260701", [0, 1, 2])
+    trainer, runner = _make_runner(data_root, str(tmp_path / "out"),
+                                   build_mesh(HybridTopology(dp=8)))
+    faults.configure("day_runner/shuffle:hit=2:raise=IOError")
+    try:
+        with pytest.raises(OSError, match="day_runner/shuffle"):
+            runner.train_day("20260701")
+    finally:
+        faults.clear()
+    assert runner._inflight_preload is None
+    for g in trainer.engine.groups:
+        assert g.engine._pending is None
+        assert g.engine._pending_sem.acquire(blocking=False)
+        g.engine._pending_sem.release()

@@ -331,6 +331,194 @@ def test_pass_keys_runs_preserve_zero_key():
                                       np.array([0, 5], np.uint64))
 
 
+# -- the streamed merger: keys united while the load runs ---------------------
+
+def _made_chunks(n_chunks, seed, *, zero_in=None, empty=None, hi=400):
+    """Chunks of 1..40 rows whose slots share many ids (drawn under
+    ``hi``); ``zero_in`` plants the 0 sentinel in that slot of one chunk,
+    ``empty`` leaves that slot without an id in every row."""
+    from paddlebox_tpu.data.slots import Instance
+    rng = np.random.default_rng(seed)
+    chunks = []
+    for c in range(n_chunks):
+        rows = []
+        for _ in range(int(rng.integers(1, 41))):
+            sparse = {s: rng.integers(1, hi, rng.integers(1, 4),
+                                      dtype=np.uint64)
+                      for s in ("user", "item") if s != empty}
+            rows.append(Instance(labels=np.zeros((1,), np.float32),
+                                 sparse=sparse,
+                                 dense={"dense0": np.zeros(3, np.float32)}))
+        if zero_in is not None and c == n_chunks // 2:
+            rows[0].sparse[zero_in] = np.array([0, 7], np.uint64)
+        chunks.append(instances_to_chunk(rows, CFG))
+    return chunks
+
+
+def _drained(chunks):
+    """A Dataset that took ``chunks`` through _drain in this order."""
+    from paddlebox_tpu.data.channel import Channel
+    ds = Dataset(CFG)
+    ch = Channel(len(chunks) + 1)
+    for c in chunks:
+        ch.put(c)
+    ch.close()
+    ds._drain(ch)
+    ds._raise_reader_errors()
+    return ds
+
+
+def _exact_keys(chunks, slots=None):
+    names = ("user", "item") if slots is None else slots
+    parts = [c.sparse_ids[s] for c in chunks for s in names
+             if s in c.sparse_ids]
+    return np.unique(np.concatenate(parts)) if parts else \
+        np.empty((0,), np.uint64)
+
+
+SLOT_SUBSETS = (None, ["user"], ["item"], ["item", "user"],
+                ["item", "nosuch"], ["nosuch"])
+
+
+@pytest.mark.parametrize("order", ["as_made", "reversed", "shuffled"])
+@pytest.mark.parametrize("n_chunks", [1, 3, 7, 12, 21])
+def test_streamed_merger_equals_np_unique(n_chunks, order):
+    flags.set_flags({"ingest_key_runs": True})
+    chunks = _made_chunks(n_chunks, seed=n_chunks)
+    if order == "reversed":
+        chunks = chunks[::-1]
+    elif order == "shuffled":
+        np.random.default_rng(5).shuffle(chunks)
+    ds = _drained(chunks)
+    assert ds._key_runs_valid
+    # every slot's stack was united before the load returned
+    assert all(m.num_runs == 1 for m in ds._key_mergers.values())
+    for slots in SLOT_SUBSETS:
+        got = ds.pass_keys(slots=slots)
+        assert got.dtype == np.uint64
+        np.testing.assert_array_equal(got, _exact_keys(chunks, slots))
+    # a second call answers the same, from the same united runs
+    np.testing.assert_array_equal(ds.pass_keys(), _exact_keys(chunks))
+
+
+@pytest.mark.parametrize("zero_in", ["user", "item"])
+def test_streamed_merger_keeps_a_zero_id_of_one_slot(zero_in):
+    chunks = _made_chunks(6, seed=3, zero_in=zero_in)
+    ds = _drained(chunks)
+    other = "item" if zero_in == "user" else "user"
+    assert ds.pass_keys()[0] == 0
+    assert ds.pass_keys(slots=[zero_in])[0] == 0
+    assert ds.pass_keys(slots=[other])[0] != 0
+    for slots in SLOT_SUBSETS:
+        np.testing.assert_array_equal(ds.pass_keys(slots=slots),
+                                      _exact_keys(chunks, slots))
+
+
+def test_streamed_merger_with_an_empty_slot():
+    chunks = _made_chunks(5, seed=4, empty="item")
+    ds = _drained(chunks)
+    assert ds.pass_keys(slots=["item"]).size == 0
+    for slots in SLOT_SUBSETS:
+        np.testing.assert_array_equal(ds.pass_keys(slots=slots),
+                                      _exact_keys(chunks, slots))
+    assert _drained([]).pass_keys().size == 0
+
+
+def test_streamed_merger_counts_its_runs():
+    from paddlebox_tpu.core import monitor
+    chunks = _made_chunks(9, seed=8)
+    runs0 = monitor.get("ingest/key_runs")
+    merged0 = monitor.get("ingest/key_runs_merged_in_load")
+    served0 = monitor.get("ingest/pass_keys_from_runs")
+    ds = _drained(chunks)
+    # 9 chunks x 2 slots handed in, all but one a slot folded under the load
+    assert monitor.get("ingest/key_runs") - runs0 == 18
+    assert monitor.get("ingest/key_runs_merged_in_load") - merged0 == 16
+    assert ds.key_run_counts() == (18, 16)
+    ds.pass_keys()
+    ds.pass_keys(slots=["user"])
+    assert monitor.get("ingest/pass_keys_from_runs") - served0 == 2
+    # the load's helper is gone with the load
+    import threading
+    assert not [t for t in threading.enumerate()
+                if t.name == "pbx-ingest-keys"]
+
+
+@pytest.mark.parametrize("how", ["global_shuffle", "restored_chunk",
+                                 "runs_off"])
+def test_exact_path_answers_once_the_runs_are_invalid(how):
+    from paddlebox_tpu.core import monitor
+    chunks = _made_chunks(6, seed=6, zero_in="user")
+    flags.set_flags({"ingest_key_runs": how != "runs_off"})
+    ds = _drained(chunks)
+    if how == "global_shuffle":
+        ds.global_shuffle(num_ranks=2, rank=0, seed=1, allow_partition=True)
+    elif how == "restored_chunk":
+        ds.restore_chunks(ds.snapshot_chunks())
+    assert not ds._key_runs_valid and not ds._key_mergers
+    served0 = monitor.get("ingest/pass_keys_from_runs")
+    kept = [ds._merge()]
+    for slots in SLOT_SUBSETS:
+        np.testing.assert_array_equal(ds.pass_keys(slots=slots),
+                                      _exact_keys(kept, slots))
+    assert monitor.get("ingest/pass_keys_from_runs") == served0
+    # a load after clear() collects runs again (with the flag on)
+    ds.clear()
+    assert ds._key_runs_valid
+
+
+def test_a_failing_key_helper_fails_the_load(monkeypatch):
+    from paddlebox_tpu.native import keymap_py
+
+    def boom(ids):
+        raise OSError("dedup failed")
+    monkeypatch.setattr(keymap_py, "dedup_keys", boom)
+    with pytest.raises(OSError, match="dedup failed"):
+        _drained(_made_chunks(3, seed=1))
+
+
+@pytest.mark.parametrize("n_runs", [1, 2, 3, 5, 8, 13, 33, 100])
+def test_sorted_run_merger_holds_few_runs_and_merges_exactly(n_runs):
+    from paddlebox_tpu.native.store_py import SortedRunMerger
+    rng = np.random.default_rng(n_runs)
+    runs = [np.unique(rng.integers(1, 5000, rng.integers(1, 700),
+                                   dtype=np.uint64))
+            for _ in range(n_runs)]
+    merger = SortedRunMerger()
+    total = 0
+    for r in runs:
+        merger.add_run(r)
+        total += r.size
+        # held runs shrink by halves: never more than log2 of the ids
+        assert merger.num_runs <= max(1, int(np.log2(total)) + 1)
+    want = np.unique(np.concatenate(runs))
+    np.testing.assert_array_equal(merger.merge(), want)
+    assert merger.num_runs == 1
+    np.testing.assert_array_equal(merger.merge(), want)
+    merger.add_run(np.array([7, 9999], np.uint64))      # goes on after
+    np.testing.assert_array_equal(
+        merger.merge(), np.union1d(want, np.array([7, 9999], np.uint64)))
+
+
+@pytest.mark.parametrize("native", [True, False])
+def test_merge_unique_native_and_fallback_agree(native, monkeypatch):
+    from paddlebox_tpu.native import store_py
+    if not native:
+        monkeypatch.setattr(store_py, "load_library", lambda: None)
+    elif store_py.load_library() is None:
+        pytest.skip("no native library here")
+    rng = np.random.default_rng(2)
+    empty = np.empty((0,), np.uint64)
+    # the last pair is long enough for the native union to run threaded
+    for n, m, hi in [(0, 0, 9), (0, 5, 9), (5, 0, 9), (50, 50, 60),
+                     (3000, 10, 1 << 40), (10, 3000, 5000),
+                     (300_000, 200_000, 600_000)]:
+        a = np.unique(rng.integers(1, hi, n, dtype=np.uint64)) if n else empty
+        b = np.unique(rng.integers(1, hi, m, dtype=np.uint64)) if m else empty
+        np.testing.assert_array_equal(store_py.merge_unique(a, b),
+                                      np.union1d(a, b))
+
+
 # -- sorted-run store build vs incremental upsert ---------------------------
 
 def test_bulk_build_matches_upsert_rows_and_keys():

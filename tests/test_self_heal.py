@@ -170,6 +170,54 @@ def test_transient_fault_costs_one_retry_bit_parity(
         [(DAY, 1), (DAY, 2), (DAY, 0)]
 
 
+@pytest.mark.parametrize("device_store", [False, True],
+                         ids=["host_store", "device_store"])
+def test_shuffle_fault_after_the_feed_retries_bit_parity(
+        device_store, day_data, reference, tmp_path, monkeypatch):
+    """The loader feeds the engine BEFORE it shuffles: a transient fault
+    in pass 2's shuffle finds that pass's build pending, the retry path
+    cancels it, and the replay (load -> keys -> feed -> shuffle again)
+    ends bit-identical to an unfailed day."""
+    from paddlebox_tpu.train.day_runner import DayRunner
+    if device_store:
+        want = _make_runner(day_data, str(tmp_path / "ref"),
+                            device_store=True)
+        ref = {"stats": want.train_day(DAY), "state": _final_state(want)}
+    else:
+        ref = reference
+    runner = _make_runner(day_data, str(tmp_path / "out"),
+                          device_store=device_store)
+    runner.pipeline_passes = False       # each pass loads inside its retry loop
+    fed, cancelled = [], []
+    feed_keys = DayRunner._feed_keys
+    cancel = runner.trainer.engine.cancel_pending
+
+    def spy_feed(self, ds, day, pass_id):
+        fed.append(pass_id)
+        return feed_keys(self, ds, day, pass_id)
+
+    def spy_cancel():
+        cancelled.append(any(g.engine._pending is not None
+                             for g in runner.trainer.engine.groups))
+        return cancel()
+    monkeypatch.setattr(DayRunner, "_feed_keys", spy_feed)
+    monkeypatch.setattr(runner.trainer.engine, "cancel_pending", spy_cancel)
+    retries0 = monitor.get("pass/retries")
+    faults.configure("day_runner/shuffle:hit=2:raise=IOError")
+    stats = runner.train_day(DAY)
+    faults.clear()
+    assert monitor.get("fault/day_runner/shuffle_injected") >= 1
+    assert monitor.get("pass/retries") - retries0 == 1
+    assert fed == [1, 2, 2]
+    assert cancelled[0] is True, "no build was pending at the fault"
+    for got, want in zip(stats, ref["stats"]):
+        assert (got["steps"], got["loss"], got["auc"]) == \
+            (want["steps"], want["loss"], want["auc"])
+    _assert_state_equal(_final_state(runner), ref["state"])
+    assert [(r.day, r.pass_id) for r in runner.ckpt.records()] == \
+        [(DAY, 1), (DAY, 2), (DAY, 0)]
+
+
 def test_fatal_fault_is_not_retried(day_data, tmp_path):
     """ValueError (bad data / code bug class) must raise immediately —
     blind retry would re-fail or mask the bug."""

@@ -3,8 +3,12 @@ day over the device store with the ring on names every place a thread
 waits or builds — the preload join, the pass build, the feed queue — on
 the thread that does it; with the ring off the same day records nothing."""
 
+import importlib
+import json
+import os
 import threading
 
+import numpy as np
 import pytest
 
 from paddlebox_tpu.core import trace
@@ -87,6 +91,77 @@ def test_ingest_spans_are_on_the_preload_thread(day_events, name):
     assert {e["args"]["day"] for e in spans} == {DAY}
 
 
+def test_the_engine_gets_the_keys_before_the_shuffle(day_events):
+    by_pass = {name: {e["args"]["pass_id"]: e
+                      for e in _named(day_events, name)}
+               for name in PRELOAD_SPANS}
+    for pass_id in (1, 2, 3):
+        load, shuffle, keys, feed = (by_pass[n][pass_id]
+                                     for n in PRELOAD_SPANS)
+        assert len({e["tid"] for e in (load, shuffle, keys, feed)}) == 1
+        assert load["ts"] + load["dur"] <= keys["ts"]
+        assert keys["ts"] + keys["dur"] <= feed["ts"]
+        assert feed["ts"] < shuffle["ts"] + shuffle["dur"]
+        assert feed["ts"] + feed["dur"] <= shuffle["ts"]
+
+
+def test_key_merges_run_under_their_pass_load(day_events):
+    merges = _named(day_events, "ingest/key_merge")
+    outer = (_named(day_events, "ingest/load")
+             + _named(day_events, "ingest/pass_keys"))
+
+    def within(e, o):
+        return (o["ts"] <= e["ts"]
+                and e["ts"] + e["dur"] <= o["ts"] + o["dur"])
+    homes = [[o for o in outer if within(e, o)] for e in merges]
+    assert all(len(h) == 1 for h in homes), homes
+    # every pass's load had its keys merged under it, on a helper thread
+    assert {h[0]["args"]["pass_id"] for h in homes
+            if h[0]["name"] == "ingest/load"} == {1, 2, 3}
+    assert all(e["tid"] != h[0]["tid"] for e, h in zip(merges, homes)
+               if h[0]["name"] == "ingest/load")
+
+
+def _read_metric(name, events, passes):
+    """A benchmark metric file through its reader, over ring events."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "metrics",
+                           name + ".json")) as f:
+        spec = json.load(f)
+    reader = importlib.import_module("benchmarks.readers." + spec["reader"])
+    spans = [(e["ts"] * 1e3, (e["ts"] + e["dur"]) * 1e3, e["name"],
+              e["tid"]) for e in events]
+    observed = {"program_spans": spans, "passes": passes,
+                "window_unix_ns": (min(s[0] for s in spans),
+                                   max(s[1] for s in spans))}
+    return reader.read(spec["params"], observed, None, None)
+
+
+def test_keys_to_engine_metric_reads_load_end_to_feed(day_events):
+    got = _read_metric("ingest.keys_to_engine_ms_per_pass", day_events,
+                       len(HOURS))
+    loads = {e["args"]["pass_id"]: e
+             for e in _named(day_events, "ingest/load")}
+    gaps = [(f["ts"] - loads[f["args"]["pass_id"]]["ts"]
+             - loads[f["args"]["pass_id"]]["dur"]) / 1e3
+            for f in _named(day_events, "ingest/feed_pass")]
+    assert got == pytest.approx(float(np.median(gaps)), abs=1e-6)
+    # what lies between holds the key tail (and no shuffle: the test above)
+    assert got >= min(e["dur"] / 1e3
+                      for e in _named(day_events, "ingest/pass_keys"))
+
+
+def test_key_merge_metric_sums_the_helpers_spans(day_events):
+    got = _read_metric("ingest.key_merge_ms_per_pass", day_events,
+                       len(HOURS))
+    total = sum(e["dur"] for e in _named(day_events, "ingest/key_merge"))
+    assert got == pytest.approx(total / 1e3 / len(HOURS))
+    # a program without the span (the parent) leaves the metric out
+    rest = [e for e in day_events if e["name"] != "ingest/key_merge"]
+    assert _read_metric("ingest.key_merge_ms_per_pass", rest,
+                        len(HOURS)) is None
+
+
 def test_preload_join_names_the_pass_joined(day_events):
     joins = sorted(_named(day_events, "day/preload_join"),
                    key=lambda e: e["ts"])
@@ -124,7 +199,7 @@ def test_store_spans_nest_under_the_build_or_the_boundary(day_events, name):
     assert all(e["args"][arg] > 0 for e in spans)
 
 
-def test_unpipelined_day_feeds_inside_the_pass(tmp_path):
+def test_unpipelined_day_feeds_before_its_shuffle_too(tmp_path):
     trace.clear()
     trace.enable(ring_events=1 << 16)
     try:
@@ -133,12 +208,19 @@ def test_unpipelined_day_feeds_inside_the_pass(tmp_path):
     finally:
         trace.disable()
         trace.clear()
-    feeds = _named(events, "pass/feed_pass")
-    assert len(feeds) == len(HOURS)
-    assert all(any(_inside(f, t) for t in _named(events, "day/train"))
-               for f in feeds)
+    # the same load -> keys -> feed -> shuffle, inside the pass's day/load
+    loads = _named(events, "day/load")
+    for name in PRELOAD_SPANS:
+        spans = _named(events, name)
+        assert sorted(e["args"]["pass_id"] for e in spans) == [1, 2, 3]
+        assert all(any(_inside(e, d) for d in loads) for e in spans)
+    shuffles = {e["args"]["pass_id"]: e
+                for e in _named(events, "ingest/shuffle")}
+    for feed in _named(events, "ingest/feed_pass"):
+        assert (feed["ts"] + feed["dur"]
+                <= shuffles[feed["args"]["pass_id"]]["ts"])
+    assert not _named(events, "pass/feed_pass")
     assert not _named(events, "day/preload_join")
-    assert not _named(events, "ingest/feed_pass")
 
 
 def test_ring_off_records_nothing_and_costs_a_shared_null_span(
@@ -158,5 +240,6 @@ def test_ring_off_records_nothing_and_costs_a_shared_null_span(
     assert len(stats) == len(HOURS)
     assert trace.snapshot() == []
     seen = {name for name, _, _ in opened}
-    assert seen >= set(TRAINER_SPANS + PRELOAD_SPANS + BUILD_SPANS)
+    assert seen >= set(TRAINER_SPANS + PRELOAD_SPANS + BUILD_SPANS
+                       + ("ingest/key_merge",))
     assert all(got is trace.NULL_SPAN for _, got, _ in opened)
