@@ -8,16 +8,16 @@ Phases (a failure in any is fatal; nothing is retried on another path):
 
 1. identity  device facts, versions, compile-cache directory, the native
              host library must have built, host link rates
-2. kernels   each Pallas kernel, compiled, at its bench shape against its
+2. kernels   each Pallas kernel, compiled, at its full-width shape against its
              jax.numpy reference on the same chip
-3. deepfm    bench.py's DeepFM at full width as a trainer: text files ->
+3. deepfm    DeepFM at the Criteo widths as a trainer: text files ->
              Dataset (ingest worker processes) -> two train passes with a
              pipelined split build and fused boundary between them; then
              the same pass on the XLA kernel path, twice, to bound the
              Pallas-vs-XLA difference by XLA's difference with itself
 4. predict   export -> CTRPredictor -> PredictServer answers a
              PredictClient over the wire, bit-identical to direct predict
-5. gpt       bench.py's GPT config: two train steps on the flash path,
+5. gpt       a GPT-2-medium-wide stack: two train steps on the flash path,
              step-1 loss against the ring (XLA) attention of the same model
 6. nemotron  a three-layer M*E stack (Mamba-2, grouped-query attention,
              latent experts) at published widths: the scan kernels against
@@ -39,9 +39,10 @@ import time
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
-# bench.py's deepfm / gpt configurations (bench.py:226-242, 894-896). Cut
-# for the smoke: the resident store (8M keys, not 50M) and the batch
-# count (10 per pass, not 64) — every width is the bench's.
+# DeepFM at the Criteo widths and a GPT-2-medium-wide stack (cf.
+# benchmarks/configs/deepfm_criteo.json and gpt2_medium.json). Cut for
+# the smoke: the resident store (8M keys), the batch count (10 per pass)
+# and the GPT depth; no width.
 FULL = {
     "slots": 26, "emb_dim": 16, "dense_dim": 13, "hidden": (400, 400, 400),
     "batch": 16384, "store_keys": 8_000_000, "pass_keys": 4_000_000,
@@ -51,7 +52,7 @@ FULL = {
     # 21: the layer scan saves f32[24, 4, 1024, 4096] residuals).
     "gpt": {"vocab_size": 50304, "d_model": 1024, "n_heads": 16,
             "n_layers": 12, "d_ff": 4096, "max_seq_len": 1024},
-    "gpt_cut": "12 of bench_gpt's 24 layers; every width as in bench.py",
+    "gpt_cut": "12 of 24 layers; no width cut",
     "gpt_batch_per_chip": 4, "flash": (4, 1024, 16, 64),
     "seqpool": (65536, 16, 16384), "serve_requests": 48, "link_mib": 256,
     "auc_floor": 0.7,
@@ -158,7 +159,7 @@ def check(cond, msg):
 
 
 # ---------------------------------------------------------------------------
-# Data, generated from a seed (bench.py:_planted_labels/_gen_pass_files role)
+# Data, generated from a seed (planted labels on a hot head of keys)
 # ---------------------------------------------------------------------------
 
 def planted_labels(rng, hot_ids, target_rate=0.25, strength=2.0):
@@ -198,7 +199,7 @@ def gen_lines(rng, ids, cfg, hot):
 def gen_pass_files(tmpdir, tag, rng, pass_keys, cfg, hot):
     """One part file per batch. Every key of ``pass_keys`` occurs at
     least once (whole permutations are dealt out), so the pass table is
-    built for the full key set, as the bench's 64-batch pass does."""
+    built for the full key set."""
     import numpy as np
     nb, batch, slots = cfg["batches"], cfg["batch"], cfg["slots"] - 1
     need = nb * batch * slots
@@ -351,7 +352,7 @@ def phase_kernels(sm: Smoke, out: dict):
     sparse_pair("sparse_4chip_shard", 4 * bucket_capacity(n_ids // 4, 4),
                 plan_shards(cfg["pass_keys"], 4) + 1)
 
-    # flash attention forward + backward at bench_gpt's shape, default
+    # flash attention forward + backward at [4, 1024, 16, 64], default
     # FLAGS_flash_block_q/k, against the XLA reference at full
     # precision. Errors are relative to each tensor's largest value. The
     # kernel's f32 dots may run as bf16 passes on the MXU, exactly as
@@ -438,7 +439,7 @@ def deepfm_model(cfg):
 
 
 def make_trainer(sm: Smoke, devices):
-    """bench_deepfm's trainer (bench.py:446-467) over ``devices``, its
+    """A DeepFM ``CTRTrainer`` over ``devices`` with a device-resident
     store prepopulated with the resident key set."""
     import numpy as np
 
@@ -516,10 +517,10 @@ def phase_deepfm(sm: Smoke, out: dict, tmpdir):
     want_mode = "interpret" if sm.rehearsal else "pallas"
     rng = np.random.default_rng(0)
     out.update({
-        "cut": f"resident store {cfg['store_keys']:,} keys (bench: "
-               f"50,000,000); {cfg['batches']} batches per pass (bench: "
-               f"64); widths, batch {cfg['batch']}, pass table "
-               f"{cfg['pass_keys']:,} keys as in bench.py deepfm",
+        "cut": f"resident store {cfg['store_keys']:,} keys; "
+               f"{cfg['batches']} batches per pass; widths, batch "
+               f"{cfg['batch']} and the {cfg['pass_keys']:,}-key pass "
+               f"table are the deepfm_criteo cells'",
         "n_devices": ndev})
 
     # Key sets: pass 2 shares half of pass 1's keys, draws most of the

@@ -216,19 +216,18 @@ def validate_all() -> List[str]:
 
 
 def pallas_kernels_enabled() -> bool:
-    """True when auto-selection may pick a Pallas kernel: TPU backend
-    AND the enable_pallas_kernels master switch. One predicate for every
-    kernel gate (lookup scatter, flash attention, seqpool-CVM)."""
+    """True when auto-selection may pick a Pallas kernel: on the TPU
+    backend. One predicate for every kernel gate (lookup scatter, flash
+    attention, seqpool-CVM)."""
     import jax
-    return jax.default_backend() == "tpu" and bool(
-        flag("enable_pallas_kernels"))
+    return jax.default_backend() == "tpu"
 
 
 # What each kernel dispatch site actually selected, recorded while its
 # caller traces: 'auto' resolves from the backend, and some sites give
 # way to XLA on their own (a fused record wider than one lane tile), so
-# the flag value does not say what ran. chip_smoke.py and bench.py
-# report this table instead of the flags.
+# the flag value does not say what ran. chip_smoke.py and the benchmark's
+# runners report this table instead of the flags.
 _resolved_kernels: Dict[str, set] = {}
 _resolved_lock = threading.Lock()
 
@@ -253,12 +252,13 @@ def resolved_kernels(reset: bool = False) -> Dict[str, List[str]]:
 
 def compilation_cache_dir() -> str:
     """The persistent compile cache of a chip entry point (chip_smoke.py,
-    bench.py): ``JAX_COMPILATION_CACHE_DIR`` where the environment sets
-    it — then nothing is set in code — and otherwise ``.jax_cache`` at
-    the root of this checkout. The path is part of the cache key, so it
-    is never derived from a temp dir, a pid or the time. Call before the
-    first compile; tests never call it, so CPU executables (which carry
-    machine-feature stamps) are not cached. Returns the directory."""
+    benchmarks/run.py): ``JAX_COMPILATION_CACHE_DIR`` where the
+    environment sets it — then nothing is set in code — and otherwise
+    ``.jax_cache`` at the root of this checkout. The path is part of the
+    cache key, so it is never derived from a temp dir, a pid or the time.
+    Call before the first compile; tests never call it, so CPU
+    executables (which carry machine-feature stamps) are not cached.
+    Returns the directory."""
     d = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if d:
         return d
@@ -286,9 +286,6 @@ define_flag("v", 0, "global VLOG verbosity level (role of glog FLAGS_v)")
 define_flag("check_nan_inf", False,
             "scan train-step outputs for NaN/Inf and abort the pass "
             "(role of FLAGS_check_nan_inf + nan_inf_utils_detail)")
-define_flag("enable_pallas_kernels", True,
-            "use Pallas TPU kernels for hot ops where available; "
-            "fall back to pure-XLA lowering when False (or on CPU tests)")
 define_flag("embedding_shard_slack", 1.3,
             "over-allocation factor for per-shard bucket capacity in the "
             "sparse pull/push all-to-all (static-shape padding headroom)")
@@ -334,11 +331,6 @@ define_flag("embedding_exchange_dtype", "f32",
             "grads still merge sender-side in f32 and widen back "
             "before the owner-side accumulate). Row/request exchanges "
             "stay int32 either way")
-define_flag("pass_table_pow2_rows", 1,
-            "round each pass table's rows-per-shard up to a power of two "
-            "so consecutive passes with different key counts reuse the "
-            "compiled train step (1 recompile per size DOUBLING instead "
-            "of every pass; costs <=2x table HBM in the worst case)")
 define_flag("padbox_max_shuffle_wait_count", 16,
             "max concurrent sends per rank in the cross-node shuffle "
             "exchange (flow-control window — role of "
@@ -350,8 +342,8 @@ define_flag("xbox_quant_bits", 0,
             "fused_seqpool_cvm_op.cu:247 quant_ratio — applied at the "
             "export boundary; w and the serving math stay float)")
 define_flag("flash_block_q", 512,
-            "flash-attention q-tile rows (Pallas kernel); tuned per "
-            "hardware by tools/tune_flash_blocks.py — override via "
+            "flash-attention q-tile rows (Pallas kernel); chosen for "
+            "VMEM residency, never swept on a chip — override via "
             "FLAGS_flash_block_q without touching call sites")
 define_flag("flash_block_k", 512,
             "flash-attention k-tile columns (see flash_block_q)")
@@ -601,7 +593,7 @@ define_flag("stream_pass_window_s", 60.0,
 define_flag("stream_poll_s", 1.0,
             "sleep between streaming source polls in "
             "StreamRunner.run() when a poll carved nothing (the idle "
-            "cadence of the files-as-stream tailer; tests and bench "
+            "cadence of the files-as-stream tailer; tests "
             "drive poll_once() directly and never sleep)")
 define_flag("table_decay_rate", 0.0,
             "show/click decay applied by every store variant's "

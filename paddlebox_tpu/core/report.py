@@ -27,7 +27,7 @@ from paddlebox_tpu.core import log, monitor, timers, trace
 # Canonical stage-timer names (the PrintSyncTimer vocabulary). Every
 # pass summary carries ALL of them — a stage the host could not observe
 # this pass reports 0.0 rather than disappearing, so downstream tooling
-# (tools/trace_report.py, PROFILE rounds) sees a stable schema.
+# (tools/trace_report.py) sees a stable schema.
 STAGES = ("read", "pack", "pull", "fwd_bwd", "push", "dispatch", "sync")
 
 # Last emitted summaries, stashed for the incident flight recorder
@@ -41,8 +41,7 @@ def stage_delta(group: "timers.TimerGroup",
                 base_ms: Dict[str, float]) -> Dict[str, float]:
     """Per-pass stage ms from a cumulative TimerGroup: current snapshot
     minus the snapshot taken at pass start (the group is shared across
-    passes — bench.py reads its cumulative totals — so the pass report
-    must difference, not read raw)."""
+    passes, so the pass report must difference, not read raw)."""
     now = group.snapshot_ms()
     out = {s: round(now.get(s, 0.0) - base_ms.get(s, 0.0), 3)
            for s in STAGES}
@@ -118,21 +117,6 @@ def emit_pass_report(kind: str, *, steps: int, samples: int,
             v = b.get(k)
             if isinstance(v, (int, float)):
                 reg.set_gauge(f"pass/{kind}_boundary_{k}", float(v))
-    # Critical-path verdict (round 11): headline fractions + per-stage
-    # occupancy land as gauges under pipeline/ so trace_report.py can
-    # render the occupancy table from the metrics JSONL alone.
-    bn = summary.get("bottleneck")
-    if isinstance(bn, dict):
-        for k in ("device_idle_frac", "host_critical_share"):
-            v = bn.get(k)
-            if isinstance(v, (int, float)):
-                reg.set_gauge(f"pass/{kind}_{k}", float(v))
-        for stage, sh in (bn.get("stages") or {}).items():
-            for k in ("busy_ms", "busy_frac", "blocked_up_frac",
-                      "blocked_down_frac"):
-                v = sh.get(k)
-                if isinstance(v, (int, float)):
-                    reg.set_gauge(f"pipeline/{stage}_{k}", float(v))
     dq = summary.get("dispatch_ms_quantiles")
     if isinstance(dq, dict):
         for k, v in dq.items():

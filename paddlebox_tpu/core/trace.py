@@ -16,7 +16,7 @@ Design constraints (the CTR hot loop runs through here):
   stages; nothing here may add ops or syncs to a jitted program.
 - **Bounded.** Events live in a ring (``FLAGS_trace_ring_events``); a
   multi-hour run cannot OOM the host, and ``snapshot()`` hands the tail
-  to crash/stall dumps (bench.py's watchdog forensics).
+  to crash/stall dumps (``stall_forensics``).
 
 Distributed tracing (OBSERVABILITY.md "Distributed tracing"): a
 compact TRACE CONTEXT — ``{tid, sid, origin}`` = trace id, sending
@@ -240,7 +240,7 @@ class Tracer:
 
     def init_from_flags(self) -> bool:
         """Idempotent flag-driven enable: a non-empty ``FLAGS_trace_path``
-        turns tracing on (called at pass/bench/service entry points, so
+        turns tracing on (called at pass/service entry points, so
         env-set flags work without code changes). Returns enabled."""
         if not self._enabled:
             path = flags.flag("trace_path")
@@ -298,7 +298,7 @@ class Tracer:
 
     def snapshot(self) -> List[Dict[str, Any]]:
         """The current ring contents, oldest first — the crash/stall dump
-        surface (bench watchdog, ``stall_forensics``)."""
+        surface (``stall_forensics``)."""
         with self._lock:
             return list(self._events)
 
@@ -389,9 +389,8 @@ def stall_forensics(max_events: int = 256) -> Dict[str, Any]:
     (faulthandler), the trace ring tail, and every registered provider
     section (e.g. ``inflight_rpcs`` — the in-flight RPC table, so a
     hang names the REMOTE it is stuck on, not just local frames).
-    bench.py's watchdog embeds this in the failure JSON so an r05-style
-    'no progress in phase device-probe' stall names the blocked frame,
-    not just the phase."""
+    The pass watchdog logs this so a 'no progress in phase X' stall
+    names the blocked frame, not just the phase."""
     import faulthandler
     import tempfile
     try:

@@ -1,15 +1,14 @@
 """Heartbeat stall watchdog: abort a hung pass instead of hanging forever.
 
-bench.py grew an ad-hoc watchdog after a device call blocked for 30+
-minutes with zero progress and the run recorded nothing. This module is
-that watchdog moved into the library proper, generalized for the
-training loop: the day runner arms it around
-each pass, the trainer's per-block dispatch path feeds it, and a stall
-(``FLAGS_stall_timeout_s`` with no heartbeat) dumps
-``trace.stall_forensics()`` — every thread's Python stack + the span-ring
-tail — into the log, then aborts the pass by raising :class:`StallError`
-*in the armed thread* so the failure flows through the same
-cancel/rollback/retry machinery as any other transient fault.
+A device call once blocked for 30+ minutes with zero progress and the
+run recorded nothing. This module is the watchdog for the training
+loop: the day runner arms it around each pass, the trainer's per-block
+dispatch path feeds it, and a stall (``FLAGS_stall_timeout_s`` with no
+heartbeat) dumps ``trace.stall_forensics()`` — every thread's Python
+stack + the span-ring tail — into the log, then aborts the pass by
+raising :class:`StallError` *in the armed thread* so the failure flows
+through the same cancel/rollback/retry machinery as any other transient
+fault.
 
 The async raise (``PyThreadState_SetAsyncExc``) lands when the target
 thread next executes Python bytecode. A thread blocked inside a C call
@@ -27,7 +26,7 @@ import ctypes
 import sys
 import threading
 import time
-from typing import Callable, Optional
+from typing import Optional
 
 from paddlebox_tpu.core import flags, log, monitor, trace
 
@@ -58,18 +57,14 @@ class Watchdog:
     """One armed window at a time: ``arm()`` starts (or re-targets) the
     monitor thread, ``beat()`` feeds it, ``disarm()`` closes the window.
 
-    ``on_stall(phase, idle_s)`` overrides the default abort action —
-    bench.py uses it to print its structured failure JSON and hard-exit;
-    the default dumps forensics and async-raises :class:`StallError` in
-    the armed thread, once per armed window."""
+    A stall dumps forensics and async-raises :class:`StallError` in the
+    armed thread, once per armed window."""
 
     def __init__(self, timeout_s: float, *, name: str = "watchdog",
-                 on_stall: Optional[Callable[[str, float], None]] = None,
                  poll_s: float = 0.0,
                  heartbeat_s: float = 0.0):
         self.name = name
         self._timeout = float(timeout_s)
-        self._on_stall = on_stall
         self._poll = float(poll_s) if poll_s > 0 else None
         self._heartbeat_s = float(heartbeat_s)
         self._armed = False            # the ONE beat() check
@@ -89,8 +84,7 @@ class Watchdog:
         return self._armed
 
     def set_timeout(self, timeout_s: float) -> None:
-        """Re-tier the limit mid-window (bench's short-until-proven-alive
-        then relaxed two-tier scheme)."""
+        """Change the limit; an open window sees it at its next poll."""
         self._timeout = float(timeout_s)
 
     def arm(self, *, thread: Optional[threading.Thread] = None,
@@ -172,10 +166,7 @@ class Watchdog:
         monitor.add("watchdog/stalls", 1)
         monitor.set_gauge("watchdog/last_stall_idle_s", round(idle, 3))
         phase = self._phase
-        if self._on_stall is not None:
-            self._on_stall(phase, idle)
-            return
-        # Default action: forensics into the log, then abort the armed
+        # Forensics into the log, then abort the armed
         # thread through the normal exception path. The RPC plane leads
         # (rpc.poller_table / rpc.inflight_table via the forensics
         # providers): a stall in the event-loop plane should name the

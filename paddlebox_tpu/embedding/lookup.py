@@ -14,7 +14,7 @@ Role of the HeterComm data path (``heter_comm_inl.h``):
   optimizer once per touched row — but it lowers to one scatter plus
   streaming elementwise work instead of 3 sorts + 6 gathers + 6 scatters
   (XLA TPU scatter costs ~7 ns/element plus ~5 ms fixed per op; the r02
-  layout paid that 6x per step — see tools/profile_step.py).
+  layout paid that 6x per step).
 
 Everything is static-shape: per-destination buckets have fixed capacity
 ``C = ceil(n_unique/num_shards * slack)`` (flags
@@ -356,7 +356,7 @@ def _gather_rows(vals: jax.Array, rows: jax.Array, width: int, block: int,
     """vals[rows, :width] by the configured backend
     (``sparse_gather_kernel`` flag): the Pallas sorted-stream gather
     (CopyForPull role — the XLA gather is the pull path's dominant op,
-    PROFILE.md) or the XLA gather. On the kernel path trash rows
+    r02 chip run) or the XLA gather. On the kernel path trash rows
     (block - 1: padding/overflow requests) are DROPPED to zeros — the
     trash row's pull columns are zero by contract (apply_accumulated
     keeps them so), so the result is identical while the concentrated
@@ -486,7 +486,7 @@ def _accumulate(rows: jax.Array, payload: jax.Array, block: int,
     """zeros([block, AW]).at[rows].add(payload) by the configured backend
     (``sparse_scatter_kernel`` flag): the Pallas sorted-stream kernel
     (CopyForPush role — XLA TPU scatter is the step's dominant cost,
-    PROFILE.md) or the XLA scatter. Trash-row entries (row == block-1:
+    r02 chip run) or the XLA scatter. Trash-row entries (row == block-1:
     padding/overflow, all-zero or count-only payload) are dropped on the
     kernel path — apply_accumulated re-zeroes the trash row either way,
     and every padding lane on one row is a run the kernel would walk

@@ -20,8 +20,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from paddlebox_tpu.core import (faults, flags, log, monitor,
-                                pipeline_stats, timers, trace)
+from paddlebox_tpu.core import faults, flags, log, monitor, timers, trace
 from paddlebox_tpu.embedding.store import FeatureStore
 from paddlebox_tpu.embedding.table import (PassTable, TableConfig,
                                            build_pass_table_host,
@@ -258,13 +257,8 @@ class PassEngine:
         builder starts); the ``_no_active_pass`` check is both the
         no-active fast path and a poll-rate safety net."""
         faults.faultpoint("pass_engine/boundary")
-        # Occupancy: the builder parked here is the boundary stage
-        # blocked on its upstream (the active pass owning the store).
-        # The per-pass verdict uses the engine's own boundary_ms deltas
-        # as the authoritative numbers; this feed keeps the raw
-        # occupancy view (trace_report) consistent with them.
+        # The builder parks here while the active pass owns the store.
         with self.timers.scope("feed_wait"), \
-                pipeline_stats.GLOBAL.blocked_up("boundary"), \
                 trace.span("build/boundary_wait"):
             while True:
                 if pending.cancel.is_set():

@@ -22,7 +22,7 @@ All per-row fields live in ONE ``[rows, W]`` float32 array (the
 CommonFeatureValue packing) so the hot path is a single gather per pull and
 a single scatter per push — XLA scatter/gather on TPU pays a fixed cost
 per *op*, and the r02 six-arrays layout paid it six times per step
-(measured: ~50 ms per 426K-row scatter; see tools/profile_step.py).
+(measured on the r02 chip run: ~50 ms per 426K-row scatter).
 
 Column layout (D = emb dim, Ke/Kw = optimizer state widths):
 
@@ -39,7 +39,7 @@ Index math (device-side, int32):
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Optional, Tuple
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -157,24 +157,18 @@ def table_widths(config: TableConfig) -> Tuple[int, int, int]:
     return config.dim, opt.emb_state_width(config.dim), opt.w_state_width()
 
 
-def plan_shards(num_keys: int, num_shards: int,
-                round_pow2: Optional[bool] = None) -> int:
-    """Rows per shard covering num_keys.
+def plan_shards(num_keys: int, num_shards: int) -> int:
+    """Rows per shard covering num_keys, rounded up to a power of two.
 
-    By default rounds up to a power of two (``pass_table_pow2_rows``
-    flag): the jitted train step's shapes depend on the table's leading
-    dim, so WITHOUT rounding every pass with a new key count would
-    recompile (~tens of seconds); with it, steady-state online passes hit
-    the same size bucket and reuse the compiled program. Row alignment
-    beyond that is unnecessary — gathers index the row dim; only the
-    trailing feature dim needs TPU tiling."""
-    from paddlebox_tpu.core import flags
+    The jitted train step's shapes depend on the table's leading dim, so
+    WITHOUT rounding every pass with a new key count would recompile
+    (~tens of seconds); with it, steady-state online passes hit the same
+    size bucket and reuse the compiled program (one recompile per size
+    doubling; at most 2x table HBM). Row alignment beyond that is
+    unnecessary — gathers index the row dim; only the trailing feature
+    dim needs TPU tiling."""
     rps = -(-max(num_keys, 1) // num_shards)
-    if round_pow2 is None:
-        round_pow2 = bool(flags.flag("pass_table_pow2_rows"))
-    if round_pow2:
-        rps = 1 << (rps - 1).bit_length()
-    return rps
+    return 1 << (rps - 1).bit_length()
 
 
 def fuse_values_host(values: Dict[str, np.ndarray]) -> np.ndarray:

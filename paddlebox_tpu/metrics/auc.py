@@ -75,7 +75,7 @@ def auc_accumulate(state: AucState, preds: jax.Array, labels: jax.Array,
     pos = (labels > 0.5).astype(preds.dtype) * w
     # ONE width-2 scatter-add builds BOTH histograms: each sample adds
     # its (neg_w, pos_w) column at its bucket. XLA TPU scatter pays a
-    # ~5 ms fixed cost per OP (PROFILE.md "AUC hist scatter"), so the
+    # ~5 ms fixed cost per OP (r02 chip run), so the
     # split show/click form — one scatter per label row, or the flat
     # segment_sum over [2*nb] whose index arithmetic defeats the
     # unique-window lowering — pays the overhead twice for the same

@@ -1,4 +1,4 @@
-"""BERT encoder for masked-LM pretraining (BASELINE.md config 2).
+"""BERT encoder for masked-LM pretraining.
 
 Role of the reference's Fleet data-parallel BERT path (static-graph program
 + per-grad ``c_allreduce_sum``; SURVEY.md §3.4). TPU-first: one jitted

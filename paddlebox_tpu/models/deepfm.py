@@ -1,6 +1,6 @@
 """DeepFM CTR model over pulled sparse embeddings.
 
-The BASELINE.md config-4 model (DeepFM on Criteo, reference path
+DeepFM on Criteo (reference path
 ``pull_box_sparse`` + dense ops). Consumes the sparse pull outputs
 (per-slot CSR embeddings) and produces logits:
 

@@ -1,6 +1,6 @@
 """GPT-style transformer with full hybrid parallelism (dp×pp×sp×mp).
 
-The BASELINE.md config-3 model (GPT 1.3B hybrid parallel; reference path
+A GPT hybrid-parallel model (reference path
 ``fleet/meta_parallel/`` TP+PP+sharding). Composes the whole parallelism
 suite in one train step:
 

@@ -1,4 +1,4 @@
-"""ResNet-50 for image classification (BASELINE.md config 1).
+"""ResNet-50 for image classification.
 
 Role of the reference's vision path (``paddle.vision.models.resnet50``).
 TPU-first: NHWC layout (channels on the lane axis), bottleneck blocks as

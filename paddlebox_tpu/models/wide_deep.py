@@ -1,6 +1,6 @@
 """Wide&Deep CTR model with CVM features.
 
-The BASELINE.md config-5 model (Wide&Deep 100B-feature HeterPS-style).
+The Wide&Deep 100B-feature HeterPS-style model.
 Deep tower consumes ``fused_seqpool_cvm`` outputs — per-slot pooled
 embeddings with leading [log(show+1), log(ctr)] channels, the PaddleBox
 production pattern (fused_seqpool_cvm wrapper, contrib/layers/nn.py:1746);

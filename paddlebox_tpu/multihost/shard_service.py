@@ -1426,8 +1426,8 @@ class _ShardFuture:
 def start_local_shards(world: int, config: TableConfig, *, seed: int = 0,
                        replicas: int = 1
                        ) -> Tuple[List[ShardServer], List[str]]:
-    """Loopback cluster on 127.0.0.1 ephemeral ports (tests / the
-    ``bench.py multihost`` loopback mode). ``replicas`` > 1 wires the
+    """Loopback cluster on 127.0.0.1 ephemeral ports (tests, drills and
+    examples). ``replicas`` > 1 wires the
     ring replica map across the started servers."""
     ranges = ShardRangeTable.for_world(world)
     servers = [ShardServer("127.0.0.1:0", i, ranges, config, seed=seed)

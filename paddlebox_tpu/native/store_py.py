@@ -274,16 +274,14 @@ class KeyIndex:
 
 
 def bench_index_build(n_keys: int, *, chunk: int = 10_000_000,
-                      seed: int = 7, tick=None,
-                      mode: str = "upsert") -> float:
+                      seed: int = 7, mode: str = "upsert") -> float:
     """ONE definition of the 'host pass-build' metric (SURVEY hard part
     #1 — PreBuildTask role, ps_gpu_wrapper.cc:114): fresh build of
     n_keys uniform-random keys into a pre-sized KeyIndex, chunked like a
-    production bulk build. Returns keys/s. Shared by bench.py
-    (host_index_build_keys_per_s), tools/bench_native_store.py and the
-    round-13 sorted-run acceptance so recorded numbers can never drift
-    in methodology. ``tick`` is an optional per-chunk progress callback
-    (the bench watchdog).
+    production bulk build. Returns keys/s. Shared by
+    tools/bench_native_store.py and the round-13 sorted-run acceptance
+    (tests/test_ingest.py) so recorded numbers can never drift in
+    methodology.
 
     Modes (same keys in, same index out — rows differ only in the order
     contract each mode documents):
@@ -303,8 +301,6 @@ def bench_index_build(n_keys: int, *, chunk: int = 10_000_000,
         merger = SortedRunMerger()
         for lo in range(0, n_keys, chunk):
             merger.add_run(dedup_keys(keys[lo:lo + chunk]))
-            if tick is not None:
-                tick(lo)
         idx = KeyIndex()
         idx.bulk_build(merger.merge())
     elif mode == "dict":
@@ -320,8 +316,6 @@ def bench_index_build(n_keys: int, *, chunk: int = 10_000_000,
                     r = len(fb)
                     fb[kk] = r
                 out[i] = r
-            if tick is not None:
-                tick(lo)
         dt = _time.perf_counter() - t0
         return n_keys / dt
     else:
@@ -331,8 +325,6 @@ def bench_index_build(n_keys: int, *, chunk: int = 10_000_000,
         idx.reserve(n_keys)
         for lo in range(0, n_keys, chunk):
             idx.upsert(keys[lo:lo + chunk])
-            if tick is not None:
-                tick(lo)
     dt = _time.perf_counter() - t0
     idx.close()
     return n_keys / dt

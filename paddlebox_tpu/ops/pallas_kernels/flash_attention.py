@@ -462,8 +462,7 @@ def flash_attention(q, k, v, *, causal: bool = False,
     ``use_pallas=None`` auto-selects: the Pallas kernel on TPU backends,
     the XLA reference elsewhere (``interpret=True`` forces the kernel in
     interpreter mode — for tests). ``block_q``/``block_k`` default to
-    the ``flash_block_{q,k}`` flags (tuned per hardware by
-    tools/tune_flash_blocks.py) so every call site picks up the tuned
+    the ``flash_block_{q,k}`` flags so every call site picks up the same
     tiles without plumbing.
     """
     from paddlebox_tpu.core import flags as _flags

@@ -4,7 +4,7 @@ Role of the reference's pull-side CUDA kernels (``box_wrapper.cu``
 CopyForPull + the HeterComm per-shard table get): materialize the pull
 payload ``table[rows, :pw]`` for a batch of request rows at memory
 bandwidth. XLA's TPU gather costs ~6 ns/element regardless of layout
-(PROFILE.md: 16.2 ms for [426K x 16], 25.4 ms at pull width 40) — two
+(r02 chip run: 16.2 ms for [426K x 16], 25.4 ms at pull width 40) — two
 orders of magnitude off HBM bandwidth for what is a streaming read. This
 kernel instead SORTS the requests by destination row (XLA argsort —
 cheap, and SHARED with the push-side ``sorted_scatter`` via

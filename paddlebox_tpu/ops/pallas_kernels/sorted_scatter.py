@@ -4,7 +4,7 @@ Role of the reference's push-side CUDA kernels (``box_wrapper.cu``
 CopyForPush + ``heter_comm`` dynamic_merge_grad): merge a batch of
 per-occurrence sparse updates into a per-row accumulator at memory
 bandwidth. XLA's TPU scatter costs ~7 ns/element regardless of hints
-(PROFILE.md) — ~55 ms for the bench step's 426K×20 update. This kernel
+(r02 chip run) — ~55 ms for the DeepFM step's 426K×20 update. This kernel
 instead SORTS the updates by destination row (XLA sort — cheap) and
 streams the accumulator through VMEM one block at a time, applying each
 block's contiguous run of updates with in-VMEM dynamic-row adds.

@@ -96,8 +96,8 @@ class StreamRunner(DayRunner):
 
     def poll_once(self, *, flush: bool = False) -> int:
         """One tail step: scan the log dir, durably carve ready passes,
-        train each, publish each delta. Returns passes trained. Tests,
-        bench and the crash drill call this directly; ``run`` wraps it
+        train each, publish each delta. Returns passes trained. Tests
+        and the crash drill call this directly; ``run`` wraps it
         in the idle-sleep loop."""
         faults.init_from_flags()
         faults.faultpoint("stream/source_poll")
@@ -192,6 +192,6 @@ class StreamRunner(DayRunner):
 
     def freshness_quantiles(self) -> Optional[Dict[str, float]]:
         """p50/p90/p99/p999 of event→servable ms (None before the first
-        pass) — what `bench.py online` records and perf_gate gates."""
+        pass): the freshness an online deployment is judged by."""
         d = monitor.GLOBAL.quantile_digest("stream/event_to_servable_ms")
         return d.quantiles() if d is not None else None

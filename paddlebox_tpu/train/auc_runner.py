@@ -33,8 +33,7 @@ def slot_replacement_eval(trainer, dataset, *,
     plus per-slot ``quality/slot_auc/<slot>`` /
     ``quality/slot_auc_drop/<slot>`` gauges — so per-slot AUC
     degradation is recordable through the telemetry plane (JSONL
-    export, ``metrics_snapshot`` scrape, ``bench.py deepfm
-    --slot-auc``) instead of print-only.
+    export, ``metrics_snapshot`` scrape) instead of print-only.
     """
     base = trainer.eval_pass(dataset)
     names = list(slots) if slots is not None else [

@@ -28,9 +28,8 @@ import optax
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from paddlebox_tpu.core import (faults, flags, log, monitor,
-                                pipeline_stats, quality, report, timers,
-                                trace, watchdog)
+from paddlebox_tpu.core import (faults, flags, log, monitor, quality,
+                                report, timers, trace, watchdog)
 from paddlebox_tpu.data.dataset import Dataset
 from paddlebox_tpu.data.slots import DataFeedConfig, SlotBatch
 from paddlebox_tpu.embedding import TableConfig, make_sparse_optimizer
@@ -892,7 +891,6 @@ class CTRTrainer:
         pass_t0 = time.perf_counter()
         stage_base = self.timers.snapshot_ms()
         boundary_base = self.engine.boundary_ms()
-        pipe_base = pipeline_stats.GLOBAL.snapshot()
         disp_q_base = monitor.GLOBAL.quantile_digest("trainer/dispatch_ms")
         self._seg_cache_hits = 0
         self._seg_cache_misses = 0
@@ -922,7 +920,6 @@ class CTRTrainer:
             for args in self._prefetch_batches(dataset, k=k_disp):
                 t_disp0 = time.perf_counter()
                 with self.timers.scope("dispatch"), \
-                        pipeline_stats.GLOBAL.busy("device"), \
                         trace.span("pass/dispatch", kind="eval",
                                    block=n_blocks, k=k_disp):
                     if k_disp == 1:
@@ -949,7 +946,6 @@ class CTRTrainer:
         finally:
             eng.abort_pass()
         with self.timers.scope("sync"), \
-                pipeline_stats.GLOBAL.busy("device"), \
                 trace.span("pass/final_fetch"):
             stats = self._auc_stats(auc)
             # graftlint: allow-sync(pass-end stat fetch inside the sync scope)
@@ -961,8 +957,6 @@ class CTRTrainer:
         stats["seg_cache_hit_rate"] = self._seg_cache_rate()
         stats["boundary"] = self._boundary_delta(boundary_base)
         wall_s = time.perf_counter() - pass_t0
-        stats["bottleneck"] = self._bottleneck_verdict(
-            pipe_base, stats["boundary"], wall_s)
         stats["dispatch_ms_quantiles"] = self._dispatch_quantiles(
             disp_q_base)
         stats["pass_report"] = report.emit_pass_report(
@@ -1065,20 +1059,15 @@ class CTRTrainer:
             return dev
 
         def _put(item) -> bool:
-            # blocked_down on the packer stage: time spent here with the
-            # queue FULL means the device side is the slower half (a
-            # healthy sign); near-zero put-wait with a starved consumer
-            # means the host pipeline is the wall.
-            with pipeline_stats.GLOBAL.blocked_down("packer"):
-                while not stop.is_set():
-                    try:
-                        q.put(item, timeout=0.1)
-                        pipeline_stats.GLOBAL.sample_queue(
-                            "producer_queue", q.qsize())
-                        return True
-                    except queue.Full:
-                        continue
-                return False
+            # Polls with a timeout: a consumer that left early sets
+            # ``stop`` and never drains the full queue.
+            while not stop.is_set():
+                try:
+                    q.put(item, timeout=0.1)
+                    return True
+                except queue.Full:
+                    continue
+            return False
 
         n_groups = len(self.engine.groups)
         # Map-ahead worker (FLAGS_trainer_map_ahead): the host keymap
@@ -1098,14 +1087,12 @@ class CTRTrainer:
             # half of PullSparse (feasign -> device-row keymap, the
             # CopyKeys role); "pack" is batch assembly + dtype prep.
             faults.faultpoint("trainer/map_ahead")
-            with self.timers.scope("pull"), trace.span("prefetch/keymap"), \
-                    pipeline_stats.GLOBAL.busy("keymap"):
+            with self.timers.scope("pull"), trace.span("prefetch/keymap"):
                 return self._map_batch_rows_host(batch)
 
         def _pack_host(batch, rows_h):
             faults.faultpoint("trainer/pack")
-            with self.timers.scope("pack"), \
-                    pipeline_stats.GLOBAL.busy("packer"):
+            with self.timers.scope("pack"):
                 dense_h = _concat_dense_host(batch)
                 if dense_bf16:
                     import ml_dtypes
@@ -1115,8 +1102,7 @@ class CTRTrainer:
                         batch.labels, batch.valid, dense_h)
 
         def _stack_block(blk):
-            with self.timers.scope("pack"), \
-                    pipeline_stats.GLOBAL.busy("packer"):
+            with self.timers.scope("pack"):
                 n_active = len(blk)
                 # static-shape tail pad
                 blk = blk + [blk[-1]] * (k - n_active)
@@ -1143,8 +1129,7 @@ class CTRTrainer:
                 # timer); separate from pack/pull so a starved pass
                 # is distinguishable from a slow keymap.
                 faults.faultpoint("trainer/prefetch")
-                with self.timers.scope("read"), \
-                        pipeline_stats.GLOBAL.busy("reader"):
+                with self.timers.scope("read"):
                     return next(it, _EOF)
 
             try:
@@ -1166,8 +1151,7 @@ class CTRTrainer:
                         faults.faultpoint("trainer/pack")
                         with self.timers.scope("host_map"), \
                                 trace.span("prefetch/host_map"):
-                            with self.timers.scope("pack"), \
-                                    pipeline_stats.GLOBAL.busy("packer"):
+                            with self.timers.scope("pack"):
                                 dense_h = _concat_dense_host(batch)
                                 if dense_bf16:
                                     import ml_dtypes
@@ -1208,14 +1192,8 @@ class CTRTrainer:
         t.start()
         try:
             while True:
-                # blocked_up on the device stage: the consumer (and so
-                # the device's supply of new blocks) starved waiting on
-                # the host pipeline — the device_idle_frac numerator.
-                with pipeline_stats.GLOBAL.blocked_up("device"), \
-                        trace.span("pass/feed_wait"):
+                with trace.span("pass/feed_wait"):
                     item = q.get()
-                pipeline_stats.GLOBAL.sample_queue("producer_queue",
-                                                   q.qsize())
                 if item is _DONE:
                     break
                 if isinstance(item, BaseException):
@@ -1331,15 +1309,14 @@ class CTRTrainer:
         if self.params is None:
             raise RuntimeError("call init() first")
         # Telemetry is host-side only: flag-armed sinks, a per-pass stage
-        # baseline (the TimerGroup is cumulative across passes — bench.py
-        # reads the totals), and seg-cache counters. NOTHING below adds
+        # baseline (the TimerGroup is cumulative across passes), and
+        # seg-cache counters. NOTHING below adds
         # ops or syncs to the jitted step.
         report.init_telemetry_from_flags()
         faults.init_from_flags()
         pass_t0 = time.perf_counter()
         stage_base = self.timers.snapshot_ms()
         boundary_base = self.engine.boundary_ms()
-        pipe_base = pipeline_stats.GLOBAL.snapshot()
         disp_q_base = monitor.GLOBAL.quantile_digest("trainer/dispatch_ms")
         self._seg_cache_hits = 0
         self._seg_cache_misses = 0
@@ -1423,7 +1400,6 @@ class CTRTrainer:
             pending_finite = None
             self._host_syncs += 1
             with self.timers.scope("sync"), \
-                    pipeline_stats.GLOBAL.busy("device"), \
                     trace.span("pass/sync_finite"):
                 fv = np.asarray(fin)[:na]
             if not fv.all():
@@ -1523,7 +1499,6 @@ class CTRTrainer:
             # the synced step wall (credited to fwd_bwd below).
             with self.timers.scope("device_step"), \
                     self.timers.scope("dispatch"), \
-                    pipeline_stats.GLOBAL.busy("device"), \
                     trace.span("pass/dispatch",
                                block=self._dispatch_blocks, k=k_disp):
                 if k_disp == 1:
@@ -1625,7 +1600,6 @@ class CTRTrainer:
         # "sync" = blocking device fetches: the pass-end stat reductions
         # (plus any deferred finite-vector fetches counted above).
         with self.timers.scope("sync"), \
-                pipeline_stats.GLOBAL.busy("device"), \
                 trace.span("pass/final_fetch"):
             stats = self._auc_stats(self.auc_state)
             # graftlint: allow-sync(pass-end stat fetch inside the sync scope)
@@ -1681,11 +1655,7 @@ class CTRTrainer:
         stats["seg_cache_hit_rate"] = self._seg_cache_rate()
         stats["boundary"] = self._boundary_delta(boundary_base)
         wall_s = time.perf_counter() - pass_t0
-        # Critical-path attribution: the occupancy window over this pass
-        # plus the boundary halves -> ONE bottleneck verdict, and the
-        # dispatch-latency digest window -> p50/p90/p99/p999.
-        stats["bottleneck"] = self._bottleneck_verdict(
-            pipe_base, stats["boundary"], wall_s)
+        # The dispatch-latency digest window -> p50/p90/p99/p999.
         stats["dispatch_ms_quantiles"] = self._dispatch_quantiles(
             disp_q_base)
         # The PrintSyncTimer moment: ONE structured per-pass summary
@@ -1718,8 +1688,7 @@ class CTRTrainer:
         auc = auc_state if auc_state is not None else self.auc_state
         q_table = None
         if self.num_tasks == 1 and auc is not None:
-            with self.timers.scope("sync"), \
-                    pipeline_stats.GLOBAL.busy("device"):
+            with self.timers.scope("sync"):
                 # graftlint: allow-sync(pass-end quality table fetch inside the sync scope)
                 q_table = np.asarray(auc.table, np.float64)
         # Slot health rides TRAIN passes only: eval re-walks the same
@@ -1763,26 +1732,6 @@ class CTRTrainer:
             d["exchange_overlap_frac"] = round(
                 min(1.0, max(0.0, 1.0 - xwait / xbusy)), 4)
         return d
-
-    def _bottleneck_verdict(self, pipe_base, boundary,
-                            wall_s: float) -> Dict[str, Any]:
-        """The pass's critical-path verdict: the occupancy window since
-        ``pipe_base`` (reader/packer/keymap/device states + queue
-        depths) with the engine's boundary halves injected as a
-        ``boundary`` stage (build minus its blocked wait = busy; the
-        wait itself = blocked_up; end_pass write-back counts as busy —
-        it holds the store against the next build)."""
-        win = pipeline_stats.GLOBAL.window(pipe_base)
-        b = boundary or {}
-        build = float(b.get("build_ms") or 0.0)
-        wait = float(b.get("feed_wait_ms") or 0.0)
-        end = float(b.get("end_ms") or 0.0)
-        if build > 1e-6 or wait > 1e-6 or end > 1e-6:
-            win["stages"]["boundary"] = {
-                "busy_ms": round(max(build - wait, 0.0) + end, 3),
-                "blocked_up_ms": round(wait, 3),
-                "blocked_down_ms": 0.0, "count": 1}
-        return pipeline_stats.bottleneck_verdict(win, wall_s * 1e3)
 
     def _dispatch_quantiles(self, base) -> Optional[Dict[str, float]]:
         """This pass's dispatch-latency p50/p90/p99/p999 from the

@@ -26,9 +26,8 @@ import numpy as np
 
 from paddlebox_tpu.checkpoint.protocol import (CheckpointProtocol,
                                                get_online_pass_interval)
-from paddlebox_tpu.core import (faults, flags, log, monitor,
-                                pipeline_stats, quality, report, timers,
-                                trace, watchdog)
+from paddlebox_tpu.core import (faults, flags, log, monitor, quality,
+                                report, timers, trace, watchdog)
 from paddlebox_tpu.data.dataset import Dataset
 
 
@@ -218,12 +217,7 @@ class DayRunner:
         ds = Dataset(self.feed_config,
                      num_reader_threads=self.num_reader_threads)
         ds.set_filelist(files)
-        # Occupancy: a pipelined day loop runs this in the preload
-        # thread, so day_load overlapping a training window shows up in
-        # that pass's verdict exactly like the reference's
-        # PreLoadIntoMemory overlap would.
-        with pipeline_stats.GLOBAL.busy("day_load"), \
-                trace.span("ingest/load", day=day, pass_id=pass_id):
+        with trace.span("ingest/load", day=day, pass_id=pass_id):
             ds.load_into_memory()
         if feed:
             self._feed_keys(ds, day, pass_id)

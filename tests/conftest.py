@@ -5,7 +5,7 @@ Role of the reference's localhost fake-cluster test mechanism
 instead of subprocesses, JAX gives us N virtual devices in one process via
 ``--xla_force_host_platform_device_count``, so every multi-chip sharding test
 runs single-process on CPU. Behaviour on real chips is exercised by
-chip_smoke.py and bench.py on the chip.
+chip_smoke.py and benchmarks/run.py on the chip.
 
 This file must set the env vars BEFORE jax is imported anywhere.
 """

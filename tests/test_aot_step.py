@@ -88,8 +88,8 @@ def test_dense_bench_steps_aot_compile_for_tpu():
 
 @pytest.mark.slow
 def test_scale_steps_aot_compile_for_tpu_256_chips():
-    """The 8->256-chip scaling evidence (BASELINE.md metric 3) the bench
-    chip can't give: the multislice CTR step (slice=4 x dp=64) and the
+    """The 8->256-chip scaling evidence one chip or one four-chip host
+    can't give: the multislice CTR step (slice=4 x dp=64) and the
     hybrid GPT step (slice x dp x pp x sp x mp) lower + compile against
     a real 16x16 v5e compile-only topology — XLA schedules the full
     256-chip collective program (slice axis logical on the single-slice

@@ -8,7 +8,7 @@ Two halves:
    produces nothing. Plus baseline suppression / ``--fail-on new``
    semantics and the near-miss metric-name warning.
 2. **The real tree** — ``run_passes(default_config(REPO))`` over
-   ``paddlebox_tpu/``, ``tools/`` and ``bench.py`` must produce ZERO
+   ``paddlebox_tpu/`` and ``tools/`` must produce ZERO
    non-baselined error findings: a PR that introduces a hot-path sync,
    an undocumented flag/metric, a faultpoint/doc drift, an unlocked
    cross-thread write, or replay-path wall-clock FAILS this suite.
@@ -445,8 +445,8 @@ def test_baseline_save_load_roundtrip(tmp_path):
 # ---------------------------------------------------------------------------
 
 def test_real_tree_has_no_new_findings():
-    """The tier-1 contract: graftlint over paddlebox_tpu/, tools/ and
-    bench.py yields zero non-baselined errors. If this fails, either fix
+    """The tier-1 contract: graftlint over paddlebox_tpu/ and tools/
+    yields zero non-baselined errors. If this fails, either fix
     the finding, add an inline pragma with a reason, or (for a reviewed
     intentional case) add a baseline entry with a reason."""
     cfg = default_config(REPO)

@@ -129,13 +129,9 @@ def test_train_pass_report_with_megastep_and_artifacts(shard_13,
     assert rep["lookup_exchange_bytes"] == stats["lookup_exchange_bytes"]
     assert rep["lookup_exchange_bytes"] > 0
     assert "seg_cache_hit_rate" in rep
-    # -- critical-path attribution (round 11) -------------------------
-    bn = rep["bottleneck"]
-    assert bn["stage"] is not None
-    assert 0.0 <= bn["device_idle_frac"] <= 1.0
-    assert 0.0 <= bn["host_critical_share"] <= 1.0
-    for stage in ("reader", "packer", "keymap", "device"):
-        assert stage in bn["stages"]
+    # No host-side idle estimate: the device's idle share is read from
+    # the device trace (benchmarks/), which the program cannot see.
+    assert "bottleneck" not in rep and "bottleneck" not in stats
     dq = rep["dispatch_ms_quantiles"]
     assert dq["count"] == stats["dispatch_blocks"]
     assert dq["p50"] <= dq["p99"]
@@ -168,14 +164,14 @@ def test_train_pass_report_with_megastep_and_artifacts(shard_13,
     assert last["gauges"]["pass/train_samples_per_s"] > 0
     assert last["counters"]["lookup/exchange_bytes_per_step"] == \
         stats["lookup_exchange_bytes"]
-    # Quantile digests ride the snapshot (mergeable across ranks), and
-    # the occupancy gauges feed trace_report's pipeline table.
+    # Quantile digests ride the snapshot (mergeable across ranks).
     q = last["quantiles"]["trainer/dispatch_ms"]
     assert q["count"] == stats["dispatch_blocks"]
     assert q["p50"] is not None
-    assert last["gauges"]["pass/train_device_idle_frac"] == \
-        rep["bottleneck"]["device_idle_frac"]
-    assert "pipeline/device_busy_frac" in last["gauges"]
+    assert not [g for g in last["gauges"]
+                if g.startswith("pipeline/")
+                or g.endswith(("_device_idle_frac",
+                               "_host_critical_share"))]
 
 
 def test_eval_pass_report(shard_13, telemetry_paths):
@@ -272,3 +268,122 @@ def test_day_runner_timers_reach_registry(shard_13, tmp_path,
     assert snap["day_runner/train_ms"] > 0.0
     assert snap["day_runner/passes"] == 1
     assert snap["pass/train_passes"] >= 1
+
+
+# What a pass report carries (a day loop adds its own ingest counters).
+EVAL_REPORT_KEYS = {
+    "kind", "steps", "samples", "wall_s", "samples_per_s", "stage_ms",
+    "other_ms", "auc", "bucket_error", "mae", "rmse", "actual_ctr",
+    "predicted_ctr", "copc", "count", "loss", "dispatch_blocks",
+    "steps_per_dispatch", "seg_cache_hit_rate", "boundary",
+    "dispatch_ms_quantiles"}
+TRAIN_REPORT_KEYS = EVAL_REPORT_KEYS | {
+    "host_syncs", "lookup_overflow", "kernel_fallback",
+    "kernel_hot_served", "lookup_exchange_bytes", "lookup_duplication",
+    "scale_sparse_grad_by_batch"}
+
+
+def test_train_pass_emits_report_keys_and_dispatch_quantiles(shard_13):
+    """A train pass's report: every key, and dispatch-latency quantiles
+    windowed to this pass's blocks, mirrored as registry gauges."""
+    monitor.reset()
+    tr = _trainer()
+    stats = tr.train_pass(_dataset(shard_13))
+    rep = stats["pass_report"]
+    assert set(rep) == TRAIN_REPORT_KEYS
+    assert set(rep["boundary"]) == {"end_ms", "build_ms", "feed_wait_ms",
+                                    "overlap_frac"}
+    dq = rep["dispatch_ms_quantiles"]
+    assert dq is stats["dispatch_ms_quantiles"]
+    assert dq["count"] == stats["dispatch_blocks"] == N_BATCHES
+    assert dq["p50"] is not None and dq["p50"] > 0.0
+    assert dq["p50"] <= dq["p90"] <= dq["p99"] <= dq["p999"]
+    snap = monitor.snapshot()
+    assert snap["pass/train_dispatch_ms_p99"] == dq["p99"]
+    assert not [g for g in snap if g.startswith("pipeline/")]
+
+
+def test_eval_pass_emits_report_keys(shard_13):
+    tr = _trainer()
+    stats = tr.eval_pass(_dataset(shard_13))
+    rep = stats["pass_report"]
+    assert set(rep) == EVAL_REPORT_KEYS
+    assert rep["dispatch_ms_quantiles"]["count"] == \
+        stats["dispatch_blocks"]
+    # Eval writes nothing back: no end_pass half in its boundary.
+    assert rep["boundary"]["end_ms"] == 0.0
+
+
+def test_pass_windows_are_independent(shard_13):
+    """Two consecutive passes each get their OWN window: the timers and
+    the dispatch digest are cumulative, the report differences them."""
+    monitor.reset()
+    tr = _trainer()
+    ds = _dataset(shard_13)     # reusable (in memory)
+    s1 = tr.train_pass(ds)
+    s2 = tr.train_pass(ds)
+    for s in (s1, s2):
+        assert s["dispatch_ms_quantiles"]["count"] == s["dispatch_blocks"]
+    assert tr.timers["dispatch"].count == (s1["dispatch_blocks"]
+                                           + s2["dispatch_blocks"])
+    total = tr.timers.snapshot_ms()
+    for stage in ("read", "pack", "pull", "dispatch", "push"):
+        a, b = (s["pass_report"]["stage_ms"][stage] for s in (s1, s2))
+        assert a > 0.0 and b > 0.0
+        assert a + b == pytest.approx(total[stage], abs=0.01)
+
+
+# (group, stage timer, span) of each ``with`` that opens both marks.
+TIMER_SPAN_SITES = (
+    ("trainer", "pull", "prefetch/keymap"),
+    ("trainer", "host_map", "prefetch/host_map"),
+    ("trainer", "dispatch", "pass/dispatch"),
+    ("trainer", "end_pass", "pass/end_pass"),
+    ("engine", "feed_pass", "build/pass_table"),
+    ("engine", "feed_wait", "build/boundary_wait"),
+    ("runner", "load", "day/load"),
+    ("runner", "train", "day/train"),
+)
+
+
+@pytest.fixture(scope="module")
+def traced_day(tmp_path_factory):
+    """One pipelined three-pass CPU day with the ring on: the span count
+    per name, and the timer groups that marked the same sites (one pass
+    engine per width group under the trainer's grouped engine)."""
+    from collections import Counter
+
+    from tests.test_day_runner import _write_day
+    from tests.test_day_runner_device_store import _make_runner
+
+    root = tmp_path_factory.mktemp("timer_span_day")
+    _write_day(str(root / "data"), "20260701", [0, 1, 2])
+    trainer, runner = _make_runner(str(root / "data"), str(root / "out"),
+                                   build_mesh(HybridTopology(dp=8)))
+    trace.clear()
+    trace.enable(ring_events=1 << 16)
+    try:
+        assert len(runner.train_day("20260701")) == 3
+        ring = trace.GLOBAL.trace_object()
+    finally:
+        trace.disable()
+        trace.clear()
+    assert ring["otherData"]["dropped_events"] == 0
+    spans = Counter(e["name"] for e in ring["traceEvents"]
+                    if e["ph"] == "X")
+    return spans, {"trainer": [trainer.timers],
+                   "engine": [g.engine.timers
+                              for g in trainer.engine.groups],
+                   "runner": [runner.timers]}
+
+
+@pytest.mark.parametrize(
+    "group,stage,span", TIMER_SPAN_SITES,
+    ids=[f"{stage}-{span}" for _, stage, span in TIMER_SPAN_SITES])
+def test_stage_timer_and_span_mark_the_same_sites(traced_day, group,
+                                                  stage, span):
+    """The pass report's stage totals and the ring's spans are two marks
+    on one ``with``: a site that keeps one and loses the other shows as
+    a count that differs (counts, not times: this is a CPU clock)."""
+    spans, timers = traced_day
+    assert sum(t[stage].count for t in timers[group]) == spans[span] > 0

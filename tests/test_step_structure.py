@@ -2,7 +2,7 @@
 
 The r02→r03 rework collapsed the push path from six scatter-adds +
 three argsorts + six gathers per step to ONE owner-side
-scatter-accumulate + a dense optimizer sweep (PROFILE.md: XLA TPU
+scatter-accumulate + a dense optimizer sweep (r02 chip run: XLA TPU
 scatter costs ~7 ns/element, so scatter COUNT is the step's cost
 model). These tests pin the op-level shape of the compiled program so a
 refactor that quietly reintroduces per-field scatters (or a second

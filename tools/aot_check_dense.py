@@ -1,7 +1,7 @@
 """AOT-compile the dense benchmark train steps (resnet50 bf16, BERT-base)
 for TPU — no TPU needed (compile-only PJRT topology).
 
-These two bench harnesses had never run on hardware before round 3 (both
+These two steps had never run on hardware before round 3 (both
 carried calling-convention bugs), so their TPU-compile surface — notably
 the bf16 conv forward/transpose path resnet now uses — is exactly the
 kind of thing that would otherwise only fail inside the recorded run:
@@ -35,10 +35,9 @@ from tools._aot_common import sds, tpu_topology  # noqa: E402
 
 
 def check_resnet(sh) -> None:
-    """bench_resnet50's step shape: bf16 compute params (BN stats f32),
-    f32 master merge — the conv dtype-symmetry fix under autodiff.
-    Uses the SAME amp helpers bench_resnet50 imports, so this check
-    cannot drift from the step it certifies."""
+    """The resnet50 AMP step: bf16 compute params (BN stats f32), f32
+    master merge — the conv dtype-symmetry fix under autodiff, through
+    the package's own amp helpers."""
     from paddlebox_tpu.amp import (cast_compute_except_stats as
                                    cast_compute)
     from paddlebox_tpu.amp import merge_bn_stats as merge_bn

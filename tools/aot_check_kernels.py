@@ -28,18 +28,18 @@ from jax.sharding import PartitionSpec as P  # noqa: E402
 
 from tools._aot_common import tpu_topology  # noqa: E402
 
-# (updates, payload width, rows incl. trash) — bench_deepfm push,
-# bench_wide_deep push, and the tiny probe shape.
+# (updates, payload width, rows incl. trash) — the deepfm_criteo cells'
+# push, a Wide&Deep-sized push, and the tiny probe shape.
 SCATTER_SHAPES = [
     (425_984, 20, 4_194_305),
     (163_840, 12, 1_048_577),
     (64, 8, 9000),
 ]
 
-# (requests, pull width, table width, rows incl. trash) — bench_deepfm
-# pull (426K ids from the [4M, W] fused table; rows NOT a multiple of
-# the kernel BLOCK, so this also pins Mosaic's padded tail-block fetch)
-# and the tiny probe shape.
+# (requests, pull width, table width, rows incl. trash) — the
+# deepfm_criteo cells' pull (426K ids from the [4M, W] fused table; rows
+# NOT a multiple of the kernel BLOCK, so this also pins Mosaic's padded
+# tail-block fetch) and the tiny probe shape.
 GATHER_SHAPES = [
     (425_984, 16, 20, 4_194_305),
     (425_984, 40, 40, 4_194_305),
@@ -76,7 +76,7 @@ def main() -> None:
         print(f"AOT sorted_gather [{n}] <- [{rows_n} x {w}] width {pw}: OK",
               flush=True)
 
-    # bench_gpt's shape: [4, 1024, 16, 64], causal, with gradients.
+    # A GPT-2-medium-wide batch: [4, 1024, 16, 64], causal, with gradients.
     q = sds((4, 1024, 16, 64), jnp.float32)
     jax.jit(jax.grad(
         lambda q, k, v: flash_attention(q, k, v, causal=True,

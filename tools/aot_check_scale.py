@@ -1,7 +1,6 @@
 """AOT-compile the training steps at SCALE topologies (64 and 256 chips)
-— evidence for the 8→256-chip scaling metric (BASELINE.md metric 3)
-without 256 real chips: the real XLA:TPU pipeline lowers the full
-multislice CTR step (slice-hierarchical dense sync, intra-slice
+— evidence for 8→256-chip scaling without 256 real chips: the real
+XLA:TPU pipeline lowers the full multislice CTR step (slice-hierarchical dense sync, intra-slice
 all-to-all pull/push with the DCN accumulator psum) and the hybrid GPT
 step at production-shaped meshes.
 

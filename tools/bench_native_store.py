@@ -52,8 +52,7 @@ def main() -> None:
 
     out = {"keys": n}
     # The headline metrics come from the ONE shared definition
-    # (store_py.bench_index_build — same as bench.py's
-    # host_index_build_keys_per_s / host_index_bulk_build_keys_per_s).
+    # (store_py.bench_index_build).
     out["index_build_keys_per_s"] = round(bench_index_build(n))
     # Round 13: sorted-run build (per-chunk dedup → run merge →
     # bulk_build) and the pre-r13 per-key dict walk it is measured

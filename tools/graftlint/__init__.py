@@ -3,8 +3,8 @@
 Six PRs of runtime conventions (zero hot-loop syncs, bit-identical
 replay, flag/faultpoint/metric registries mirrored in docs, lock
 discipline across the threaded pipeline) become machine-checked
-invariants: five AST passes over ``paddlebox_tpu/``, ``tools/`` and
-``bench.py``, stdlib-only, no jax import, runs in tier-1.
+invariants: five AST passes over ``paddlebox_tpu/`` and ``tools/``,
+stdlib-only, no jax import, runs in tier-1.
 
     python -m tools.graftlint                  # human-readable, exit 1 on new
     python -m tools.graftlint --json           # findings as JSON

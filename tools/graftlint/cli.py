@@ -27,7 +27,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                     "purity (see STATIC_ANALYSIS.md)")
     ap.add_argument("paths", nargs="*",
                     help="roots to analyze, relative to --root "
-                         "(default: paddlebox_tpu tools bench.py)")
+                         "(default: paddlebox_tpu tools)")
     ap.add_argument("--root", default=None,
                     help="repo root (default: the parent of tools/)")
     ap.add_argument("--json", action="store_true",

@@ -17,7 +17,7 @@ from typing import Dict, List, Optional, Sequence
 class Config:
     root: str
     # analyzed file roots, relative to ``root``
-    roots: Sequence[str] = ("paddlebox_tpu", "tools", "bench.py")
+    roots: Sequence[str] = ("paddlebox_tpu", "tools")
     exclude: Sequence[str] = ()
     # -- pass 1: hot-path sync detector -----------------------------------
     # Functions whose transitive callees must not sync the host: the

@@ -1,9 +1,8 @@
 """Summarize a span trace (and/or metrics JSONL) into per-stage tables.
 
-The PROFILE.md workflow in one command: point it at the artifacts a
-telemetry-enabled run wrote (``FLAGS_trace_path`` / ``FLAGS_metrics_path``)
-and get the same shape of table the profile rounds hand-build — per
-span name: count, total ms, p50/p95/max, share of the traced wall —
+Point it at the artifacts a telemetry-enabled run wrote
+(``FLAGS_trace_path`` / ``FLAGS_metrics_path``) and get, per span name:
+count, total ms, p50/p95/max, share of the traced wall —
 plus the registry's counters/gauges and bucket-estimated histogram
 percentiles from the newest metrics snapshot.
 
@@ -87,52 +86,11 @@ def _hist_pct(buckets, counts, q):
     return f">{buckets[-1]:g}"
 
 
-def _report_occupancy(gauges: dict) -> None:
-    """Pipeline occupancy / bottleneck section from the pipeline/*
-    gauges the pass report publishes (core/pipeline_stats.py): per-stage
-    busy + blocked shares of the last pass window, the implied bounding
-    stage (highest busy share), and the headline fractions."""
-    stages = {}
-    for name, v in gauges.items():
-        if not name.startswith("pipeline/"):
-            continue
-        rest = name[len("pipeline/"):]
-        for suffix in ("busy_ms", "busy_frac", "blocked_up_frac",
-                       "blocked_down_frac"):
-            if rest.endswith("_" + suffix):
-                stages.setdefault(rest[:-len(suffix) - 1], {})[suffix] = v
-    if not stages:
-        return
-    hdr = (f"\n{'pipeline stage':<16} {'busy_ms':>10} {'busy':>7} "
-           f"{'blk_up':>7} {'blk_dn':>7}")
-    print(hdr)
-    print("-" * len(hdr))
-    for name in sorted(stages, key=lambda n: -stages[n].get("busy_frac",
-                                                            0.0)):
-        s = stages[name]
-        print(f"{name:<16} {s.get('busy_ms', 0.0):>10.2f} "
-              f"{s.get('busy_frac', 0.0):>6.1%} "
-              f"{s.get('blocked_up_frac', 0.0):>6.1%} "
-              f"{s.get('blocked_down_frac', 0.0):>6.1%}")
-    bounding = max(stages, key=lambda n: stages[n].get("busy_frac", 0.0))
-    parts = [f"bottleneck: {bounding}"]
-    def pct(v):
-        return f"{v:.1%}" if isinstance(v, (int, float)) else "-"
-
-    for kind in ("train", "eval"):
-        idle = gauges.get(f"pass/{kind}_device_idle_frac")
-        host = gauges.get(f"pass/{kind}_host_critical_share")
-        if idle is not None or host is not None:
-            parts.append(f"{kind}: device_idle={pct(idle)} "
-                         f"host_critical={pct(host)}")
-    print("  ".join(parts))
-
-
 def _report_quality(gauges: dict, counters: dict) -> None:
     """Model quality & data health section (core/quality.py): the
     COPC/calibration headline, every quality alarm counter, and the
-    per-slot health gauges (coverage / zero rate / churn / skew) — so
-    a PROFILE round reads model health beside the stage tables."""
+    per-slot health gauges (coverage / zero rate / churn / skew),
+    beside the stage tables."""
     qg = {k: v for k, v in gauges.items() if k.startswith("quality/")}
     qa = {k: v for k, v in counters.items()
           if k.startswith("quality/")}
@@ -174,7 +132,7 @@ def _report_quality(gauges: dict, counters: dict) -> None:
 def _report_quantiles(quantiles: dict) -> None:
     """Streaming-digest percentiles (core/quantiles.py): exact-count,
     rel-error-bounded p50/p90/p99/p999 — the dispatch-latency and
-    serving-SLO view, plus queue depths."""
+    serving-SLO view."""
     if not quantiles:
         return
     hdr = (f"\n{'quantile digest':<32} {'count':>8} {'p50':>9} "
@@ -217,7 +175,6 @@ def report_metrics(path: str) -> None:
                   f"{_hist_pct(h['buckets'], h['counts'], 0.95):>8} "
                   f"{(h['max'] if h['max'] is not None else 0):>9.3f}")
     _report_quantiles(last.get("quantiles", {}))
-    _report_occupancy(last.get("gauges", {}))
     _report_quality(last.get("gauges", {}), last.get("counters", {}))
     gauges = last.get("gauges", {})
     if gauges:
