@@ -21,25 +21,29 @@ Built like ``models/gpt.py``: one ``shard_map`` over the hybrid mesh,
 vocabulary-parallel embedding and cross entropy over ``mp``, batch over
 the data axes; every other weight is whole on every device. The layers
 differ, so they are a Python list (no ``lax.scan`` over equal blocks), and
-each is rematerialised (``jax.checkpoint``): what a layer keeps for its
-backward pass is its input.
+each is rematerialised (``jax.checkpoint``): a layer keeps its input for
+its backward pass and, of what its forward computes, the values
+``plan_residuals`` finds room for in the device's memory.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
-from typing import Dict, Tuple
+from typing import Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
 
 from paddlebox_tpu.core import flags, trace
 from paddlebox_tpu.models.gpt import _data_axes
 from paddlebox_tpu.models.train_step import make_train_step
-from paddlebox_tpu.ops.pallas_kernels.flash_attention import flash_attention
+from paddlebox_tpu.ops.pallas_kernels.flash_attention import (
+    RESIDUAL_NAMES as FLASH_RESIDUAL_NAMES, flash_attention)
 from paddlebox_tpu.ops.pallas_kernels.ssd_scan import ssd_scan
 from paddlebox_tpu.parallel import moe as moelib
 from paddlebox_tpu.parallel import tp as tplib
@@ -210,8 +214,9 @@ def _mamba(p, h, cfg: NemotronHConfig):
     b, s, _ = h.shape
     di, gn = cfg.mamba_inner, cfg.n_groups * cfg.ssm_state_size
     heads, k = cfg.mamba_num_heads, cfg.conv_kernel
-    z, xbc, dt = jnp.split(_dot(h, p["w_in"]), [di, di + cfg.conv_dim],
-                           axis=-1)
+    z, xbc, dt = jnp.split(
+        checkpoint_name(_dot(h, p["w_in"]), "mamba_in_proj"),
+        [di, di + cfg.conv_dim], axis=-1)
     # causal depthwise convolution: position t sees t-k+1 .. t
     padded = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0)))
     xbc = jax.nn.silu(sum(padded[:, i:i + s] * p["conv_w"][i]
@@ -258,12 +263,129 @@ def _experts(p, h, cfg: NemotronHConfig):
                               p["w2"], sizes)
     flags.note_kernel("nemotron_moe_dispatch", "sort_ragged_dot")
     routed, counts = moelib.dropless_dispatch(
-        _dot(x, p["w_down"]), idx, weights, cfg.experts_held, held_experts)
-    y = _dot(routed, p["w_up"]) + _dot(_relu2(_dot(x, p["ws1"])), p["ws2"])
+        checkpoint_name(_dot(x, p["w_down"]), "moe_latent"), idx, weights,
+        cfg.experts_held, held_experts)
+    shared = checkpoint_name(_dot(x, p["ws1"]), "moe_shared_hidden")
+    y = _dot(routed, p["w_up"]) + _dot(_relu2(shared), p["ws2"])
     return y.reshape(b, s, d), counts
 
 
 _MIXER = {"M": _mamba, "*": _attention, "E": _experts}
+
+
+# -- what a layer keeps for its backward pass --------------------------------
+
+# The share of the device's memory that parameters, their gradients, the
+# layers' inputs and the kept values may fill together; the rest is room
+# for the layer being differentiated, the head and the compiler's own
+# temporaries (a kept value costs the compiled programs up to twice its
+# size: XLA's schedule, read with tools/aot_check_dense.py, which holds
+# both programs the benchmark cell builds from this plan under the
+# device's memory).
+PLANNED_MEMORY_SHARE = 0.83
+# Where the backend reports no memory (the CPU; a device that is described
+# and not attached): the smallest HBM of a TPU this stack is run on.
+DEFAULT_DEVICE_BYTES = int(15.75 * 2 ** 30)
+
+
+class _Keepable(NamedTuple):
+    """Named values of one layer kind, a token of the layer's input."""
+    kind: str
+    names: Tuple[str, ...]
+    bytes: int          # to hold them, float32
+    ops: float          # matmul operations the second forward spends on them
+
+
+def _keepable(cfg: NemotronHConfig, seq: int):
+    """The candidates, dearest to recompute per byte first. A product of
+    the layer's input with a ``[hidden, width]`` matrix gives hidden / 2
+    operations a byte whatever the width, so those tie and stay in the
+    order written: attention, experts, Mamba."""
+    d, hd = cfg.hidden_size, cfg.head_dim
+    hq, hkv = cfg.num_attention_heads, cfg.num_key_value_heads
+    routed, k = cfg.n_routed_experts, cfg.num_experts_per_tok
+
+    def product(kind, names, width):
+        return _Keepable(kind, names, 4 * width, 2.0 * d * width)
+    found = [
+        product("*", FLASH_RESIDUAL_NAMES[:3], (hq + 2 * hkv) * hd),
+        # causal: half of the two products over every earlier position
+        _Keepable("*", FLASH_RESIDUAL_NAMES[3:], 4 * hq * (hd + 1),
+                  2.0 * seq * hq * hd),
+        # the router's product runs at Precision.HIGHEST, six passes; the
+        # top-k and the sort come on top and are not counted
+        _Keepable("E", moelib.ROUTING_RESIDUAL_NAMES, 4 * (routed + 3 * k),
+                  6 * 2.0 * d * routed),
+        product("E", ("moe_latent",), cfg.moe_latent_size),
+        product("E", ("moe_shared_hidden",),
+                cfg.moe_shared_expert_intermediate_size),
+        product("M", ("mamba_in_proj",),
+                cfg.mamba_inner + cfg.conv_dim + cfg.mamba_num_heads),
+    ]
+    return sorted(found, key=lambda c: -c.ops / c.bytes)
+
+
+class ResidualPlan(NamedTuple):
+    """What each layer keeps beside its input: one tuple of names a letter
+    of the pattern (empty: the layer is rematerialised whole)."""
+    names: Tuple[Tuple[str, ...], ...]
+    bytes: int
+
+    def attributes(self, pattern: str) -> Dict:
+        """The plan as the ``nemotron_h/build_step`` span reports it."""
+        kept = {kind: sum(bool(n) for n, letter in zip(self.names, pattern)
+                          if letter == kind)
+                for kind in dict.fromkeys(pattern)}
+        return {
+            "layers_kept": ",".join(f"{k}:{n}" for k, n in kept.items()),
+            "names_kept": ",".join(sorted({n for names in self.names
+                                           for n in names})),
+            "planned_residual_bytes": self.bytes,
+        }
+
+
+def plan_residuals(cfg: NemotronHConfig, tokens: int, seq: int,
+                   param_bytes: int, device_bytes: int) -> ResidualPlan:
+    """Chooses what each layer keeps, for ``tokens`` tokens a device in
+    sequences of ``seq``: parameters, their gradients and every layer's
+    input are planned first; the candidates of ``_keepable`` then take
+    what is left of ``PLANNED_MEMORY_SHARE`` of the device, dearest first
+    and, within one, last layer first (its backward pass comes first, so
+    it holds what it keeps the shortest)."""
+    layers = len(cfg.pattern)
+    room = (int(PLANNED_MEMORY_SHARE * device_bytes) - 2 * param_bytes
+            - layers * tokens * cfg.hidden_size * 4)
+    names = [()] * layers
+    planned = 0
+    for cand in _keepable(cfg, seq):
+        for i in reversed(range(layers)):
+            if (cfg.pattern[i] == cand.kind
+                    and planned + tokens * cand.bytes <= room):
+                names[i] += cand.names
+                planned += tokens * cand.bytes
+    return ResidualPlan(tuple(names), planned)
+
+
+def _device_bytes(mesh: Mesh) -> int:
+    try:
+        stats = mesh.devices.flat[0].memory_stats()
+    except jax.errors.JaxRuntimeError:      # described, not attached
+        stats = None
+    return int((stats or {}).get("bytes_limit", DEFAULT_DEVICE_BYTES))
+
+
+def _plan_for(cfg: NemotronHConfig, mesh: Mesh, params, tokens):
+    """The plan for one call's shapes: ``tokens`` ``[B, S]`` over the data
+    axes, ``params`` whole on every device but for the vocabulary's
+    split."""
+    shards = math.prod(int(mesh.shape[a]) for a in _data_axes(mesh))
+    whole = sum(leaf.size * leaf.dtype.itemsize
+                for leaf in jax.tree.leaves(params))
+    split = sum(params[n].size * params[n].dtype.itemsize
+                for n in ("embed", "head"))
+    return plan_residuals(
+        cfg, tokens.size // shards, tokens.shape[1],
+        whole - split + split // int(mesh.shape["mp"]), _device_bytes(mesh))
 
 
 def nemotron_h_loss_fn(cfg: NemotronHConfig, mesh: Mesh, specs: Dict):
@@ -281,19 +403,22 @@ def nemotron_h_loss_fn(cfg: NemotronHConfig, mesh: Mesh, specs: Dict):
                 "whole and the expert layer has no exchange yet")
     daxes = _data_axes(mesh)
 
-    def layer(letter):
+    def layer(letter, keep):
         def apply(lp, x):
             y, counts = _MIXER[letter](lp, _rms(x, lp["norm"],
                                                 cfg.norm_eps), cfg)
             return x + y, counts
-        return jax.checkpoint(apply)
+        return jax.checkpoint(
+            apply, policy=jax.checkpoint_policies.save_only_these_names(
+                *keep) if keep else None)
 
-    def body(params, tokens, targets):
+    def body(plan, params, tokens, targets):
         x = tplib.vocab_parallel_embedding(
             {"table": params["embed"]}, tokens, axis="mp")
         served = []
-        for letter, lp in zip(cfg.pattern, params["layers"]):
-            x, counts = layer(letter)(lp, x)
+        for letter, keep, lp in zip(cfg.pattern, plan.names,
+                                    params["layers"]):
+            x, counts = layer(letter, keep)(lp, x)
             if counts is not None:
                 served.append(counts)
         logits = _dot(_rms(x, params["norm_f"], cfg.norm_eps),
@@ -312,17 +437,28 @@ def nemotron_h_loss_fn(cfg: NemotronHConfig, mesh: Mesh, specs: Dict):
         }
         return total / count, aux
 
-    return jax.shard_map(body, mesh=mesh,
-                         in_specs=(specs, P(daxes, None), P(daxes, None)),
-                         out_specs=(P(), P()), check_vma=False)
+    def loss(params, tokens, targets):
+        plan = _plan_for(cfg, mesh, params, tokens)
+        return jax.shard_map(
+            functools.partial(body, plan), mesh=mesh,
+            in_specs=(specs, P(daxes, None), P(daxes, None)),
+            out_specs=(P(), P()), check_vma=False)(params, tokens, targets)
+    return loss
 
 
 def make_nemotron_h_train_step(cfg: NemotronHConfig, mesh: Mesh,
                                specs: Dict, optimizer):
     """Jitted ``(params, opt_state, tokens, targets) -> (params,
     opt_state, loss, aux)`` with donation; ``aux`` as
-    ``nemotron_h_loss_fn`` returns it."""
-    with trace.span("nemotron_h/build_step", layers=len(cfg.pattern)):
-        vg = jax.value_and_grad(nemotron_h_loss_fn(cfg, mesh, specs),
-                                has_aux=True)
-        return make_train_step(vg, optimizer, has_aux=True)
+    ``nemotron_h_loss_fn`` returns it. The ``nemotron_h/build_step`` span
+    covers the tracing of the loss and its gradient, once a compilation,
+    and says what the layers keep (``ResidualPlan.attributes``)."""
+    vg = jax.value_and_grad(nemotron_h_loss_fn(cfg, mesh, specs),
+                            has_aux=True)
+
+    def traced(params, tokens, targets):
+        plan = _plan_for(cfg, mesh, params, tokens)
+        with trace.span("nemotron_h/build_step", layers=len(cfg.pattern),
+                        **plan.attributes(cfg.pattern)):
+            return vg(params, tokens, targets)
+    return make_train_step(traced, optimizer, has_aux=True)

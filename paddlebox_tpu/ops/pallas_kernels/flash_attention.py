@@ -383,6 +383,17 @@ def _pad_seq(x3, block):
     return x3
 
 
+# What the backward kernels read of the forward pass, under the names a
+# ``jax.checkpoint`` save policy may keep them by (``_flash_fwd``). Without
+# such a policy a name is the identity and the program is what it was.
+# (Imported here and not at the top: the kernels' serialized bodies carry
+# their source lines into the compiled program, so nothing above this line
+# moves.)
+from jax.ad_checkpoint import checkpoint_name as _named  # noqa: E402
+
+RESIDUAL_NAMES = ("flash_q", "flash_k", "flash_v", "flash_out", "flash_lse")
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
 def _flash(q3, k3, v3, qoff, koff, scale, causal, block_q, block_k,
            interpret):
@@ -393,6 +404,7 @@ def _flash(q3, k3, v3, qoff, koff, scale, causal, block_q, block_k,
 
 def _flash_fwd(q3, k3, v3, qoff, koff, scale, causal, block_q, block_k,
                interpret):
+    q3, k3, v3 = map(_named, (q3, k3, v3), RESIDUAL_NAMES[:3])
     sq, sk = q3.shape[1], k3.shape[1]
     sk_real = jnp.full((1, 1), sk, jnp.int32)
     qp = _pad_seq(q3, block_q)
@@ -401,8 +413,7 @@ def _flash_fwd(q3, k3, v3, qoff, koff, scale, causal, block_q, block_k,
     out, lse = _fwd_pallas(qp, kp, vp, qoff, koff, sk_real, scale=scale,
                            causal=causal, block_q=block_q,
                            block_k=block_k, interpret=interpret)
-    out = out[:, :sq]
-    lse = lse[:, :sq]
+    out, lse = map(_named, (out[:, :sq], lse[:, :sq]), RESIDUAL_NAMES[3:])
     return out, (q3, k3, v3, out, lse, qoff, koff)
 
 

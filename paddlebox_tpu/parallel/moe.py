@@ -28,6 +28,7 @@ from typing import Callable, Dict, NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 
 def top2_gate(logits: jax.Array, *, capacity: int
@@ -122,6 +123,14 @@ def moe_layer(gate_w: jax.Array, expert_params: Dict[str, jax.Array],
 
 # -- top-k sigmoid routing, dropless sort/segment dispatch -------------------
 
+# What the two functions below compute that a rematerialised caller would
+# rather keep than compute again (a full-precision product, a top-k and a
+# sort, for a few values a token), under the names a ``jax.checkpoint``
+# save policy may keep them by; without one a name is the identity.
+ROUTING_RESIDUAL_NAMES = ("moe_logits", "moe_idx", "moe_weights",
+                          "moe_order", "moe_load")
+
+
 def topk_sigmoid_router(x: jax.Array, gate_w: jax.Array, bias: jax.Array,
                         *, k: int, scaling: float = 1.0
                         ) -> Tuple[jax.Array, jax.Array]:
@@ -135,13 +144,16 @@ def topk_sigmoid_router(x: jax.Array, gate_w: jax.Array, bias: jax.Array,
     experts must not turn on the MXU's bfloat16 pass.
     """
     f32 = jnp.float32
-    scores = jax.nn.sigmoid(jnp.dot(
+    # the product is named, not the scores: the sigmoid's own derivative
+    # reads what the sigmoid returned, and a name is a new value
+    scores = jax.nn.sigmoid(checkpoint_name(jnp.dot(
         x.astype(f32), gate_w.astype(f32), precision=lax.Precision.HIGHEST,
-        preferred_element_type=f32))
+        preferred_element_type=f32), "moe_logits"))
     _, idx = lax.top_k(scores + lax.stop_gradient(bias.astype(f32)), k)
+    idx = checkpoint_name(idx, "moe_idx")
     chosen = jnp.take_along_axis(scores, idx, axis=-1)
     chosen = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
-    return idx, chosen * scaling
+    return idx, checkpoint_name(chosen * scaling, "moe_weights")
 
 
 class DispatchCounts(NamedTuple):
@@ -174,9 +186,11 @@ def dropless_dispatch(u: jax.Array, idx: jax.Array, weights: jax.Array,
     flat_e, flat_w = idx.reshape(t * k), weights.reshape(t * k)
     local = jnp.where((flat_e >= first) & (flat_e < first + count),
                       flat_e - first, count)
-    order = jnp.argsort(local, stable=True).astype(jnp.int32)
-    load = jnp.sum(local[:, None] == jnp.arange(count)[None, :], axis=0,
-                   dtype=jnp.int32)
+    order = checkpoint_name(
+        jnp.argsort(local, stable=True).astype(jnp.int32), "moe_order")
+    load = checkpoint_name(
+        jnp.sum(local[:, None] == jnp.arange(count)[None, :], axis=0,
+                dtype=jnp.int32), "moe_load")
     ends = jnp.cumsum(load)
     starts, n_held = ends - load, ends[-1]
     acc = (jnp.zeros(u.shape, jnp.float32), jnp.zeros((), jnp.int32))

@@ -87,6 +87,23 @@ def test_dense_bench_steps_aot_compile_for_tpu():
 
 
 @pytest.mark.slow
+def test_hybrid_cell_programs_fit_the_v5e():
+    """Tier-1 has no chip: this is what guards the hybrid stack's memory
+    plan. The benchmark cell's timed step and its set-up's ``highest``
+    gradient function, compiled for the v5e at published widths and 8,192
+    positions, each under the tool's stated share of the chip, under a
+    plan that keeps the attention layer's and every expert layer's
+    values (how many Mamba in-projections fit beside them is the
+    plan's to say)."""
+    out = _run_tool("aot_check_dense.py", 1500, "--hybrid")
+    plan = out.split("hybrid plan: ", 1)[1].splitlines()[0]
+    assert "E:5,*:1" in plan, plan
+    assert "AOT hybrid step: " in out
+    assert "AOT hybrid setup gradient: " in out
+    assert "AOT hybrid step and setup gradient fit: OK" in out
+
+
+@pytest.mark.slow
 def test_scale_steps_aot_compile_for_tpu_256_chips():
     """The 8->256-chip scaling evidence one chip or one four-chip host
     can't give: the multislice CTR step (slice=4 x dp=64) and the

@@ -194,6 +194,145 @@ def test_step_trains_and_returns_the_routers_counts():
     assert losses[-1] < losses[0] - 0.1
 
 
+# -- what a layer keeps for its backward pass --------------------------------
+
+def _value_and_grad(cfg, monkeypatch, device_bytes=None, checkpoint=None):
+    """Loss, aux and gradients of the seeded stack with the plan made for
+    ``device_bytes`` (None: the default) and ``jax.checkpoint`` replaced
+    by ``checkpoint`` (None: as it is)."""
+    if device_bytes is not None:
+        monkeypatch.setattr(nh, "_device_bytes", lambda mesh: device_bytes)
+    if checkpoint is not None:
+        monkeypatch.setattr(jax, "checkpoint", checkpoint)
+    params, specs, tokens, targets = _seeded(cfg)
+    mesh = build_mesh(HybridTopology(dp=1), devices=jax.devices()[:1])
+    vg = jax.value_and_grad(nemotron_h_loss_fn(cfg, mesh, specs),
+                            has_aux=True)
+    return vg, (params, tokens, targets)
+
+
+# a layer's input at _seeded's sizes: no candidate fits beside it
+ONE_LAYER_INPUT = 2 * 40 * SMALL.hidden_size * 4
+
+
+@pytest.mark.parametrize("other", ["rematerialised_whole", "nothing"])
+@pytest.mark.parametrize("kernels", ["xla", "interpret"])
+def test_kept_values_leave_loss_and_every_gradient_as_they_were(
+        kernels, other, monkeypatch):
+    """The kept values are the ones the second forward would compute: the
+    loss and every gradient under the save policy are those of every
+    layer rematerialised whole, and of no rematerialisation at all, bit
+    for bit (exact equality, not 1 ulp: the same operations in the same
+    order on the same operands)."""
+    cfg = dataclasses.replace(SMALL, pattern="M*EM", kernels=kernels)
+    vg, args = _value_and_grad(cfg, monkeypatch)
+    (loss, aux), grads = jax.jit(vg)(*args)
+    if other == "nothing":
+        vg, _ = _value_and_grad(cfg, monkeypatch,
+                                checkpoint=lambda f, policy=None: f)
+    else:
+        vg, _ = _value_and_grad(cfg, monkeypatch,
+                                device_bytes=ONE_LAYER_INPUT)
+    (want, want_aux), want_grads = jax.jit(vg)(*args)
+    assert float(loss) == float(want)
+    np.testing.assert_array_equal(np.asarray(aux["load"]),
+                                  np.asarray(want_aux["load"]))
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    for (path, got), ref in zip(flat, jax.tree.leaves(want_grads)):
+        np.testing.assert_array_equal(
+            np.asarray(got), np.asarray(ref),
+            err_msg=jax.tree_util.keystr(path))
+
+
+def _count(jaxpr, found):
+    """Occurrences of each primitive, and of each jitted helper by its
+    name, in ``jaxpr`` and every jaxpr inside it."""
+    for eqn in jaxpr.eqns:
+        found[eqn.primitive.name] += 1
+        if eqn.primitive.name == "jit":
+            found[eqn.params["name"]] += 1
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _count(sub, found)
+    return found
+
+
+def test_the_policy_engages_and_a_full_device_falls_back(monkeypatch):
+    """In the whole gradient program of ``M*EM`` on the kernel path: one
+    flash forward and one top-k, sort and scores product where the values
+    are kept, two of each where the device has no room for them; two scan
+    forwards a Mamba layer either way (the in-projection is what is
+    kept)."""
+    import collections
+    cfg = dataclasses.replace(SMALL, pattern="M*EM")
+    counts = {}
+    for name, device_bytes in (("kept", None), ("full", ONE_LAYER_INPUT)):
+        vg, args = _value_and_grad(cfg, monkeypatch, device_bytes)
+        counts[name] = _count(jax.make_jaxpr(vg)(*args).jaxpr,
+                              collections.Counter())
+    kept, full = counts["kept"], counts["full"]
+    assert (kept["_flash_fwd_call"], full["_flash_fwd_call"]) == (1, 2)
+    assert kept["_flash_dq_call"] == full["_flash_dq_call"] == 1
+    assert (kept["top_k"], full["top_k"]) == (1, 2)
+    assert (kept["sort"], full["sort"]) == (1, 2)
+    assert kept["_ssd_fwd_call"] == full["_ssd_fwd_call"] == 4
+    assert kept["_ssd_bwd_call"] == full["_ssd_bwd_call"] == 2
+    # wq, wk, wv; the scores, the latent and the shared expert's first
+    # product; two in-projections
+    assert full["dot_general"] - kept["dot_general"] >= 3 + 3 + 2
+    assert full["ragged_dot_general"] == kept["ragged_dot_general"]
+
+
+@pytest.mark.parametrize("device_bytes,layers_kept", [
+    (None, "M:2,*:1,E:1"), (ONE_LAYER_INPUT, "M:0,*:0,E:0")])
+def test_build_step_span_says_what_the_layers_keep(device_bytes,
+                                                   layers_kept,
+                                                   monkeypatch):
+    from paddlebox_tpu.core import trace
+    cfg = dataclasses.replace(SMALL, pattern="M*EM", kernels="xla")
+    if device_bytes is not None:
+        monkeypatch.setattr(nh, "_device_bytes", lambda mesh: device_bytes)
+    params, specs, tokens, targets = _seeded(cfg)
+    mesh = build_mesh(HybridTopology(dp=1), devices=jax.devices()[:1])
+    opt = optax.adafactor(1e-2)
+    trace.GLOBAL.enable(ring_events=256)
+    try:
+        trace.GLOBAL.clear()
+        make_nemotron_h_train_step(cfg, mesh, specs, opt).lower(
+            params, opt.init(params), tokens, targets)
+        spans = [e for e in trace.GLOBAL.snapshot()
+                 if e["name"] == "nemotron_h/build_step"]
+    finally:
+        trace.GLOBAL.disable()
+        trace.GLOBAL.clear()
+    plan = nh.plan_residuals(
+        cfg, tokens.size, tokens.shape[1],
+        sum(leaf.nbytes for leaf in jax.tree.leaves(params)),
+        device_bytes or nh.DEFAULT_DEVICE_BYTES)
+    assert [e["args"] for e in spans] == [
+        dict(layers=4, **plan.attributes(cfg.pattern))]
+    said = spans[0]["args"]
+    assert said["layers_kept"] == layers_kept
+    assert (said["planned_residual_bytes"] == 0) == (device_bytes is not None)
+    assert ("moe_logits" in said["names_kept"]) == (device_bytes is None)
+
+
+def test_candidates_rank_by_operations_a_byte():
+    """The router's six-pass product first; the flash forward above the
+    plain products at 8,192 positions (S / 2 operations a byte against
+    hidden / 2) and below them at 1,024; the plain products tie and stay
+    in the order attention, experts, Mamba."""
+    cfg = NemotronHConfig()
+    first = [c.names[0] for c in nh._keepable(cfg, 8192)]
+    assert first == ["moe_logits", "flash_out", "flash_q", "moe_latent",
+                     "moe_shared_hidden", "mamba_in_proj"]
+    first = [c.names[0] for c in nh._keepable(cfg, 1024)]
+    assert first == ["moe_logits", "flash_q", "moe_latent",
+                     "moe_shared_hidden", "mamba_in_proj", "flash_out"]
+
+
 def test_gpt_and_nemotron_steps_share_one_wrapper():
     from paddlebox_tpu.models import gpt, train_step
     assert gpt.make_train_step is train_step.make_train_step

@@ -1,12 +1,23 @@
-"""AOT-compile the dense benchmark train steps (resnet50 bf16, BERT-base)
-for TPU — no TPU needed (compile-only PJRT topology).
+"""AOT-compile the dense benchmark train steps (resnet50 bf16, BERT-base,
+the hybrid cell's two programs with their memory) for TPU — no TPU needed
+(compile-only PJRT topology).
 
 These two steps had never run on hardware before round 3 (both
 carried calling-convention bugs), so their TPU-compile surface — notably
 the bf16 conv forward/transpose path resnet now uses — is exactly the
 kind of thing that would otherwise only fail inside the recorded run:
 
-    python tools/aot_check_dense.py
+    python tools/aot_check_dense.py [--hybrid]
+
+``--hybrid`` checks the hybrid cell in their place. The hybrid stack
+(``models/nemotron_h.py``) plans what its layers keep for the backward
+pass from the device's memory. ``check_hybrid`` compiles
+what ``benchmarks/runners/hybrid_train.py`` builds from that plan at the
+cell's sizes (``benchmarks/configs/nemotron3_super_120b.json``, 8,192
+positions): the timed step, and the same loss's gradient at ``highest``
+with every gradient an output, which the set-up runs first. Either above
+``HYBRID_MEMORY_SHARE`` of the v5e's memory fails the check: on the chip
+that is an out-of-memory in set-up, a failed cell.
 """
 
 import os
@@ -85,11 +96,90 @@ def check_bert(sh) -> None:
     print("AOT bert-base train step: OK")
 
 
+# What one program may take of the chip: arguments + outputs that alias
+# none of them + temporaries. The rest is what the runner holds beside a
+# program (token batches, the optimizer state while the set-up's gradient
+# runs) and the allocator's fragmentation.
+HYBRID_MEMORY_SHARE = 0.93
+
+
+def program_bytes(compiled) -> dict:
+    m = compiled.memory_analysis()
+    return {"arguments": m.argument_size_in_bytes,
+            "outputs": m.output_size_in_bytes - m.alias_size_in_bytes,
+            "temporaries": m.temp_size_in_bytes}
+
+
+def check_hybrid(device) -> None:
+    import json
+
+    from benchmarks.runners.hybrid_train import program_config
+    from paddlebox_tpu.core import flags
+    from paddlebox_tpu.models import nemotron_h as nh
+    from paddlebox_tpu.parallel import HybridTopology, build_mesh
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "nemotron3_super_120b.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(root, "benchmarks", "traffic",
+                           "train_s8192.json")) as f:
+        seq = int(json.load(f)["sequence_length"])
+    cfg = program_config(config)
+    # the device is described, the backend here is the CPU: say what the
+    # chip's process would find
+    flags.pallas_kernels_enabled = lambda: True
+    mesh = build_mesh(HybridTopology(dp=1), devices=[device])
+    rep = NamedSharding(mesh, P())
+    specs = {}
+
+    def make(key):
+        params, s = nh.init_nemotron_h(key, cfg)
+        specs.update(s)
+        return params
+    opt = optax.adafactor(config["learning_rate"])
+    params = jax.eval_shape(make, jax.random.PRNGKey(0))
+    opt_state = jax.eval_shape(opt.init, params)
+
+    def placed(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=rep), tree)
+    params, opt_state = placed(params), placed(opt_state)
+    tok = jax.ShapeDtypeStruct((int(config["sequences_per_chip"]), seq),
+                               jnp.int32, sharding=NamedSharding(
+                                   mesh, P("dp")))
+    plan = nh._plan_for(cfg, mesh, params, tok)
+    print("hybrid plan:", json.dumps(plan.attributes(cfg.pattern)),
+          flush=True)
+    step = nh.make_nemotron_h_train_step(cfg, mesh, specs, opt).lower(
+        params, opt_state, tok, tok).compile()
+    with jax.default_matmul_precision("highest"):
+        grads = jax.jit(jax.value_and_grad(
+            nh.nemotron_h_loss_fn(cfg, mesh, specs), has_aux=True)).lower(
+            params, tok, tok).compile()
+    # a described device reports no memory: the plan was made for the
+    # stack's stated default, which is the v5e's
+    limit = int(HYBRID_MEMORY_SHARE * nh.DEFAULT_DEVICE_BYTES)
+    for name, compiled in (("step", step), ("setup gradient", grads)):
+        parts = program_bytes(compiled)
+        total = sum(parts.values())
+        print(f"AOT hybrid {name}: {json.dumps(parts)} total {total} "
+              f"of {limit} allowed", flush=True)
+        if total > limit:
+            raise SystemExit(
+                f"hybrid {name} program needs {total} bytes, over "
+                f"{HYBRID_MEMORY_SHARE:.0%} of the v5e's "
+                f"{nh.DEFAULT_DEVICE_BYTES}")
+    print("AOT hybrid step and setup gradient fit: OK")
+
+
 def main() -> None:
     topo = tpu_topology("v5e:2x2x1")
     if topo is None:
         return
     sh = NamedSharding(Mesh([topo.devices[0]], ("d",)), P())
+    if "--hybrid" in sys.argv:      # two minutes and a half of its own
+        check_hybrid(topo.devices[0])
+        return
     check_bert(sh)
     check_resnet(sh)
     print("DENSE BENCH TPU AOT COMPILE: OK")
