@@ -223,6 +223,21 @@ def pallas_kernels_enabled() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def kernel_mode(kernels: str) -> Dict:
+    """How a dense stack's ``kernels`` setting resolves: "auto" the Pallas
+    kernels on a TPU and their XLA references elsewhere, "interpret" the
+    kernels through the Pallas interpreter (tests), "xla" the references.
+    ``name`` is what ``note_kernel`` records."""
+    if kernels not in ("auto", "interpret", "xla"):
+        raise ValueError(f"unknown kernels mode {kernels!r}; choose "
+                         "from 'auto', 'interpret', 'xla'")
+    interpret = kernels == "interpret"
+    use = interpret or (kernels == "auto" and pallas_kernels_enabled())
+    return {"use_pallas": use, "interpret": interpret,
+            "name": "interpret" if interpret else "pallas" if use
+            else "xla"}
+
+
 # What each kernel dispatch site actually selected, recorded while its
 # caller traces: 'auto' resolves from the backend, and some sites give
 # way to XLA on their own (a fused record wider than one lane tile), so

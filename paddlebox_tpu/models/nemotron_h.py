@@ -23,7 +23,7 @@ the data axes; every other weight is whole on every device. The layers
 differ, so they are a Python list (no ``lax.scan`` over equal blocks), and
 each is rematerialised (``jax.checkpoint``): a layer keeps its input for
 its backward pass and, of what its forward computes, the values
-``plan_residuals`` finds room for in the device's memory.
+``residual_plan.plan_residuals`` finds room for in the device's memory.
 """
 
 from __future__ import annotations
@@ -31,7 +31,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Dict, NamedTuple, Tuple
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -40,7 +40,9 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
 
 from paddlebox_tpu.core import flags, trace
+from paddlebox_tpu.models import residual_plan
 from paddlebox_tpu.models.gpt import _data_axes
+from paddlebox_tpu.models.residual_plan import Keepable, product, ranked
 from paddlebox_tpu.models.train_step import make_train_step
 from paddlebox_tpu.ops.pallas_kernels.flash_attention import (
     RESIDUAL_NAMES as FLASH_RESIDUAL_NAMES, flash_attention)
@@ -93,15 +95,7 @@ class NemotronHConfig:
 
 
 def _kernel_mode(cfg: NemotronHConfig) -> Dict:
-    if cfg.kernels not in ("auto", "interpret", "xla"):
-        raise ValueError(f"unknown kernels mode {cfg.kernels!r}; choose "
-                         "from 'auto', 'interpret', 'xla'")
-    interpret = cfg.kernels == "interpret"
-    use = interpret or (cfg.kernels == "auto"
-                        and flags.pallas_kernels_enabled())
-    return {"use_pallas": use, "interpret": interpret,
-            "name": "interpret" if interpret else "pallas" if use
-            else "xla"}
+    return flags.kernel_mode(cfg.kernels)
 
 
 # -- parameters --------------------------------------------------------------
@@ -275,117 +269,37 @@ _MIXER = {"M": _mamba, "*": _attention, "E": _experts}
 
 # -- what a layer keeps for its backward pass --------------------------------
 
-# The share of the device's memory that parameters, their gradients, the
-# layers' inputs and the kept values may fill together; the rest is room
-# for the layer being differentiated, the head and the compiler's own
-# temporaries (a kept value costs the compiled programs up to twice its
-# size: XLA's schedule, read with tools/aot_check_dense.py, which holds
-# both programs the benchmark cell builds from this plan under the
-# device's memory).
-PLANNED_MEMORY_SHARE = 0.83
-# Where the backend reports no memory (the CPU; a device that is described
-# and not attached): the smallest HBM of a TPU this stack is run on.
-DEFAULT_DEVICE_BYTES = int(15.75 * 2 ** 30)
-
-
-class _Keepable(NamedTuple):
-    """Named values of one layer kind, a token of the layer's input."""
-    kind: str
-    names: Tuple[str, ...]
-    bytes: int          # to hold them, float32
-    ops: float          # matmul operations the second forward spends on them
-
-
 def _keepable(cfg: NemotronHConfig, seq: int):
-    """The candidates, dearest to recompute per byte first. A product of
-    the layer's input with a ``[hidden, width]`` matrix gives hidden / 2
-    operations a byte whatever the width, so those tie and stay in the
-    order written: attention, experts, Mamba."""
+    """The candidates of ``residual_plan``, dearest to recompute per byte
+    first. A product of the layer's input with a ``[hidden, width]``
+    matrix gives hidden / 2 operations a byte whatever the width, so those
+    tie and stay in the order written: attention, experts, Mamba."""
     d, hd = cfg.hidden_size, cfg.head_dim
     hq, hkv = cfg.num_attention_heads, cfg.num_key_value_heads
     routed, k = cfg.n_routed_experts, cfg.num_experts_per_tok
-
-    def product(kind, names, width):
-        return _Keepable(kind, names, 4 * width, 2.0 * d * width)
-    found = [
-        product("*", FLASH_RESIDUAL_NAMES[:3], (hq + 2 * hkv) * hd),
+    return ranked([
+        product("*", FLASH_RESIDUAL_NAMES[:3], d, (hq + 2 * hkv) * hd),
         # causal: half of the two products over every earlier position
-        _Keepable("*", FLASH_RESIDUAL_NAMES[3:], 4 * hq * (hd + 1),
-                  2.0 * seq * hq * hd),
+        Keepable("*", FLASH_RESIDUAL_NAMES[3:], 4 * hq * (hd + 1),
+                 2.0 * seq * hq * hd),
         # the router's product runs at Precision.HIGHEST, six passes; the
         # top-k and the sort come on top and are not counted
-        _Keepable("E", moelib.ROUTING_RESIDUAL_NAMES, 4 * (routed + 3 * k),
-                  6 * 2.0 * d * routed),
-        product("E", ("moe_latent",), cfg.moe_latent_size),
-        product("E", ("moe_shared_hidden",),
+        Keepable("E", moelib.ROUTING_RESIDUAL_NAMES, 4 * (routed + 3 * k),
+                 6 * 2.0 * d * routed),
+        product("E", ("moe_latent",), d, cfg.moe_latent_size),
+        product("E", ("moe_shared_hidden",), d,
                 cfg.moe_shared_expert_intermediate_size),
-        product("M", ("mamba_in_proj",),
+        product("M", ("mamba_in_proj",), d,
                 cfg.mamba_inner + cfg.conv_dim + cfg.mamba_num_heads),
-    ]
-    return sorted(found, key=lambda c: -c.ops / c.bytes)
-
-
-class ResidualPlan(NamedTuple):
-    """What each layer keeps beside its input: one tuple of names a letter
-    of the pattern (empty: the layer is rematerialised whole)."""
-    names: Tuple[Tuple[str, ...], ...]
-    bytes: int
-
-    def attributes(self, pattern: str) -> Dict:
-        """The plan as the ``nemotron_h/build_step`` span reports it."""
-        kept = {kind: sum(bool(n) for n, letter in zip(self.names, pattern)
-                          if letter == kind)
-                for kind in dict.fromkeys(pattern)}
-        return {
-            "layers_kept": ",".join(f"{k}:{n}" for k, n in kept.items()),
-            "names_kept": ",".join(sorted({n for names in self.names
-                                           for n in names})),
-            "planned_residual_bytes": self.bytes,
-        }
-
-
-def plan_residuals(cfg: NemotronHConfig, tokens: int, seq: int,
-                   param_bytes: int, device_bytes: int) -> ResidualPlan:
-    """Chooses what each layer keeps, for ``tokens`` tokens a device in
-    sequences of ``seq``: parameters, their gradients and every layer's
-    input are planned first; the candidates of ``_keepable`` then take
-    what is left of ``PLANNED_MEMORY_SHARE`` of the device, dearest first
-    and, within one, last layer first (its backward pass comes first, so
-    it holds what it keeps the shortest)."""
-    layers = len(cfg.pattern)
-    room = (int(PLANNED_MEMORY_SHARE * device_bytes) - 2 * param_bytes
-            - layers * tokens * cfg.hidden_size * 4)
-    names = [()] * layers
-    planned = 0
-    for cand in _keepable(cfg, seq):
-        for i in reversed(range(layers)):
-            if (cfg.pattern[i] == cand.kind
-                    and planned + tokens * cand.bytes <= room):
-                names[i] += cand.names
-                planned += tokens * cand.bytes
-    return ResidualPlan(tuple(names), planned)
-
-
-def _device_bytes(mesh: Mesh) -> int:
-    try:
-        stats = mesh.devices.flat[0].memory_stats()
-    except jax.errors.JaxRuntimeError:      # described, not attached
-        stats = None
-    return int((stats or {}).get("bytes_limit", DEFAULT_DEVICE_BYTES))
+    ])
 
 
 def _plan_for(cfg: NemotronHConfig, mesh: Mesh, params, tokens):
-    """The plan for one call's shapes: ``tokens`` ``[B, S]`` over the data
-    axes, ``params`` whole on every device but for the vocabulary's
-    split."""
-    shards = math.prod(int(mesh.shape[a]) for a in _data_axes(mesh))
-    whole = sum(leaf.size * leaf.dtype.itemsize
-                for leaf in jax.tree.leaves(params))
-    split = sum(params[n].size * params[n].dtype.itemsize
-                for n in ("embed", "head"))
-    return plan_residuals(
-        cfg, tokens.size // shards, tokens.shape[1],
-        whole - split + split // int(mesh.shape["mp"]), _device_bytes(mesh))
+    """What each layer keeps for one call's shapes (``residual_plan``):
+    one application a letter of the pattern."""
+    return residual_plan._plan_for(
+        mesh, params, tokens, cfg.pattern,
+        _keepable(cfg, tokens.shape[1]), cfg.hidden_size)
 
 
 def nemotron_h_loss_fn(cfg: NemotronHConfig, mesh: Mesh, specs: Dict):

@@ -104,6 +104,21 @@ def test_hybrid_cell_programs_fit_the_v5e():
 
 
 @pytest.mark.slow
+def test_looped_cell_programs_fit_the_v5e():
+    """The looped cell's timed step and its set-up's ``highest`` gradient
+    function, compiled for the v5e at published widths, 12 layers x 4
+    passes and 4,096 positions, each under the tool's stated share of the
+    chip, under a plan that keeps the flash kernel's output in every pass
+    (what else fits is the plan's to say)."""
+    out = _run_tool("aot_check_dense.py", 1500, "--looped")
+    plan = out.split("looped plan: ", 1)[1].splitlines()[0]
+    assert "flash_out:12/12/12/12" in plan, plan
+    assert "AOT looped step: " in out
+    assert "AOT looped setup gradient: " in out
+    assert "AOT looped step and setup gradient fit: OK" in out
+
+
+@pytest.mark.slow
 def test_scale_steps_aot_compile_for_tpu_256_chips():
     """The 8->256-chip scaling evidence one chip or one four-chip host
     can't give: the multislice CTR step (slice=4 x dp=64) and the

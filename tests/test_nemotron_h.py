@@ -14,6 +14,7 @@ import optax
 import pytest
 
 from paddlebox_tpu.models import nemotron_h as nh
+from paddlebox_tpu.models import residual_plan
 from paddlebox_tpu.models.nemotron_h import (NemotronHConfig,
                                              init_nemotron_h,
                                              make_nemotron_h_train_step,
@@ -201,7 +202,8 @@ def _value_and_grad(cfg, monkeypatch, device_bytes=None, checkpoint=None):
     ``device_bytes`` (None: the default) and ``jax.checkpoint`` replaced
     by ``checkpoint`` (None: as it is)."""
     if device_bytes is not None:
-        monkeypatch.setattr(nh, "_device_bytes", lambda mesh: device_bytes)
+        monkeypatch.setattr(residual_plan, "_device_bytes",
+                            lambda mesh: device_bytes)
     if checkpoint is not None:
         monkeypatch.setattr(jax, "checkpoint", checkpoint)
     params, specs, tokens, targets = _seeded(cfg)
@@ -293,7 +295,8 @@ def test_build_step_span_says_what_the_layers_keep(device_bytes,
     from paddlebox_tpu.core import trace
     cfg = dataclasses.replace(SMALL, pattern="M*EM", kernels="xla")
     if device_bytes is not None:
-        monkeypatch.setattr(nh, "_device_bytes", lambda mesh: device_bytes)
+        monkeypatch.setattr(residual_plan, "_device_bytes",
+                            lambda mesh: device_bytes)
     params, specs, tokens, targets = _seeded(cfg)
     mesh = build_mesh(HybridTopology(dp=1), devices=jax.devices()[:1])
     opt = optax.adafactor(1e-2)
@@ -307,10 +310,11 @@ def test_build_step_span_says_what_the_layers_keep(device_bytes,
     finally:
         trace.GLOBAL.disable()
         trace.GLOBAL.clear()
-    plan = nh.plan_residuals(
-        cfg, tokens.size, tokens.shape[1],
+    plan = residual_plan.plan_residuals(
+        cfg.pattern, nh._keepable(cfg, tokens.shape[1]), tokens.size,
+        cfg.hidden_size,
         sum(leaf.nbytes for leaf in jax.tree.leaves(params)),
-        device_bytes or nh.DEFAULT_DEVICE_BYTES)
+        device_bytes or residual_plan.DEFAULT_DEVICE_BYTES)
     assert [e["args"] for e in spans] == [
         dict(layers=4, **plan.attributes(cfg.pattern))]
     said = spans[0]["args"]

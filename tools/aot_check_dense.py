@@ -1,5 +1,5 @@
 """AOT-compile the dense benchmark train steps (resnet50 bf16, BERT-base,
-the hybrid cell's two programs with their memory) for TPU — no TPU needed
+the hybrid and the looped cell's two programs each with their memory) for TPU — no TPU needed
 (compile-only PJRT topology).
 
 These two steps had never run on hardware before round 3 (both
@@ -7,9 +7,12 @@ carried calling-convention bugs), so their TPU-compile surface — notably
 the bf16 conv forward/transpose path resnet now uses — is exactly the
 kind of thing that would otherwise only fail inside the recorded run:
 
-    python tools/aot_check_dense.py [--hybrid]
+    python tools/aot_check_dense.py [--hybrid | --looped]
 
-``--hybrid`` checks the hybrid cell in their place. The hybrid stack
+``--hybrid`` checks the hybrid cell in their place, ``--looped`` the looped
+cell (``models/looped.py`` at ``benchmarks/configs/ouro_2_6b.json``, 4,096
+positions: the timed step and the set-up's ``highest`` gradient function
+as ``benchmarks/runners/looped_train.py`` builds it). The hybrid stack
 (``models/nemotron_h.py``) plans what its layers keep for the backward
 pass from the device's memory. ``check_hybrid`` compiles
 what ``benchmarks/runners/hybrid_train.py`` builds from that plan at the
@@ -110,21 +113,29 @@ def program_bytes(compiled) -> dict:
             "temporaries": m.temp_size_in_bytes}
 
 
-def check_hybrid(device) -> None:
+def _cell_files(config_name: str, traffic_name: str):
     import json
-
-    from benchmarks.runners.hybrid_train import program_config
-    from paddlebox_tpu.core import flags
-    from paddlebox_tpu.models import nemotron_h as nh
-    from paddlebox_tpu.parallel import HybridTopology, build_mesh
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmarks", "configs",
-                           "nemotron3_super_120b.json")) as f:
+                           config_name + ".json")) as f:
         config = json.load(f)
     with open(os.path.join(root, "benchmarks", "traffic",
-                           "train_s8192.json")) as f:
-        seq = int(json.load(f)["sequence_length"])
-    cfg = program_config(config)
+                           traffic_name + ".json")) as f:
+        return config, int(json.load(f)["sequence_length"])
+
+
+def _check_programs(name: str, device, config, seq, init, said, programs):
+    """Compiles for the described ``device`` what a dense cell's runner
+    builds at the cell's sizes and fails if any program is over
+    ``HYBRID_MEMORY_SHARE`` of the chip. ``init(key) -> (params, specs)``;
+    ``said(mesh, params, tokens)`` the plan as its span reports it;
+    ``programs(mesh, specs, opt)`` pairs of a name and a function ``(params,
+    opt_state, tokens) -> lowered``."""
+    import json
+
+    from paddlebox_tpu.core import flags
+    from paddlebox_tpu.models import residual_plan
+    from paddlebox_tpu.parallel import HybridTopology, build_mesh
     # the device is described, the backend here is the CPU: say what the
     # chip's process would find
     flags.pallas_kernels_enabled = lambda: True
@@ -133,7 +144,7 @@ def check_hybrid(device) -> None:
     specs = {}
 
     def make(key):
-        params, s = nh.init_nemotron_h(key, cfg)
+        params, s = init(key)
         specs.update(s)
         return params
     opt = optax.adafactor(config["learning_rate"])
@@ -147,29 +158,69 @@ def check_hybrid(device) -> None:
     tok = jax.ShapeDtypeStruct((int(config["sequences_per_chip"]), seq),
                                jnp.int32, sharding=NamedSharding(
                                    mesh, P("dp")))
-    plan = nh._plan_for(cfg, mesh, params, tok)
-    print("hybrid plan:", json.dumps(plan.attributes(cfg.pattern)),
-          flush=True)
-    step = nh.make_nemotron_h_train_step(cfg, mesh, specs, opt).lower(
-        params, opt_state, tok, tok).compile()
-    with jax.default_matmul_precision("highest"):
-        grads = jax.jit(jax.value_and_grad(
-            nh.nemotron_h_loss_fn(cfg, mesh, specs), has_aux=True)).lower(
-            params, tok, tok).compile()
+    print(f"{name} plan:", json.dumps(said(mesh, params, tok)), flush=True)
     # a described device reports no memory: the plan was made for the
-    # stack's stated default, which is the v5e's
-    limit = int(HYBRID_MEMORY_SHARE * nh.DEFAULT_DEVICE_BYTES)
-    for name, compiled in (("step", step), ("setup gradient", grads)):
-        parts = program_bytes(compiled)
+    # stacks' stated default, which is the v5e's
+    limit = int(HYBRID_MEMORY_SHARE * residual_plan.DEFAULT_DEVICE_BYTES)
+    for program, lower in programs(mesh, specs, opt):
+        parts = program_bytes(lower(params, opt_state, tok).compile())
         total = sum(parts.values())
-        print(f"AOT hybrid {name}: {json.dumps(parts)} total {total} "
+        print(f"AOT {name} {program}: {json.dumps(parts)} total {total} "
               f"of {limit} allowed", flush=True)
         if total > limit:
             raise SystemExit(
-                f"hybrid {name} program needs {total} bytes, over "
+                f"{name} {program} program needs {total} bytes, over "
                 f"{HYBRID_MEMORY_SHARE:.0%} of the v5e's "
-                f"{nh.DEFAULT_DEVICE_BYTES}")
-    print("AOT hybrid step and setup gradient fit: OK")
+                f"{residual_plan.DEFAULT_DEVICE_BYTES}")
+    print(f"AOT {name} step and setup gradient fit: OK")
+
+
+def check_hybrid(device) -> None:
+    from benchmarks.runners.hybrid_train import program_config
+    from paddlebox_tpu.models import nemotron_h as nh
+    config, seq = _cell_files("nemotron3_super_120b", "train_s8192")
+    cfg = program_config(config)
+
+    def programs(mesh, specs, opt):
+        def step(params, opt_state, tok):
+            return nh.make_nemotron_h_train_step(cfg, mesh, specs, opt).lower(
+                params, opt_state, tok, tok)
+
+        def grads(params, opt_state, tok):
+            with jax.default_matmul_precision("highest"):
+                return jax.jit(jax.value_and_grad(
+                    nh.nemotron_h_loss_fn(cfg, mesh, specs),
+                    has_aux=True)).lower(params, tok, tok)
+        return ("step", step), ("setup gradient", grads)
+    _check_programs(
+        "hybrid", device, config, seq,
+        lambda key: nh.init_nemotron_h(key, cfg),
+        lambda mesh, params, tok: nh._plan_for(
+            cfg, mesh, params, tok).attributes(cfg.pattern), programs)
+
+
+def check_looped(device) -> None:
+    from benchmarks.runners import looped_train as runner
+    from paddlebox_tpu.models import looped
+    config, seq = _cell_files("ouro_2_6b", "train_s4096")
+    cfg = runner.program_config(config)
+
+    def programs(mesh, specs, opt):
+        def step(params, opt_state, tok):
+            return looped.make_looped_train_step(cfg, mesh, specs, opt).lower(
+                params, opt_state, tok, tok)
+
+        def grads(params, opt_state, tok):
+            with jax.default_matmul_precision("highest"):
+                return runner.program_reading(
+                    cfg, mesh, specs, runner.checked_leaves(cfg.pieces)
+                ).lower(params, tok, tok)
+        return ("step", step), ("setup gradient", grads)
+    _check_programs(
+        "looped", device, config, seq,
+        lambda key: looped.init_looped(key, cfg),
+        lambda mesh, params, tok: looped.plan_attributes(
+            cfg, looped._plan_for(cfg, mesh, params, tok)), programs)
 
 
 def main() -> None:
@@ -179,6 +230,9 @@ def main() -> None:
     sh = NamedSharding(Mesh([topo.devices[0]], ("d",)), P())
     if "--hybrid" in sys.argv:      # two minutes and a half of its own
         check_hybrid(topo.devices[0])
+        return
+    if "--looped" in sys.argv:      # two minutes of its own
+        check_looped(topo.devices[0])
         return
     check_bert(sh)
     check_resnet(sh)
