@@ -32,13 +32,14 @@ def _run_tool(name, timeout, *args):
 
 @pytest.mark.slow
 def test_pallas_kernels_aot_compile_for_tpu():
-    """sorted_scatter, sorted_gather, flash_attention fwd+bwd (equal and
-    grouped heads), ssd_scan fwd+bwd and seqpool_cvm at the shapes the
-    benchmarks use."""
+    """sorted_scatter, sorted_gather, flash_attention fwd+bwd (the three
+    dense cells' shapes: equal heads of 64 and of 128, grouped heads),
+    ssd_scan fwd+bwd and seqpool_cvm at the shapes the benchmarks use."""
     out = _run_tool("aot_check_kernels.py", 900)
     assert out.count("AOT sorted_scatter") == 3
     assert out.count("AOT sorted_gather") == 3
-    assert "AOT flash_attention fwd+bwd" in out
+    assert "AOT flash_attention fwd+bwd [4, 1024, 16, 64]" in out
+    assert "AOT flash_attention fwd+bwd [1, 4096, 16/16, 128]" in out
     assert "AOT flash_attention grouped fwd+bwd" in out
     assert out.count("AOT ssd_scan fwd+bwd") == 2
     assert "AOT seqpool_cvm" in out

@@ -97,6 +97,15 @@ def main() -> None:
         argnums=(0, 1, 2))).lower(q, kv, kv).compile()
     print("AOT flash_attention grouped fwd+bwd [1, 8192, 32/2, 128]: OK",
           flush=True)
+    # The looped stack's attention at published widths and the benchmark
+    # cell's 4,096 positions (benchmarks/configs/ouro_2_6b.json): 16 query
+    # heads over 16 key/value heads of 128.
+    q = sds((1, 4096, 16, 128), jnp.float32)
+    jax.jit(jax.grad(
+        lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                        use_pallas=True).sum(),
+        argnums=(0, 1, 2))).lower(q, q, q).compile()
+    print("AOT flash_attention fwd+bwd [1, 4096, 16/16, 128]: OK", flush=True)
     scan_args = (sds((1, 8192, 128, 64), jnp.float32),
                  sds((1, 8192, 128), jnp.float32), sds((128,), jnp.float32),
                  sds((1, 8192, 8, 128), jnp.float32),
