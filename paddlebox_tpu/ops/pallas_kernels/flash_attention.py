@@ -42,6 +42,19 @@ transposes a tile), and the row statistics, stored lane-major as
 masking, so the same kernel serves ring attention's per-step blocks where
 each device holds a rotated K/V shard.
 
+**A mask rule beside causal.** ``flash_attention(mask=...)`` takes one
+static rule, ``BlockDiffusionMask(seq, block)``: the scores of ``2 *
+seq`` rows, a clean copy of a sequence followed by a noisy copy, where a
+clean row reads the clean rows of its own and earlier blocks, a noisy row
+the clean rows of strictly earlier blocks and the noisy rows of its own
+block, and no clean row reads a noisy one. The rule is resolved when the
+program is traced: tiles divide ``seq``, so a tile lies in one of the
+four quadrants, ``_tile_kind`` answers live / interior from the block
+numbers of the tile's corners, the schedule lists the live tiles (80 of
+256 a head at 4,096 x 4, 512 x 512 tiles) and an edge tile builds the
+rule from two shifts, a subtraction and two compares. Without a rule the
+three kernels are the programs they were.
+
 Grouped heads (H query heads over H_kv < H key/value heads, query head h
 reading key/value head ``h // (H / H_kv)``): K and V stay ``[B * H_kv, S,
 D]`` in HBM and the block index maps send each query head to its group's
@@ -52,6 +65,7 @@ its VMEM accumulators.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Optional, Tuple
 
@@ -76,10 +90,92 @@ def _pick_block(s: int, preferred: int) -> int:
     return max(8, -(-s // 8) * 8)
 
 
-def _tile_kind(qoff, koff, kreal, qi, ki, *, causal, block_q, block_k):
+@dataclasses.dataclass(frozen=True)
+class BlockDiffusionMask:
+    """The mask of block-diffusion training over ``2 * seq`` rows: rows
+    ``0 .. seq - 1`` a clean sequence, rows ``seq .. 2 seq - 1`` its noisy
+    copy, both cut into blocks of ``block`` positions. With ``noisy(r) =
+    r >= seq`` and ``b(r) = (r mod seq) // block``, row q reads row k iff
+
+        (not noisy(k) and b(k) < b(q))
+        or (noisy(k) == noisy(q) and b(k) == b(q))
+
+    The kernels' tiles must divide ``seq`` (``flash_attention`` picks them
+    so, or refuses): a tile then lies in one quadrant."""
+    seq: int
+    block: int
+
+    def __post_init__(self):
+        if self.seq < 1 or self.block < 1 or self.seq % self.block:
+            raise ValueError(f"BlockDiffusionMask(seq={self.seq}, block="
+                             f"{self.block}): blocks must divide the "
+                             "sequence")
+
+    def everywhere(self):
+        """The rule as written, over all ``[2 seq, 2 seq]`` entries (the
+        XLA path and the tests; the kernels never build it)."""
+        row = jnp.arange(2 * self.seq)
+        noisy, b = row >= self.seq, (row % self.seq) // self.block
+        return ((~noisy[None, :] & (b[None, :] < b[:, None]))
+                | ((noisy[None, :] == noisy[:, None])
+                   & (b[None, :] == b[:, None])))
+
+    def block_of(self, local):
+        """The block of a position ``local`` in [0, seq): a shift where
+        the block length is a power of two (a vector has no cheap
+        integer division)."""
+        if self.block & (self.block - 1) == 0:
+            return local >> (self.block.bit_length() - 1)
+        return local // self.block
+
+    def quadrant(self, first_q, first_k):
+        """For a tile that starts at row ``first_q``, key row ``first_k``:
+        (its first position within the query rows' copy, within the key
+        rows' copy, ``below``, ``above``) such that an entry is allowed
+        iff ``below < b(k) - b(q) < above`` (no clean row reads a noisy
+        one: ``above`` lies under every difference there). Python ints or
+        traced scalars."""
+        q_noisy, k_noisy = first_q >= self.seq, first_k >= self.seq
+        far = -2 * self.seq
+        if isinstance(q_noisy, bool) and isinstance(k_noisy, bool):
+            if k_noisy:             # its own block only
+                below, above = (-1, 1) if q_noisy else (far, far)
+            else:                   # earlier blocks, a clean row its own too
+                below, above = far, (0 if q_noisy else 1)
+        else:
+            below = jnp.where(jnp.logical_and(k_noisy, q_noisy), -1, far)
+            above = jnp.where(k_noisy, jnp.where(q_noisy, 1, far),
+                              jnp.where(q_noisy, 0, 1))
+        return (first_q % self.seq, first_k % self.seq, below, above)
+
+    def tile_kind(self, qi, ki, block_q, block_k):
+        """(live, interior) of tile (qi, ki), from the block numbers of
+        its corners."""
+        q0, k0, below, above = self.quadrant(qi * block_q, ki * block_k)
+        q_lo, q_hi = self.block_of(q0), self.block_of(q0 + (block_q - 1))
+        k_lo, k_hi = self.block_of(k0), self.block_of(k0 + (block_k - 1))
+        # b(k) - b(q) runs over [k_lo - q_hi, k_hi - q_lo]
+        live = (k_lo - q_hi < above) & (k_hi - q_lo > below)
+        interior = (k_hi - q_lo < above) & (k_lo - q_hi > below)
+        return live, interior
+
+    def allowed(self, first_q, first_k, shape, q_axis):
+        """The rule over one tile as a boolean array of ``shape``, queries
+        along ``q_axis``."""
+        q0, k0, below, above = self.quadrant(first_q, first_k)
+        diff = (self.block_of(k0 + lax.broadcasted_iota(
+            jnp.int32, shape, 1 - q_axis)) - self.block_of(
+            q0 + lax.broadcasted_iota(jnp.int32, shape, q_axis)))
+        return jnp.logical_and(diff > below, diff < above)
+
+
+def _tile_kind(qoff, koff, kreal, qi, ki, *, causal, block_q, block_k,
+               mask=None):
     """(live, interior) for tile (qi, ki): live, some entry is unmasked;
     interior, none is masked. Works on Python ints and on traced scalars
     alike: the kernels, ``_schedule`` and ``tile_counts`` share it."""
+    if mask is not None:            # no offsets, no key padding
+        return mask.tile_kind(qi, ki, block_q, block_k)
     first_k = ki * block_k
     live = first_k < kreal
     interior = first_k + block_k <= kreal
@@ -91,24 +187,42 @@ def _tile_kind(qoff, koff, kreal, qi, ki, *, causal, block_q, block_k):
     return live, interior
 
 
+def _mask_blocks(mask: BlockDiffusionMask, block_q: int, block_k: int):
+    """The tiles ``flash_attention`` takes under ``mask`` from the
+    preferred ``block_q`` / ``block_k``: picked for one copy's length, so
+    that no tile straddles the two copies."""
+    blocks = (_pick_block(mask.seq, block_q), _pick_block(mask.seq, block_k))
+    if any(mask.seq % b for b in blocks):
+        raise ValueError(f"tiles {blocks} do not divide the {mask.seq} "
+                         "positions of a copy: choose block_q / block_k "
+                         "that do")
+    return blocks
+
+
 def tile_counts(sq: int, sk: int, block_q: int, block_k: int, causal: bool,
-                q_offset: int = 0, k_offset: int = 0) -> Tuple[int, int, int]:
+                q_offset: int = 0, k_offset: int = 0,
+                mask: Optional[BlockDiffusionMask] = None
+                ) -> Tuple[int, int, int]:
     """(grid, live, edge) tiles of one head's [sq, sk] scores, for the
     blocks ``flash_attention`` picks from ``block_q`` / ``block_k``: the
     tiles of the whole rectangle, those that compute anything (the steps
     a kernel makes when the offsets are known at trace time), and those
     of them that take the masked body."""
-    bq, bk = _pick_block(max(sq, 1), block_q), _pick_block(max(sk, 1), block_k)
+    if mask is not None:
+        bq, bk = _mask_blocks(mask, block_q, block_k)
+    else:
+        bq, bk = (_pick_block(max(sq, 1), block_q),
+                  _pick_block(max(sk, 1), block_k))
     nq, nk = -(-sq // bq), -(-sk // bk)
     kinds = [_tile_kind(q_offset, k_offset, sk, qi, ki, causal=causal,
-                        block_q=bq, block_k=bk)
+                        block_q=bq, block_k=bk, mask=mask)
              for qi in range(nq) for ki in range(nk)]
     return (nq * nk, sum(bool(live) for live, _ in kinds),
             sum(bool(live and not inner) for live, inner in kinds))
 
 
 def _schedule(nq, nk, group, offsets, *, keys_outer, sk, causal, block_q,
-              block_k):
+              block_k, mask=None):
     """The tiles a head's grid axis visits, in order: int32 [3, steps],
     rows (query head within the group, query block, key block). The
     forward and dq kernels walk a query block's key blocks
@@ -121,7 +235,7 @@ def _schedule(nq, nk, group, offsets, *, keys_outer, sk, causal, block_q,
     def live(qi, ki):
         return offsets is None or bool(_tile_kind(
             *offsets, sk, qi, ki, causal=causal, block_q=block_q,
-            block_k=block_k)[0])
+            block_k=block_k, mask=mask)[0])
 
     if keys_outer:
         rows = [[(g, qi, ki) for g in range(group) for qi in range(nq)]
@@ -164,13 +278,13 @@ def _lanes(x, n):
 
 
 def _on_live_tile(body, qoff_ref, koff_ref, kreal_ref, qi, ki, *, causal,
-                  block_q, block_k, q_axis):
+                  block_q, block_k, q_axis, mask=None):
     """Run ``body(valid)`` if tile (qi, ki) is live: ``valid`` is None on
     an interior tile and the tile's mask, queries along ``q_axis``, on an
     edge tile."""
     live, interior = _tile_kind(qoff_ref[0, 0], koff_ref[0, 0],
                                 kreal_ref[0, 0], qi, ki, causal=causal,
-                                block_q=block_q, block_k=block_k)
+                                block_q=block_q, block_k=block_k, mask=mask)
 
     @pl.when(interior)
     def _():
@@ -179,6 +293,9 @@ def _on_live_tile(body, qoff_ref, koff_ref, kreal_ref, qi, ki, *, causal,
     @pl.when(jnp.logical_and(live, jnp.logical_not(interior)))
     def _():
         shape = (block_q, block_k) if q_axis == 0 else (block_k, block_q)
+        if mask is not None:
+            body(mask.allowed(qi * block_q, ki * block_k, shape, q_axis))
+            return
         k_local = ki * block_k + lax.broadcasted_iota(jnp.int32, shape,
                                                       1 - q_axis)
         valid = k_local < kreal_ref[0, 0]
@@ -196,7 +313,7 @@ def _on_live_tile(body, qoff_ref, koff_ref, kreal_ref, qi, ki, *, causal,
 
 def _fwd_kernel(qoff_ref, koff_ref, kreal_ref, tab_ref, q_ref, k_ref, v_ref,
                 out_ref, lse_ref, acc, m_scr, l_scr, *, scale: float,
-                causal: bool, block_q: int, block_k: int):
+                causal: bool, block_q: int, block_k: int, mask=None):
     t = pl.program_id(1)
     qi, ki = tab_ref[1, t], tab_ref[2, t]
     first, last = _row_ends(tab_ref, t, 1)
@@ -229,7 +346,8 @@ def _fwd_kernel(qoff_ref, koff_ref, kreal_ref, tab_ref, q_ref, k_ref, v_ref,
                   + jnp.dot(p, v, preferred_element_type=jnp.float32))
 
     _on_live_tile(tile, qoff_ref, koff_ref, kreal_ref, qi, ki,
-                  causal=causal, block_q=block_q, block_k=block_k, q_axis=0)
+                  causal=causal, block_q=block_q, block_k=block_k, q_axis=0,
+                  mask=mask)
 
     @pl.when(last)
     def _():
@@ -248,7 +366,7 @@ def _fwd_kernel(qoff_ref, koff_ref, kreal_ref, tab_ref, q_ref, k_ref, v_ref,
 
 def _dq_kernel(qoff_ref, koff_ref, kreal_ref, tab_ref, q_ref, k_ref, v_ref,
                do_ref, lse_ref, delta_ref, dq_ref, dq_acc, *, scale,
-               causal, block_q, block_k):
+               causal, block_q, block_k, mask=None):
     t = pl.program_id(1)
     qi, ki = tab_ref[1, t], tab_ref[2, t]
     first, last = _row_ends(tab_ref, t, 1)
@@ -271,7 +389,8 @@ def _dq_kernel(qoff_ref, koff_ref, kreal_ref, tab_ref, q_ref, k_ref, v_ref,
         dq_acc[:] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
 
     _on_live_tile(tile, qoff_ref, koff_ref, kreal_ref, qi, ki,
-                  causal=causal, block_q=block_q, block_k=block_k, q_axis=0)
+                  causal=causal, block_q=block_q, block_k=block_k, q_axis=0,
+                  mask=mask)
 
     @pl.when(last)
     def _():
@@ -280,7 +399,7 @@ def _dq_kernel(qoff_ref, koff_ref, kreal_ref, tab_ref, q_ref, k_ref, v_ref,
 
 def _dkv_kernel(qoff_ref, koff_ref, kreal_ref, tab_ref, q_ref, k_ref, v_ref,
                 do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_acc,
-                dv_acc, *, scale, causal, block_q, block_k):
+                dv_acc, *, scale, causal, block_q, block_k, mask=None):
     t = pl.program_id(1)
     qi, ki = tab_ref[1, t], tab_ref[2, t]
     first, last = _row_ends(tab_ref, t, 2)
@@ -306,7 +425,8 @@ def _dkv_kernel(qoff_ref, koff_ref, kreal_ref, tab_ref, q_ref, k_ref, v_ref,
         dk_acc[:] += jnp.dot(dst, q, preferred_element_type=jnp.float32)
 
     _on_live_tile(tile, qoff_ref, koff_ref, kreal_ref, qi, ki,
-                  causal=causal, block_q=block_q, block_k=block_k, q_axis=1)
+                  causal=causal, block_q=block_q, block_k=block_k, q_axis=1,
+                  mask=mask)
 
     @pl.when(last)
     def _():
@@ -322,7 +442,8 @@ def _vmem(block, index_map):
     return pl.BlockSpec(block, index_map, memory_space=pltpu.VMEM)
 
 
-def _plan(q3, k3, offsets, *, keys_outer, causal, block_q, block_k):
+def _plan(q3, k3, offsets, *, keys_outer, causal, block_q, block_k,
+          mask=None):
     """A call's schedule and its (q, k/v, row statistics) index maps over
     (head, step, *scalar prefetch), the schedule being the fourth scalar.
     The forward and dq grids run over query heads; dk/dv's runs over
@@ -331,7 +452,8 @@ def _plan(q3, k3, offsets, *, keys_outer, causal, block_q, block_k):
     group = q3.shape[0] // k3.shape[0]      # query heads per key/value head
     tab = _schedule(q3.shape[1] // block_q, k3.shape[1] // block_k, group,
                     offsets, keys_outer=keys_outer, sk=k3.shape[1],
-                    causal=causal, block_q=block_q, block_k=block_k)
+                    causal=causal, block_q=block_q, block_k=block_k,
+                    mask=mask)
 
     def heads(b, t, tab_ref):               # (query head, key/value head)
         if keys_outer:
@@ -370,18 +492,19 @@ def _bwd_in_specs(d, block_q, block_k, q_map, kv_map, row_map):
             _vmem((1, 1, block_q), row_map), _vmem((1, 1, block_q), row_map)]
 
 
-_STATIC = ("scale", "causal", "block_q", "block_k", "offsets", "interpret")
+_STATIC = ("scale", "causal", "block_q", "block_k", "offsets", "interpret",
+           "mask")
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def _flash_fwd_call(qoff, koff, sk_real, q3, k3, v3, *, scale, causal,
-                    block_q, block_k, interpret, offsets=None):
+                    block_q, block_k, interpret, offsets=None, mask=None):
     """The forward kernel's call and nothing else: a jitted function whose
     result is the ``pallas_call``'s own gives the custom-call this
     function's name in the compiled program, which is where a device
     trace finds the kernel (the jit itself is inlined)."""
     bh, sq, d = q3.shape
-    tiles = dict(causal=causal, block_q=block_q, block_k=block_k)
+    tiles = dict(causal=causal, block_q=block_q, block_k=block_k, mask=mask)
     tab, q_map, kv_map, row_map = _plan(q3, k3, offsets, keys_outer=False,
                                         **tiles)
     return _call(
@@ -400,10 +523,11 @@ def _flash_fwd_call(qoff, koff, sk_real, q3, k3, v3, *, scale, causal,
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def _flash_dq_call(qoff, koff, sk_real, q3, k3, v3, do3, lse3, delta3, *,
-                   scale, causal, block_q, block_k, interpret, offsets=None):
+                   scale, causal, block_q, block_k, interpret, offsets=None,
+                   mask=None):
     """The dq kernel's call and nothing else (see ``_flash_fwd_call``)."""
     bh, sq, d = q3.shape
-    tiles = dict(causal=causal, block_q=block_q, block_k=block_k)
+    tiles = dict(causal=causal, block_q=block_q, block_k=block_k, mask=mask)
     tab, q_map, kv_map, row_map = _plan(q3, k3, offsets, keys_outer=False,
                                         **tiles)
     return _call(
@@ -418,10 +542,11 @@ def _flash_dq_call(qoff, koff, sk_real, q3, k3, v3, do3, lse3, delta3, *,
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def _flash_dkv_call(qoff, koff, sk_real, q3, k3, v3, do3, lse3, delta3, *,
-                    scale, causal, block_q, block_k, interpret, offsets=None):
+                    scale, causal, block_q, block_k, interpret, offsets=None,
+                    mask=None):
     """The dk/dv kernel's call and nothing else (see ``_flash_fwd_call``)."""
     bkv, sk, d = k3.shape
-    tiles = dict(causal=causal, block_q=block_q, block_k=block_k)
+    tiles = dict(causal=causal, block_q=block_q, block_k=block_k, mask=mask)
     tab, q_map, kv_map, row_map = _plan(q3, k3, offsets, keys_outer=True,
                                         **tiles)
     return _call(
@@ -508,9 +633,21 @@ def _flash_bwd(static, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+def _check_mask(mask: BlockDiffusionMask, q, k, causal, q_offset, k_offset):
+    if causal or not all(isinstance(o, (int, np.integer)) and o == 0
+                         for o in (q_offset, k_offset)):
+        raise ValueError("a mask rule stands alone: no causal flag, no "
+                         "offsets")
+    if q.shape[1] != 2 * mask.seq or k.shape[1] != 2 * mask.seq:
+        raise ValueError(f"{mask} is over {2 * mask.seq} rows; q has "
+                         f"{q.shape[1]}, k {k.shape[1]}")
+
+
 def flash_attention_reference(q, k, v, *, causal: bool = False,
                               scale: Optional[float] = None,
-                              q_offset=0, k_offset=0) -> jax.Array:
+                              q_offset=0, k_offset=0,
+                              mask: Optional[BlockDiffusionMask] = None
+                              ) -> jax.Array:
     """XLA reference (materializes scores): oracle + non-TPU fallback."""
     d = q.shape[-1]
     if scale is None:
@@ -523,8 +660,12 @@ def flash_attention_reference(q, k, v, *, causal: bool = False,
     if causal:
         qpos = q_offset + jnp.arange(q.shape[1])
         kpos = k_offset + jnp.arange(k.shape[1])
-        mask = qpos[:, None] >= kpos[None, :]
-        s = jnp.where(mask[None, :, None, :], s, _NEG_BIG)
+        seen = qpos[:, None] >= kpos[None, :]
+        s = jnp.where(seen[None, :, None, :], s, _NEG_BIG)
+    if mask is not None:
+        _check_mask(mask, q, k, causal, q_offset, k_offset)
+        seen = mask.everywhere()
+        s = jnp.where(seen[None, :, None, :], s, _NEG_BIG)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bqhk,bkhd->bqhd", p, v.astype(p.dtype),
                      preferred_element_type=jnp.float32)
@@ -536,10 +677,12 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     use_pallas: Optional[bool] = None,
-                    interpret: bool = False) -> jax.Array:
+                    interpret: bool = False,
+                    mask: Optional[BlockDiffusionMask] = None) -> jax.Array:
     """Flash attention over [B, S, H, D] tensors (differentiable); k and
     v may carry fewer heads ``[B, S, H_kv, D]`` with H a multiple of H_kv
-    (grouped-query attention).
+    (grouped-query attention). ``mask``: a static rule in place of
+    ``causal`` (``BlockDiffusionMask``), over ``2 * mask.seq`` rows.
 
     ``use_pallas=None`` auto-selects: the Pallas kernel on TPU backends,
     the XLA reference elsewhere (``interpret=True`` forces the kernel in
@@ -563,17 +706,22 @@ def flash_attention(q, k, v, *, causal: bool = False,
     if not use_pallas:
         return flash_attention_reference(q, k, v, causal=causal,
                                          scale=scale, q_offset=q_offset,
-                                         k_offset=k_offset)
+                                         k_offset=k_offset, mask=mask)
     b, sq, h, d = q.shape
     if h % k.shape[2] or k.shape[2] != v.shape[2]:
         raise ValueError(f"{h} query heads do not group over "
                          f"{k.shape[2]} key / {v.shape[2]} value heads")
     known = all(isinstance(o, (int, np.integer))
                 for o in (q_offset, k_offset))
+    if mask is not None:
+        _check_mask(mask, q, k, causal, q_offset, k_offset)
+        block_q, block_k = _mask_blocks(mask, block_q, block_k)
+    else:
+        block_q = _pick_block(max(sq, 1), block_q)
+        block_k = _pick_block(max(k.shape[1], 1), block_k)
     static = dict(
-        scale=scale, causal=causal, interpret=interpret,
-        block_q=_pick_block(max(sq, 1), block_q),
-        block_k=_pick_block(max(k.shape[1], 1), block_k),
+        scale=scale, causal=causal, interpret=interpret, block_q=block_q,
+        block_k=block_k, mask=mask,
         offsets=(int(q_offset), int(k_offset)) if known else None)
     qoff = jnp.full((1, 1), q_offset, jnp.int32)
     koff = jnp.full((1, 1), k_offset, jnp.int32)

@@ -12,10 +12,11 @@ out and one back (replacing brpc/NCCL global_scatter/global_gather). The
 einsum-heavy dispatch/combine maps onto the MXU.
 
 Beside it, for layers with hundreds of experts and tens per token (where
-a ``[T, E, C]`` one-hot cannot exist): ``topk_sigmoid_router`` and
-``dropless_dispatch``, which sorts the assignments by expert and runs the
-experts as one grouped product over contiguous row segments. The layer is
-told which experts it holds (``held = (first, count)``): it routes over
+a ``[T, E, C]`` one-hot cannot exist): ``topk_sigmoid_router``,
+``topk_softmax_router`` and ``dropless_dispatch``, which sorts the
+assignments by expert and runs the experts as one grouped product over
+contiguous row segments. The layer is told which experts it holds
+(``held = (first, count)``): it routes over
 all of them, normalises over all chosen and computes the held part, which
 is what a chip of an expert-parallel deployment does between the two
 exchanges. No capacity, so no assignment is ever dropped.
@@ -23,6 +24,7 @@ exchanges. No capacity, so no assignment is ever dropped.
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, Dict, NamedTuple, Tuple
 
 import jax
@@ -156,6 +158,28 @@ def topk_sigmoid_router(x: jax.Array, gate_w: jax.Array, bias: jax.Array,
     return idx, checkpoint_name(chosen * scaling, "moe_weights")
 
 
+def topk_softmax_router(x: jax.Array, gate_w: jax.Array, k: int
+                        ) -> Tuple[jax.Array, jax.Array]:
+    """Softmax over all experts, the ``k`` likeliest chosen (the lower
+    index of equals first), weighted by their probabilities renormalised
+    over the chosen: no bias, no scaling.
+
+    x [T, F]; gate_w [F, E]. Returns (idx [T, k] int32, weights [T, k]
+    float32, summing to 1 a token). Logits and softmax are float32 at
+    full matmul precision, as ``topk_sigmoid_router``'s scores.
+    """
+    f32 = jnp.float32
+    probs = jax.nn.softmax(checkpoint_name(jnp.dot(
+        x.astype(f32), gate_w.astype(f32), precision=lax.Precision.HIGHEST,
+        preferred_element_type=f32), "moe_logits"), axis=-1)
+    idx = checkpoint_name(lax.top_k(probs, k)[1], "moe_idx")
+    # gathered by the named indices: a caller that keeps them does not
+    # run the top-k a second time
+    chosen = jnp.take_along_axis(probs, idx, axis=-1)
+    return idx, checkpoint_name(
+        chosen / jnp.sum(chosen, axis=-1, keepdims=True), "moe_weights")
+
+
 class DispatchCounts(NamedTuple):
     """What one call of ``dropless_dispatch`` served."""
     load: jax.Array       # [count] assignments per held expert
@@ -164,9 +188,75 @@ class DispatchCounts(NamedTuple):
     dropped: jax.Array
 
 
+def _block_at(lo, k, rows, order, load):
+    """Block ``lo .. lo + rows`` of the sorted assignments: (their places
+    in the flat ``[T * k]`` assignments, their tokens, which of them are
+    held, rows per held expert)."""
+    ends = jnp.cumsum(load)
+    sel = lax.dynamic_slice(order, (lo,), (rows,))
+    live = ((lo + jnp.arange(rows)) < ends[-1])[:, None]
+    sizes = jnp.clip(ends - lo, 0, rows) - jnp.clip(ends - load - lo, 0, rows)
+    return sel, sel // k, live, sizes
+
+
+def _blocks_needed(load, rows):
+    return (jnp.sum(load) + (rows - 1)) // rows
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _looped_blocks(rows_fn, k, rows, u, flat_w, order, load, params):
+    """``dropless_dispatch``'s blocks, ``rows`` assignments each, as a
+    loop over the blocks the load needs: ``(out [T, F] float32 -> u's
+    dtype, assignments served)``. ``order`` is padded to whole blocks.
+    Rows past the last held assignment are selected away on both sides,
+    as in the unrolled form."""
+    def block(i, acc):
+        out, served = acc
+        sel, tok, live, sizes = _block_at(i * rows, k, rows, order, load)
+        y = rows_fn(params, jnp.where(live, u[tok], 0.0), sizes)
+        y = jnp.where(live, y, 0.0) * flat_w[sel][:, None]
+        return out.at[tok].add(y), served + jnp.sum(live, dtype=jnp.int32)
+    out, served = lax.fori_loop(
+        0, _blocks_needed(load, rows), block,
+        (jnp.zeros(u.shape, jnp.float32), jnp.zeros((), jnp.int32)))
+    return out.astype(u.dtype), served
+
+
+def _looped_blocks_fwd(rows_fn, k, rows, u, flat_w, order, load, params):
+    return (_looped_blocks(rows_fn, k, rows, u, flat_w, order, load, params),
+            (u, flat_w, order, load, params))
+
+
+def _looped_blocks_bwd(rows_fn, k, rows, res, cotangents):
+    u, flat_w, order, load, params = res
+    g = cotangents[0].astype(jnp.float32)
+
+    def block(i, acc):
+        du, dw, dparams = acc
+        sel, tok, live, sizes = _block_at(i * rows, k, rows, order, load)
+        y, back = jax.vjp(lambda p, x: rows_fn(p, x, sizes), params,
+                          jnp.where(live, u[tok], 0.0))
+        g_rows = jnp.where(live, g[tok], 0.0)
+        own, drows = back(g_rows * flat_w[sel][:, None])
+        return (du.at[tok].add(jnp.where(live, drows, 0.0)),
+                dw.at[sel].add(jnp.sum(
+                    g_rows * jnp.where(live, y, 0.0), axis=-1)),
+                jax.tree.map(jnp.add, dparams, own))
+    du, dw, dparams = lax.fori_loop(
+        0, _blocks_needed(load, rows), block,
+        (jnp.zeros(u.shape, jnp.float32), jnp.zeros(flat_w.shape,
+                                                    jnp.float32),
+         jax.tree.map(jnp.zeros_like, params)))
+    return du.astype(u.dtype), dw.astype(flat_w.dtype), None, None, dparams
+
+
+_looped_blocks.defvjp(_looped_blocks_fwd, _looped_blocks_bwd)
+
+
 def dropless_dispatch(u: jax.Array, idx: jax.Array, weights: jax.Array,
                       held: Tuple[int, int],
-                      rows_fn: Callable[[jax.Array, jax.Array], jax.Array]
+                      rows_fn: Callable[..., jax.Array],
+                      expert_params=None, block_rows: int = 0
                       ) -> Tuple[jax.Array, DispatchCounts]:
     """Weighted sum over each token's chosen experts, restricted to the
     experts ``first <= e < first + count`` this device holds.
@@ -179,7 +269,22 @@ def dropless_dispatch(u: jax.Array, idx: jax.Array, weights: jax.Array,
     Assignments are sorted by held expert (the others sort last and are
     never touched) and served in blocks of T rows; a block past the last
     held assignment is skipped by ``lax.cond``, so the work follows the
-    load and the static bound ``T * min(k, count)`` costs nothing.
+    load and the static bound ``T * min(k, count)`` costs no time. It
+    costs memory: every static block's backward pass has its own
+    gradient of the experts' weights and its own intermediates, allocated
+    whether it is served or not (0.7 GB a block for 16 experts of 2048 x
+    768 x 3 and 8,192 rows).
+
+    With ``expert_params`` (the experts' stacked weights, a pytree) the
+    call is ``rows_fn(expert_params, rows, sizes)`` and the blocks are a
+    loop whose trip count is the blocks the load needs, forward and
+    backward (``_looped_blocks``): one block's intermediates and one
+    gradient, summed in place, whatever the static bound. The backward
+    pass computes a served block's forward again and keeps nothing of the
+    first. ``block_rows`` (default T) is the loop's block: a trip has its
+    own cost beside its rows' (their gathers, the sum of the experts'
+    gradient), so a caller that knows its share sizes the block to hold a
+    usual load in one trip.
     """
     t, k = idx.shape
     first, count = held
@@ -193,6 +298,12 @@ def dropless_dispatch(u: jax.Array, idx: jax.Array, weights: jax.Array,
                 dtype=jnp.int32), "moe_load")
     ends = jnp.cumsum(load)
     starts, n_held = ends - load, ends[-1]
+    if expert_params is not None:
+        rows = block_rows or t
+        out, served = _looped_blocks(
+            rows_fn, k, rows, u, flat_w,
+            jnp.pad(order, (0, -(t * k) % rows)), load, expert_params)
+        return out, DispatchCounts(load, n_held - served)
     acc = (jnp.zeros(u.shape, jnp.float32), jnp.zeros((), jnp.int32))
     for lo in range(0, t * min(k, count), t):
         sizes = jnp.clip(ends - lo, 0, t) - jnp.clip(starts - lo, 0, t)

@@ -41,6 +41,7 @@ def test_pallas_kernels_aot_compile_for_tpu():
     assert "AOT flash_attention fwd+bwd [4, 1024, 16, 64]" in out
     assert "AOT flash_attention fwd+bwd [1, 4096, 16/16, 128]" in out
     assert "AOT flash_attention grouped fwd+bwd" in out
+    assert out.count("AOT flash_attention block-diffusion fwd+bwd") == 2
     assert out.count("AOT ssd_scan fwd+bwd") == 2
     assert "AOT seqpool_cvm" in out
     assert "PALLAS KERNELS TPU AOT COMPILE: OK" in out
@@ -117,6 +118,23 @@ def test_looped_cell_programs_fit_the_v5e():
     assert "AOT looped step: " in out
     assert "AOT looped setup gradient: " in out
     assert "AOT looped step and setup gradient fit: OK" in out
+
+
+@pytest.mark.slow
+def test_block_diffusion_cell_programs_fit_the_v5e():
+    """The block-diffusion cell's timed step and its set-up's ``highest``
+    gradient function, compiled for the v5e at published widths, 12
+    layers, 16 held experts and 4,096 positions (8,192 rows), each under
+    the tool's stated share of the chip, under a plan that keeps the
+    router's values in every piece (which pieces keep the flash output is
+    the plan's to say); 80 of the mask's 256 tiles a head are live."""
+    out = _run_tool("aot_check_dense.py", 1500, "--blockdiff")
+    plan = out.split("blockdiff plan: ", 1)[1].splitlines()[0]
+    assert '"layers_kept": "L:4"' in plan and "moe_logits" in plan, plan
+    assert '"tiles_live": 80' in plan and '"tiles_edge": 24' in plan
+    assert "AOT blockdiff step: " in out
+    assert "AOT blockdiff setup gradient: " in out
+    assert "AOT blockdiff step and setup gradient fit: OK" in out
 
 
 @pytest.mark.slow

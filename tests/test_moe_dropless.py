@@ -7,7 +7,8 @@ import pytest
 from jax import lax
 
 from paddlebox_tpu.parallel.moe import (dropless_dispatch,
-                                        topk_sigmoid_router)
+                                        topk_sigmoid_router,
+                                        topk_softmax_router)
 
 T, F, E, K, INNER = 64, 16, 16, 4, 24
 
@@ -55,21 +56,33 @@ def test_router_chooses_by_biased_score_and_weighs_by_score():
     assert float(jnp.abs(grad).max()) == 0.0
 
 
-@pytest.mark.parametrize("shares", [1, 4])
-def test_shares_add_up_to_the_uncut_layer(shares):
+def _expert_of(p, rows, sizes):
+    """``_expert`` with the weights handed in: the looped dispatch's form."""
+    return _expert(p["w1"], p["w2"])(rows, sizes)
+
+
+@pytest.mark.parametrize("shares,looped", [(1, False), (4, False),
+                                           (4, True)])
+def test_shares_add_up_to_the_uncut_layer(shares, looped):
     """What all the shares give (4 chips holding 4 experts each, or one
     holding all 16) adds up to the layer over all experts, in value and
-    in every gradient."""
+    in every gradient; the blocks unrolled under ``cond`` or as a loop."""
     x, gate, w1, w2 = _layer()
     count = E // shares
 
     def cut(x, gate, w1, w2):
         idx, w = topk_sigmoid_router(x, gate, jnp.zeros(E), k=K,
                                      scaling=2.5)
-        return sum(dropless_dispatch(
-            x, idx, w, (first, count),
-            _expert(w1[first:first + count], w2[first:first + count]))[0]
-            for first in range(0, E, count))
+
+        def share(first):
+            held = {"w1": w1[first:first + count],
+                    "w2": w2[first:first + count]}
+            if looped:      # in blocks that do not divide the T * K rows
+                return dropless_dispatch(x, idx, w, (first, count),
+                                         _expert_of, held, block_rows=40)[0]
+            return dropless_dispatch(x, idx, w, (first, count),
+                                     _expert(held["w1"], held["w2"]))[0]
+        return sum(share(first) for first in range(0, E, count))
     with jax.default_matmul_precision("highest"):
         np.testing.assert_allclose(
             np.asarray(jax.jit(cut)(x, gate, w1, w2)),
@@ -82,15 +95,93 @@ def test_shares_add_up_to_the_uncut_layer(shares):
         assert float(jnp.linalg.norm(g - w) / jnp.linalg.norm(w)) < 1e-5
 
 
-def test_nothing_is_dropped_when_one_expert_takes_half_the_tokens():
+def _swiglu_of(p, rows, sizes):
+    gate = lax.ragged_dot(rows, p["w1"], sizes)
+    return lax.ragged_dot(jax.nn.silu(gate) * lax.ragged_dot(
+        rows, p["w3"], sizes), p["w2"], sizes)
+
+
+def test_eight_shares_of_the_softmax_swiglu_layer_add_up_to_the_uncut_one():
+    """The block-diffusion stack's expert layer: softmax top-k routing
+    renormalised over the chosen, gated experts, the looped dispatch. The
+    eight shares ``(0, 2) .. (14, 2)`` add up to every expert applied to
+    every token, in value and in every gradient."""
+    x, gate, w1, w2 = _layer(3)
+    w3 = jax.random.normal(jax.random.PRNGKey(33), w1.shape) * 0.3
+
+    def uncut(x, gate, w1, w3, w2):
+        idx, w = topk_softmax_router(x, gate, K)
+        hidden = (jax.nn.silu(jnp.einsum("tf,efi->tei", x, w1))
+                  * jnp.einsum("tf,efi->tei", x, w3))
+        return jnp.einsum("tke,tef->tf",
+                          jax.nn.one_hot(idx, E) * w[..., None],
+                          jnp.einsum("tei,eif->tef", hidden, w2))
+
+    def cut(x, gate, w1, w3, w2):
+        idx, w = topk_softmax_router(x, gate, K)
+        return sum(dropless_dispatch(
+            x, idx, w, (first, 2), _swiglu_of,
+            {"w1": w1[first:first + 2], "w3": w3[first:first + 2],
+             "w2": w2[first:first + 2]})[0] for first in range(0, E, 2))
+    args = (x, gate, w1, w3, w2)
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(
+            np.asarray(jax.jit(cut)(*args)), np.asarray(uncut(*args)),
+            rtol=1e-5, atol=1e-5)
+        want = jax.grad(lambda *a: jnp.sum(jnp.sin(uncut(*a))),
+                        argnums=tuple(range(5)))(*args)
+        got = jax.jit(jax.grad(lambda *a: jnp.sum(jnp.sin(cut(*a))),
+                               argnums=tuple(range(5))))(*args)
+    for g, w in zip(got, want):
+        assert float(jnp.linalg.norm(g - w) / jnp.linalg.norm(w)) < 1e-5
+
+
+def test_softmax_router_is_the_sort_based_one_ties_included():
+    x, gate, _, _ = _layer(4)
+    # experts 2 and 9 score alike for every token, 4 and 11 as well: the
+    # lower index of equals is chosen first
+    gate = gate.at[:, 9].set(gate[:, 2]).at[:, 11].set(gate[:, 4])
+    idx, w = jax.jit(lambda x, g: topk_softmax_router(x, g, K))(x, gate)
+    assert idx.dtype == jnp.int32 and w.dtype == jnp.float32
+    logits = np.asarray(x, np.float64) @ np.asarray(gate, np.float64)
+    probs = np.exp(logits - logits.max(axis=1, keepdims=True))
+    probs /= probs.sum(axis=1, keepdims=True)
+    # exact ties in float64 as in float32: equal columns
+    want = np.argsort(-probs, axis=1, kind="stable")[:, :K]
+    np.testing.assert_array_equal(np.asarray(idx), want)
+    picked = np.take_along_axis(probs, want, axis=1)
+    np.testing.assert_allclose(
+        np.asarray(w), picked / picked.sum(axis=1, keepdims=True), rtol=2e-5)
+    np.testing.assert_allclose(np.asarray(w.sum(axis=1)), 1.0, rtol=1e-6)
+    assert bool(jnp.all(w[:, :-1] >= w[:, 1:]))
+    both = (np.asarray(idx) == 2).any(axis=1) & (np.asarray(idx) == 9).any(
+        axis=1)
+    assert both.any()       # a tie inside the chosen
+    rows = np.nonzero(both)[0]
+    pos2 = np.argmax(np.asarray(idx)[rows] == 2, axis=1)
+    pos9 = np.argmax(np.asarray(idx)[rows] == 9, axis=1)
+    assert (pos2 < pos9).all()
+    # the logits' gradient reaches the gate through the chosen alone
+    grad = jax.grad(lambda g: jnp.sum(topk_softmax_router(x, g, K)[1]
+                                      * jnp.arange(K)))(gate)
+    assert float(jnp.abs(grad).max()) > 0.0
+
+
+@pytest.mark.parametrize("looped", [False, True])
+def test_nothing_is_dropped_when_one_expert_takes_half_the_tokens(looped):
     x, gate, w1, w2 = _layer(1)
     # expert 5 is planted to win for the first half of the tokens
     bias = jnp.zeros(E)
     x = x.at[:T // 2].set(jnp.abs(x[:T // 2]))
     gate = gate.at[:, 5].set(3.0)
     idx, w = topk_sigmoid_router(x, gate, bias, k=K, scaling=2.5)
-    out, counts = jax.jit(lambda x, idx, w: dropless_dispatch(
-        x, idx, w, (4, 4), _expert(w1[4:8], w2[4:8])))(x, idx, w)
+    if looped:
+        dispatch = lambda x, idx, w: dropless_dispatch(
+            x, idx, w, (4, 4), _expert_of, {"w1": w1[4:8], "w2": w2[4:8]})
+    else:
+        dispatch = lambda x, idx, w: dropless_dispatch(
+            x, idx, w, (4, 4), _expert(w1[4:8], w2[4:8]))
+    out, counts = jax.jit(dispatch)(x, idx, w)
     load = np.asarray(counts.load)
     want = np.array([(np.asarray(idx) == e).sum() for e in range(4, 8)])
     np.testing.assert_array_equal(load, want)       # a numpy count
@@ -102,12 +193,18 @@ def test_nothing_is_dropped_when_one_expert_takes_half_the_tokens():
                                rtol=2e-2, atol=2e-2)
 
 
-def test_no_held_expert_chosen_gives_zero():
+@pytest.mark.parametrize("looped", [False, True])
+def test_no_held_expert_chosen_gives_zero(looped):
     x, gate, w1, w2 = _layer(2)
     idx = jnp.zeros((T, K), jnp.int32)              # everyone picks 0
     w = jnp.ones((T, K))
-    out, counts = dropless_dispatch(x, idx, w, (8, 4),
-                                    _expert(w1[8:12], w2[8:12]))
+    if looped:
+        out, counts = dropless_dispatch(
+            x, idx, w, (8, 4), _expert_of,
+            {"w1": w1[8:12], "w2": w2[8:12]})
+    else:
+        out, counts = dropless_dispatch(x, idx, w, (8, 4),
+                                        _expert(w1[8:12], w2[8:12]))
     assert float(jnp.abs(out).max()) == 0.0
     assert int(counts.load.sum()) == 0 and int(counts.dropped) == 0
 

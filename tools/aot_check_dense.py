@@ -1,5 +1,6 @@
 """AOT-compile the dense benchmark train steps (resnet50 bf16, BERT-base,
-the hybrid and the looped cell's two programs each with their memory) for TPU — no TPU needed
+the hybrid, the looped and the block-diffusion cell's two programs each
+with their memory) for TPU — no TPU needed
 (compile-only PJRT topology).
 
 These two steps had never run on hardware before round 3 (both
@@ -7,12 +8,15 @@ carried calling-convention bugs), so their TPU-compile surface — notably
 the bf16 conv forward/transpose path resnet now uses — is exactly the
 kind of thing that would otherwise only fail inside the recorded run:
 
-    python tools/aot_check_dense.py [--hybrid | --looped]
+    python tools/aot_check_dense.py [--hybrid | --looped | --blockdiff]
 
 ``--hybrid`` checks the hybrid cell in their place, ``--looped`` the looped
 cell (``models/looped.py`` at ``benchmarks/configs/ouro_2_6b.json``, 4,096
 positions: the timed step and the set-up's ``highest`` gradient function
-as ``benchmarks/runners/looped_train.py`` builds it). The hybrid stack
+as ``benchmarks/runners/looped_train.py`` builds it), ``--blockdiff`` the
+block-diffusion cell likewise (``models/block_diffusion.py`` at
+``benchmarks/configs/sdar_30b_a3b.json``, 4,096 positions, 8,192 rows;
+``benchmarks/runners/block_diffusion_train.py``). The hybrid stack
 (``models/nemotron_h.py``) plans what its layers keep for the backward
 pass from the device's memory. ``check_hybrid`` compiles
 what ``benchmarks/runners/hybrid_train.py`` builds from that plan at the
@@ -223,6 +227,40 @@ def check_looped(device) -> None:
             cfg, looped._plan_for(cfg, mesh, params, tok)), programs)
 
 
+def check_blockdiff(device) -> None:
+    from benchmarks.runners import block_diffusion_train as runner
+    from paddlebox_tpu.models import block_diffusion as bd
+    config, seq = _cell_files("sdar_30b_a3b", "train_bd_s4096")
+    cfg = runner.program_config(config)
+
+    def noise(tok):
+        """The step's other two inputs, laid out as the tokens are."""
+        return (jax.ShapeDtypeStruct(
+            (tok.shape[0], seq // cfg.block_length), jnp.float32,
+            sharding=tok.sharding),
+            jax.ShapeDtypeStruct(tok.shape, jnp.bool_,
+                                 sharding=tok.sharding))
+
+    def programs(mesh, specs, opt):
+        def step(params, opt_state, tok):
+            return bd.make_block_diffusion_train_step(
+                cfg, mesh, specs, opt).lower(params, opt_state, tok,
+                                             *noise(tok))
+
+        def grads(params, opt_state, tok):
+            with jax.default_matmul_precision("highest"):
+                return runner.program_reading(
+                    cfg, mesh, specs, runner.checked_leaves(
+                        cfg.pieces, cfg.num_hidden_layers // cfg.pieces)
+                ).lower(params, tok, *noise(tok))
+        return ("step", step), ("setup gradient", grads)
+    _check_programs(
+        "blockdiff", device, config, seq,
+        lambda key: bd.init_block_diffusion(key, cfg),
+        lambda mesh, params, tok: bd.plan_attributes(
+            cfg, bd._plan_for(cfg, mesh, params, tok), seq), programs)
+
+
 def main() -> None:
     topo = tpu_topology("v5e:2x2x1")
     if topo is None:
@@ -233,6 +271,9 @@ def main() -> None:
         return
     if "--looped" in sys.argv:      # two minutes of its own
         check_looped(topo.devices[0])
+        return
+    if "--blockdiff" in sys.argv:
+        check_blockdiff(topo.devices[0])
         return
     check_bert(sh)
     check_resnet(sh)

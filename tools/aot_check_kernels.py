@@ -49,7 +49,7 @@ GATHER_SHAPES = [
 
 def main() -> None:
     from paddlebox_tpu.ops.pallas_kernels.flash_attention import (
-        flash_attention)
+        BlockDiffusionMask, flash_attention)
     from paddlebox_tpu.ops.pallas_kernels.seqpool_cvm import (
         seqpool_cvm_pallas)
     from paddlebox_tpu.ops.pallas_kernels.sorted_gather import sorted_gather
@@ -106,6 +106,21 @@ def main() -> None:
                                         use_pallas=True).sum(),
         argnums=(0, 1, 2))).lower(q, q, q).compile()
     print("AOT flash_attention fwd+bwd [1, 4096, 16/16, 128]: OK", flush=True)
+    # The block-diffusion stack's attention at published widths and the
+    # benchmark cell's 4,096 positions (benchmarks/configs/sdar_30b_a3b.json):
+    # 8,192 rows, 32 query heads over 4 key/value heads of 128, under the
+    # block mask (blocks of 4; of 6: a block length that is no power of two
+    # divides in the edge tiles).
+    for seq, block in ((4096, 4), (3072, 6)):
+        q = sds((1, 2 * seq, 32, 128), jnp.float32)
+        kv = sds((1, 2 * seq, 4, 128), jnp.float32)
+        rule = BlockDiffusionMask(seq, block)
+        jax.jit(jax.grad(
+            lambda q, k, v: flash_attention(q, k, v, mask=rule,
+                                            use_pallas=True).sum(),
+            argnums=(0, 1, 2))).lower(q, kv, kv).compile()
+        print(f"AOT flash_attention block-diffusion fwd+bwd "
+              f"[1, {2 * seq}, 32/4, 128] blocks of {block}: OK", flush=True)
     scan_args = (sds((1, 8192, 128, 64), jnp.float32),
                  sds((1, 8192, 128), jnp.float32), sds((128,), jnp.float32),
                  sds((1, 8192, 8, 128), jnp.float32),
