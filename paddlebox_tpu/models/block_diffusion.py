@@ -56,6 +56,9 @@ from paddlebox_tpu.models.train_step import make_train_step
 from paddlebox_tpu.ops.pallas_kernels.flash_attention import (
     RESIDUAL_NAMES as FLASH_RESIDUAL_NAMES, BlockDiffusionMask,
     flash_attention, tile_counts)
+from paddlebox_tpu.ops.pallas_kernels.grouped_matmul import (
+    ROW_TILE, grouped_matmul, grouped_weight_grad, row_tile_schedule,
+    scatter_add_rows)
 from paddlebox_tpu.ops.pallas_kernels.ssd_scan import ambient_mxu_dtype
 from paddlebox_tpu.parallel import moe as moelib
 from paddlebox_tpu.parallel import tp as tplib
@@ -173,15 +176,79 @@ def init_block_diffusion(rng: jax.Array, cfg: BlockDiffusionConfig
 
 # -- the layer ---------------------------------------------------------------
 
-def _held_experts(p, rows, sizes, mxu=jnp.float32):
-    """SwiGLU experts over contiguous row segments, ``sizes[e]`` rows for
-    held expert e: two grouped products in, one out, their operands cast
-    to ``mxu``, their sums float32."""
-    def grouped(x, w):
-        return lax.ragged_dot(x.astype(mxu), w.astype(mxu), sizes,
-                              preferred_element_type=jnp.float32)
-    gate = grouped(rows, p["w1"])
-    return grouped(jax.nn.silu(gate) * grouped(rows, p["w3"]), p["w2"])
+# The looped dispatch's block, in even router's shares of a layer's
+# assignments (a layer's held load is 0.7 to 2.4 shares): read on the chip
+# at one, two and three shares over six seeds (PERF.md section 5).
+DISPATCH_SHARES = 1
+
+
+def dispatch_block_rows(cfg: BlockDiffusionConfig, rows: int) -> int:
+    """Assignments a trip of the expert dispatch's loop holds, for
+    ``rows`` rows through a layer: ``DISPATCH_SHARES`` times what the
+    held experts get of an even router, in whole row tiles of the grouped
+    products."""
+    share = -(-rows * cfg.num_experts_per_tok * cfg.experts_held[1]
+              // cfg.router_experts)
+    return -(-DISPATCH_SHARES * share // ROW_TILE) * ROW_TILE
+
+
+def _packed(lp) -> Dict:
+    """A layer's held experts as ``_held_experts`` takes them: the gate's
+    and the up-projection's matrices side by side (``w13`` ``[held, d, 2
+    inner]``), so that one grouped product reads a row once for both and
+    one gives the rows' cotangent through both. The gradient comes back
+    through the concatenation as two halves."""
+    return {"w13": jnp.concatenate([lp["w1"], lp["w3"]], axis=-1),
+            "w2": lp["w2"]}
+
+
+def _held_experts(mode: Dict, mxu) -> moelib.LoopedExperts:
+    """SwiGLU experts (``_packed``) over contiguous row segments,
+    ``sizes[e]`` rows for held expert e, each row's result times its
+    ``scale``: a grouped product in (gate | up) and one out, forward;
+    backward the one in again and four more (the rows' cotangent through
+    ``w2`` and through ``w13`` transposed; the two weights' gradients,
+    each added to the sum it is handed). Every product's operands are
+    ``mxu``, every sum float32; what lies between the products is
+    float32. ``mode``: ``flags.kernel_mode``'s."""
+    def products(sizes, rows):
+        kernels = dict(use_pallas=mode["use_pallas"],
+                       interpret=mode["interpret"], sizes=sizes)
+        if mode["use_pallas"]:      # one table of visits for all of them
+            kernels["schedule"] = row_tile_schedule(sizes, rows, ROW_TILE)
+        return (functools.partial(grouped_matmul, **kernels),
+                functools.partial(grouped_weight_grad, **kernels))
+
+    def forward(p, rows, scale, sizes):
+        rows_by, _ = products(sizes, rows.shape[0])
+        gate, up = jnp.split(rows_by(rows, p["w13"].astype(mxu)), 2, axis=-1)
+        hidden = jax.nn.silu(gate) * up * scale[:, None]
+        return rows_by(hidden.astype(mxu), p["w2"].astype(mxu))
+
+    def backward(p, rows, scale, sizes, dy, sums):
+        rows_by, weights_by = products(sizes, rows.shape[0])
+        w13, w2 = p["w13"].astype(mxu), p["w2"].astype(mxu)
+        gate, up = jnp.split(rows_by(rows, w13), 2, axis=-1)
+        sig = jax.nn.sigmoid(gate)
+        act = gate * sig
+        hidden = act * up
+        # y = scale * (hidden @ w2): the cotangent of hidden is scale *
+        # (dy @ w2.T) and that of scale is <dy, hidden @ w2> = <dy @
+        # w2.T, hidden>, so the out-product is not computed again
+        back = rows_by(dy, w2, transpose_w=True)
+        dhidden = back * scale[:, None]
+        din = jnp.concatenate(
+            [dhidden * up * (sig * (1.0 + gate * (1.0 - sig))),
+             dhidden * act], axis=-1).astype(mxu)
+        sums = {"w13": weights_by(rows, din, into=sums["w13"]),
+                "w2": weights_by((hidden * scale[:, None]).astype(mxu), dy,
+                                 into=sums["w2"])}
+        return (rows_by(din, w13, transpose_w=True),
+                jnp.sum(back * hidden, axis=-1), sums)
+    return moelib.LoopedExperts(
+        forward, backward, mxu, functools.partial(
+            scatter_add_rows, use_pallas=mode["use_pallas"],
+            interpret=mode["interpret"]))
 
 
 def _layer(lp, h, cfg: BlockDiffusionConfig, rule: BlockDiffusionMask,
@@ -207,24 +274,20 @@ def _layer(lp, h, cfg: BlockDiffusionConfig, rule: BlockDiffusionMask,
     idx, weights = moelib.topk_softmax_router(u, lp["router"],
                                               cfg.num_experts_per_tok)
 
-    flags.note_kernel("block_diffusion_moe_dispatch", "sort_ragged_dot")
-    # On a TPU the grouped product is a kernel of its own that takes its
-    # operands as they come: they are cast to what XLA makes of a float32
-    # product under the ambient precision, as the stack's other products
-    # are (bfloat16; float32 under "highest"). Elsewhere XLA's own product.
+    # the looped form (8 static blocks of 2 L rows, 2048 wide, over 16
+    # experts' matrices would be 6 GB of the step at the published sizes)
+    # over grouped products that visit the live row tiles
+    flags.note_kernel(
+        "block_diffusion_moe_dispatch",
+        "sort_pallas_grouped" if mode["name"] == "pallas" else mode["name"])
+    # The products take their operands as they come: they are cast to
+    # what XLA makes of a float32 product under the ambient precision, as
+    # the stack's other products are (bfloat16; float32 under "highest").
+    # Elsewhere XLA's own product, and the interpreter's, at float32.
     mxu = ambient_mxu_dtype() if mode["name"] == "pallas" else jnp.float32
-    # the looped form: 8 static blocks of 2 L rows, 2048 wide, over 16
-    # experts' matrices would be 6 GB of the step at the published sizes
-    # in blocks of three times an even router's share: a layer's held load
-    # is 0.7 to 2.4 shares, and a trip the load only just needs costs a
-    # whole trip's gathers and sum (at two shares a layer now and then took
-    # a second trip and one run in six read 1% slow; measured, PERF.md)
-    share = -(-b * s * cfg.num_experts_per_tok * cfg.experts_held[1]
-              // cfg.router_experts)
     y, counts = moelib.dropless_dispatch(
-        u, idx, weights, cfg.experts_held,
-        functools.partial(_held_experts, mxu=mxu),
-        {n: lp[n] for n in ("w1", "w3", "w2")}, block_rows=3 * share)
+        u, idx, weights, cfg.experts_held, _held_experts(mode, mxu),
+        _packed(lp), block_rows=dispatch_block_rows(cfg, b * s))
     return h + y.reshape(b, s, d), counts
 
 
@@ -284,17 +347,22 @@ def _plan_for(cfg: BlockDiffusionConfig, mesh: Mesh, params, tokens):
         reserved_bytes=2 * logits + experts // 2, kept_cost=KEPT_COST)
 
 
-def plan_attributes(cfg: BlockDiffusionConfig, plan, seq: int) -> Dict:
+def plan_attributes(cfg: BlockDiffusionConfig, plan, seq: int,
+                    batch: int) -> Dict:
     """The plan as the ``block_diffusion/build_step`` span reports it
-    (``layers_kept`` counts pieces), with the mask's tiles a head where
-    the flash kernels run: grid / live / edge (``tile_counts``)."""
-    out = dict(plan.attributes("L" * cfg.pieces), block=cfg.block_length)
+    (``layers_kept`` counts pieces), with the expert dispatch's block for
+    ``batch`` sequences a device (``dispatch_block_rows``) and, where the
+    kernels run, the grouped products' row tile and the mask's tiles a
+    head in the flash kernels: grid / live / edge (``tile_counts``)."""
+    out = dict(plan.attributes("L" * cfg.pieces), block=cfg.block_length,
+               dispatch_block_rows=dispatch_block_rows(cfg, batch * 2 * seq))
     if flags.kernel_mode(cfg.kernels)["use_pallas"]:
         grid, live, edge = tile_counts(
             2 * seq, 2 * seq, int(flags.flag("flash_block_q")),
             int(flags.flag("flash_block_k")), False,
             mask=BlockDiffusionMask(seq, cfg.block_length))
-        out.update(tiles_grid=grid, tiles_live=live, tiles_edge=edge)
+        out.update(tiles_grid=grid, tiles_live=live, tiles_edge=edge,
+                   dispatch_row_tile=ROW_TILE)
     return out
 
 
@@ -379,15 +447,19 @@ def make_block_diffusion_train_step(cfg: BlockDiffusionConfig, mesh: Mesh,
     opt_state, loss, aux)`` with donation; ``aux`` as
     ``block_diffusion_loss_fn`` returns it. The
     ``block_diffusion/build_step`` span covers the tracing of the loss and
-    its gradient, once a compilation, and says what the layers keep and
-    which tiles the mask leaves (``plan_attributes``)."""
+    its gradient, once a compilation, and says what the layers keep, the
+    expert dispatch's block and which tiles the mask leaves
+    (``plan_attributes``)."""
     vg = jax.value_and_grad(block_diffusion_loss_fn(cfg, mesh, specs),
                             has_aux=True)
+
+    shards = math.prod(int(mesh.shape[a]) for a in _data_axes(mesh))
 
     def traced(params, tokens, levels, masked):
         plan = _plan_for(cfg, mesh, params, tokens)
         with trace.span("block_diffusion/build_step",
                         layers=cfg.num_hidden_layers,
-                        **plan_attributes(cfg, plan, tokens.shape[1])):
+                        **plan_attributes(cfg, plan, tokens.shape[1],
+                                          tokens.shape[0] // shards)):
             return vg(params, tokens, levels, masked)
     return make_train_step(traced, optimizer, has_aux=True)

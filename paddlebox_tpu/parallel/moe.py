@@ -188,66 +188,104 @@ class DispatchCounts(NamedTuple):
     dropped: jax.Array
 
 
-def _block_at(lo, k, rows, order, load):
-    """Block ``lo .. lo + rows`` of the sorted assignments: (their places
-    in the flat ``[T * k]`` assignments, their tokens, which of them are
-    held, rows per held expert)."""
+class _Block(NamedTuple):
+    """Block ``lo .. lo + rows`` of the sorted assignments."""
+    sel: jax.Array      # [rows] their places in the flat [t * k] assignments
+    tok: jax.Array      # [rows] their tokens
+    sizes: jax.Array    # [count] rows per held expert
+    held: jax.Array     # [] how many of the rows are held assignments
+    # places and tokens once more, for adding to: those of a row past the
+    # last held assignment lie past the end, where nothing is added. A
+    # grouped product leaves such rows unwritten (whatever the buffer
+    # held), forward and in its transposes: neither their values nor their
+    # cotangents may go anywhere.
+    to_sel: jax.Array
+    to_tok: jax.Array
+
+
+def _block_at(lo, k, rows, order, load, t) -> _Block:
     ends = jnp.cumsum(load)
     sel = lax.dynamic_slice(order, (lo,), (rows,))
-    live = ((lo + jnp.arange(rows)) < ends[-1])[:, None]
+    live = (lo + jnp.arange(rows)) < ends[-1]
     sizes = jnp.clip(ends - lo, 0, rows) - jnp.clip(ends - load - lo, 0, rows)
-    return sel, sel // k, live, sizes
+    return _Block(sel, sel // k, sizes, jnp.sum(live, dtype=jnp.int32),
+                  jnp.where(live, sel, t * k), jnp.where(live, sel // k, t))
 
 
 def _blocks_needed(load, rows):
     return (jnp.sum(load) + (rows - 1)) // rows
 
 
+class LoopedExperts(NamedTuple):
+    """The experts of ``dropless_dispatch``'s looped form, forward and
+    backward written out: the loop's backward pass sums the experts'
+    gradient where it is made, which ``jax.vjp`` of a ``rows_fn`` cannot
+    say (its gradient is a new array a trip, as large as the weights).
+
+    ``forward(params, rows [R, F], scale [R], sizes [count]) -> [R, F]``
+    float32: ``scale[r]`` times expert ``i`` applied to each of the
+    ``sizes[i]`` consecutive rows of its segment. ``backward(params, rows,
+    scale, sizes, dy [R, F], sums) -> (drows [R, F], dscale [R], sums')``:
+    the cotangents of ``rows`` and ``scale`` and ``sums`` (a pytree like
+    ``params``, float32) with this call's gradient of the experts' weights
+    added to it, in ``sums``' own buffers where it can be. Both may leave
+    anything in rows past the last segment and must let nothing of such
+    rows, of ``rows`` or ``dy``, reach a live row or ``sums'``. ``rows``
+    and ``dy`` arrive as ``operand``: the loop gathers them already cast.
+    ``add_rows(into [T, F], index [R], values [R, F]) -> into'`` adds each
+    trip's results to their tokens' rows (an index past the end adds
+    nothing): XLA's scatter-add unless the caller has a faster one.
+    """
+    forward: Callable[..., jax.Array]
+    backward: Callable[..., Tuple[jax.Array, jax.Array, Dict]]
+    operand: jnp.dtype = jnp.float32
+    add_rows: Callable[..., jax.Array] = (
+        lambda into, index, values: into.at[index].add(values, mode="drop"))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _looped_blocks(rows_fn, k, rows, u, flat_w, order, load, params):
+def _looped_blocks(experts, k, rows, u, flat_w, order, load, params):
     """``dropless_dispatch``'s blocks, ``rows`` assignments each, as a
     loop over the blocks the load needs: ``(out [T, F] float32 -> u's
     dtype, assignments served)``. ``order`` is padded to whole blocks.
-    Rows past the last held assignment are selected away on both sides,
-    as in the unrolled form."""
+    What a trip computes for rows past the last held assignment is added
+    nowhere, forward and backward (``_block_at``)."""
+    cast = u.astype(experts.operand)
+
     def block(i, acc):
         out, served = acc
-        sel, tok, live, sizes = _block_at(i * rows, k, rows, order, load)
-        y = rows_fn(params, jnp.where(live, u[tok], 0.0), sizes)
-        y = jnp.where(live, y, 0.0) * flat_w[sel][:, None]
-        return out.at[tok].add(y), served + jnp.sum(live, dtype=jnp.int32)
+        b = _block_at(i * rows, k, rows, order, load, u.shape[0])
+        y = experts.forward(params, cast[b.tok], flat_w[b.sel], b.sizes)
+        return experts.add_rows(out, b.to_tok, y), served + b.held
     out, served = lax.fori_loop(
         0, _blocks_needed(load, rows), block,
         (jnp.zeros(u.shape, jnp.float32), jnp.zeros((), jnp.int32)))
     return out.astype(u.dtype), served
 
 
-def _looped_blocks_fwd(rows_fn, k, rows, u, flat_w, order, load, params):
-    return (_looped_blocks(rows_fn, k, rows, u, flat_w, order, load, params),
+def _looped_blocks_fwd(experts, k, rows, u, flat_w, order, load, params):
+    return (_looped_blocks(experts, k, rows, u, flat_w, order, load, params),
             (u, flat_w, order, load, params))
 
 
-def _looped_blocks_bwd(rows_fn, k, rows, res, cotangents):
+def _looped_blocks_bwd(experts, k, rows, res, cotangents):
     u, flat_w, order, load, params = res
-    g = cotangents[0].astype(jnp.float32)
+    cast, g = u.astype(experts.operand), cotangents[0].astype(experts.operand)
 
     def block(i, acc):
-        du, dw, dparams = acc
-        sel, tok, live, sizes = _block_at(i * rows, k, rows, order, load)
-        y, back = jax.vjp(lambda p, x: rows_fn(p, x, sizes), params,
-                          jnp.where(live, u[tok], 0.0))
-        g_rows = jnp.where(live, g[tok], 0.0)
-        own, drows = back(g_rows * flat_w[sel][:, None])
-        return (du.at[tok].add(jnp.where(live, drows, 0.0)),
-                dw.at[sel].add(jnp.sum(
-                    g_rows * jnp.where(live, y, 0.0), axis=-1)),
-                jax.tree.map(jnp.add, dparams, own))
-    du, dw, dparams = lax.fori_loop(
+        du, dw, sums = acc
+        b = _block_at(i * rows, k, rows, order, load, u.shape[0])
+        drows, dscale, sums = experts.backward(
+            params, cast[b.tok], flat_w[b.sel], b.sizes, g[b.tok], sums)
+        return (experts.add_rows(du, b.to_tok, drows),
+                dw.at[b.to_sel].add(dscale, mode="drop"), sums)
+    du, dw, sums = lax.fori_loop(
         0, _blocks_needed(load, rows), block,
         (jnp.zeros(u.shape, jnp.float32), jnp.zeros(flat_w.shape,
                                                     jnp.float32),
-         jax.tree.map(jnp.zeros_like, params)))
-    return du.astype(u.dtype), dw.astype(flat_w.dtype), None, None, dparams
+         jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)))
+    return (du.astype(u.dtype), dw.astype(flat_w.dtype), None, None,
+            jax.tree.map(lambda s, p: s.astype(p.dtype), sums, params))
 
 
 _looped_blocks.defvjp(_looped_blocks_fwd, _looped_blocks_bwd)
@@ -275,16 +313,17 @@ def dropless_dispatch(u: jax.Array, idx: jax.Array, weights: jax.Array,
     whether it is served or not (0.7 GB a block for 16 experts of 2048 x
     768 x 3 and 8,192 rows).
 
-    With ``expert_params`` (the experts' stacked weights, a pytree) the
-    call is ``rows_fn(expert_params, rows, sizes)`` and the blocks are a
-    loop whose trip count is the blocks the load needs, forward and
-    backward (``_looped_blocks``): one block's intermediates and one
-    gradient, summed in place, whatever the static bound. The backward
-    pass computes a served block's forward again and keeps nothing of the
-    first. ``block_rows`` (default T) is the loop's block: a trip has its
-    own cost beside its rows' (their gathers, the sum of the experts'
-    gradient), so a caller that knows its share sizes the block to hold a
-    usual load in one trip.
+    With ``expert_params`` (the experts' stacked weights, a pytree)
+    ``rows_fn`` is a ``LoopedExperts`` and the blocks are a loop whose trip
+    count is the blocks the load needs, forward and backward
+    (``_looped_blocks``): one block's intermediates, and one gradient of
+    the experts' weights that every trip's ``backward`` adds to where it
+    is, whatever the static bound. The backward pass computes of a served
+    block's forward what it needs again and keeps nothing of the first.
+    ``block_rows`` (default T) is the loop's block. A trip gathers and
+    scatters all its rows, held or not, and its products visit the row
+    tiles that hold a held one (``ops/pallas_kernels/grouped_matmul.py``),
+    so a caller that knows its share sizes the block near it.
     """
     t, k = idx.shape
     first, count = held

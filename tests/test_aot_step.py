@@ -34,7 +34,8 @@ def _run_tool(name, timeout, *args):
 def test_pallas_kernels_aot_compile_for_tpu():
     """sorted_scatter, sorted_gather, flash_attention fwd+bwd (the three
     dense cells' shapes: equal heads of 64 and of 128, grouped heads),
-    ssd_scan fwd+bwd and seqpool_cvm at the shapes the benchmarks use."""
+    ssd_scan fwd+bwd, the block-diffusion stack's grouped products and
+    seqpool_cvm at the shapes the benchmarks use."""
     out = _run_tool("aot_check_kernels.py", 900)
     assert out.count("AOT sorted_scatter") == 3
     assert out.count("AOT sorted_gather") == 3
@@ -43,6 +44,8 @@ def test_pallas_kernels_aot_compile_for_tpu():
     assert "AOT flash_attention grouped fwd+bwd" in out
     assert out.count("AOT flash_attention block-diffusion fwd+bwd") == 2
     assert out.count("AOT ssd_scan fwd+bwd") == 2
+    assert out.count("AOT grouped_matmul fwd+transposed+weights") == 6
+    assert out.count("AOT scatter_add_rows") == 3
     assert "AOT seqpool_cvm" in out
     assert "PALLAS KERNELS TPU AOT COMPILE: OK" in out
 
@@ -132,6 +135,10 @@ def test_block_diffusion_cell_programs_fit_the_v5e():
     plan = out.split("blockdiff plan: ", 1)[1].splitlines()[0]
     assert '"layers_kept": "L:4"' in plan and "moe_logits" in plan, plan
     assert '"tiles_live": 80' in plan and '"tiles_edge": 24' in plan
+    # the expert dispatch's loop: blocks of one even router's share over
+    # grouped products in 128-row tiles
+    assert '"dispatch_block_rows": 8192' in plan, plan
+    assert '"dispatch_row_tile": 128' in plan, plan
     assert "AOT blockdiff step: " in out
     assert "AOT blockdiff setup gradient: " in out
     assert "AOT blockdiff step and setup gradient fit: OK" in out

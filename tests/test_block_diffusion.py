@@ -293,6 +293,10 @@ def test_train_step_lowers_the_loss_and_counts_what_it_served():
     built = spans["block_diffusion/build_step"]["args"]
     assert built["layers"] == 4 and built["block"] == 4
     assert "layers_kept" in built and "tiles_live" not in built   # xla path
+    # two sequences a device: 160 rows, 2 of 8 experts a row, 4 held, in
+    # whole row tiles of the grouped products
+    assert built["dispatch_block_rows"] == 256
+    assert "dispatch_row_tile" not in built
 
 
 def test_plan_counts_both_copies_rows_and_keeps_by_piece(monkeypatch):

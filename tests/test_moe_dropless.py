@@ -6,7 +6,9 @@ import numpy as np
 import pytest
 from jax import lax
 
-from paddlebox_tpu.parallel.moe import (dropless_dispatch,
+from paddlebox_tpu.core import flags
+from paddlebox_tpu.models.block_diffusion import _held_experts, _packed
+from paddlebox_tpu.parallel.moe import (LoopedExperts, dropless_dispatch,
                                         topk_sigmoid_router,
                                         topk_softmax_router)
 
@@ -56,9 +58,24 @@ def test_router_chooses_by_biased_score_and_weighs_by_score():
     assert float(jnp.abs(grad).max()) == 0.0
 
 
-def _expert_of(p, rows, sizes):
-    """``_expert`` with the weights handed in: the looped dispatch's form."""
-    return _expert(p["w1"], p["w2"])(rows, sizes)
+def _looped(rows_fn):
+    """``rows_fn(params, rows, sizes)`` as the looped dispatch's experts:
+    the backward pass from ``jax.vjp``, its gradient of the weights added
+    to the sums."""
+    def forward(p, rows, scale, sizes):
+        return rows_fn(p, rows, sizes) * scale[:, None]
+
+    def backward(p, rows, scale, sizes, dy, sums):
+        _, back = jax.vjp(lambda p, r, s: forward(p, r, s, sizes), p, rows,
+                          scale)
+        own, drows, dscale = back(dy)
+        return drows, dscale, jax.tree.map(jnp.add, sums, own)
+    return LoopedExperts(forward, backward)
+
+
+# ``_expert`` with the weights handed in: the looped dispatch's form
+_expert_of = _looped(lambda p, rows, sizes: _expert(p["w1"], p["w2"])(
+    rows, sizes))
 
 
 @pytest.mark.parametrize("shares,looped", [(1, False), (4, False),
@@ -101,36 +118,89 @@ def _swiglu_of(p, rows, sizes):
         rows, p["w3"], sizes), p["w2"], sizes)
 
 
-def test_eight_shares_of_the_softmax_swiglu_layer_add_up_to_the_uncut_one():
+def _uncut_swiglu(x, gate, w1, w3, w2):
+    """Softmax top-k routing, every gated expert applied to every token."""
+    idx, w = topk_softmax_router(x, gate, K)
+    hidden = (jax.nn.silu(jnp.einsum("tf,efi->tei", x, w1))
+              * jnp.einsum("tf,efi->tei", x, w3))
+    return jnp.einsum("tke,tef->tf", jax.nn.one_hot(idx, E) * w[..., None],
+                      jnp.einsum("tei,eif->tef", hidden, w2))
+
+
+# The gated experts three ways, gate and up-projection side by side as the
+# block-diffusion stack packs them: the backward pass from ``jax.vjp`` of
+# ``lax.ragged_dot``; the stack's written-out one over the XLA products;
+# the same over the Pallas kernels in the interpreter, whose row tiles
+# (128) the blocks must hold whole.
+SWIGLU = {
+    "vjp": (lambda: _looped(lambda p, rows, sizes: _swiglu_of(
+        {"w1": p["w13"][..., :INNER], "w3": p["w13"][..., INNER:],
+         "w2": p["w2"]}, rows, sizes)), 0),
+    "xla": (lambda: _held_experts(flags.kernel_mode("xla"), jnp.float32),
+            40),
+    "interpret": (lambda: _held_experts(flags.kernel_mode("interpret"),
+                                        jnp.float32), 128),
+}
+
+
+@pytest.mark.parametrize("experts", sorted(SWIGLU))
+def test_eight_shares_of_the_softmax_swiglu_layer_add_up_to_the_uncut_one(
+        experts):
     """The block-diffusion stack's expert layer: softmax top-k routing
     renormalised over the chosen, gated experts, the looped dispatch. The
     eight shares ``(0, 2) .. (14, 2)`` add up to every expert applied to
     every token, in value and in every gradient."""
+    make, block_rows = SWIGLU[experts]
     x, gate, w1, w2 = _layer(3)
     w3 = jax.random.normal(jax.random.PRNGKey(33), w1.shape) * 0.3
-
-    def uncut(x, gate, w1, w3, w2):
-        idx, w = topk_softmax_router(x, gate, K)
-        hidden = (jax.nn.silu(jnp.einsum("tf,efi->tei", x, w1))
-                  * jnp.einsum("tf,efi->tei", x, w3))
-        return jnp.einsum("tke,tef->tf",
-                          jax.nn.one_hot(idx, E) * w[..., None],
-                          jnp.einsum("tei,eif->tef", hidden, w2))
 
     def cut(x, gate, w1, w3, w2):
         idx, w = topk_softmax_router(x, gate, K)
         return sum(dropless_dispatch(
-            x, idx, w, (first, 2), _swiglu_of,
-            {"w1": w1[first:first + 2], "w3": w3[first:first + 2],
-             "w2": w2[first:first + 2]})[0] for first in range(0, E, 2))
+            x, idx, w, (first, 2), make(),
+            _packed({"w1": w1[first:first + 2], "w3": w3[first:first + 2],
+                     "w2": w2[first:first + 2]}), block_rows=block_rows)[0]
+            for first in range(0, E, 2))
     args = (x, gate, w1, w3, w2)
     with jax.default_matmul_precision("highest"):
         np.testing.assert_allclose(
-            np.asarray(jax.jit(cut)(*args)), np.asarray(uncut(*args)),
+            np.asarray(jax.jit(cut)(*args)),
+            np.asarray(_uncut_swiglu(*args)),
             rtol=1e-5, atol=1e-5)
-        want = jax.grad(lambda *a: jnp.sum(jnp.sin(uncut(*a))),
+        want = jax.grad(lambda *a: jnp.sum(jnp.sin(_uncut_swiglu(*a))),
                         argnums=tuple(range(5)))(*args)
         got = jax.jit(jax.grad(lambda *a: jnp.sum(jnp.sin(cut(*a))),
+                               argnums=tuple(range(5))))(*args)
+    for g, w in zip(got, want):
+        assert float(jnp.linalg.norm(g - w) / jnp.linalg.norm(w)) < 1e-5
+
+
+@pytest.mark.parametrize("experts", ["xla", "interpret"])
+def test_every_trip_of_the_loop_sums_into_one_gradient(experts):
+    """One device holding all 16 gated experts serves the ``T * K`` = 256
+    assignments in two trips of 128 rows (one row tile each in the
+    interpreter): both trips' gradients of the experts' weights land in
+    the one carried sum, and the layer is the uncut one."""
+    x, gate, w1, w2 = _layer(5)
+    w3 = jax.random.normal(jax.random.PRNGKey(55), w1.shape) * 0.3
+    make, _ = SWIGLU[experts]
+
+    def cut(x, gate, w1, w3, w2):
+        idx, w = topk_softmax_router(x, gate, K)
+        out, counts = dropless_dispatch(
+            x, idx, w, (0, E), make(),
+            _packed({"w1": w1, "w3": w3, "w2": w2}), block_rows=128)
+        return out, counts
+    args = (x, gate, w1, w3, w2)
+    with jax.default_matmul_precision("highest"):
+        out, counts = jax.jit(cut)(*args)
+        assert int(counts.load.sum()) == T * K and int(counts.dropped) == 0
+        np.testing.assert_allclose(np.asarray(out),
+                                   np.asarray(_uncut_swiglu(*args)),
+                                   rtol=1e-5, atol=1e-5)
+        want = jax.grad(lambda *a: jnp.sum(jnp.sin(_uncut_swiglu(*a))),
+                        argnums=tuple(range(5)))(*args)
+        got = jax.jit(jax.grad(lambda *a: jnp.sum(jnp.sin(cut(*a)[0])),
                                argnums=tuple(range(5))))(*args)
     for g, w in zip(got, want):
         assert float(jnp.linalg.norm(g - w) / jnp.linalg.norm(w)) < 1e-5

@@ -258,7 +258,8 @@ def check_blockdiff(device) -> None:
         "blockdiff", device, config, seq,
         lambda key: bd.init_block_diffusion(key, cfg),
         lambda mesh, params, tok: bd.plan_attributes(
-            cfg, bd._plan_for(cfg, mesh, params, tok), seq), programs)
+            cfg, bd._plan_for(cfg, mesh, params, tok), seq, tok.shape[0]),
+        programs)
 
 
 def main() -> None:

@@ -50,6 +50,8 @@ GATHER_SHAPES = [
 def main() -> None:
     from paddlebox_tpu.ops.pallas_kernels.flash_attention import (
         BlockDiffusionMask, flash_attention)
+    from paddlebox_tpu.ops.pallas_kernels.grouped_matmul import (
+        grouped_matmul, grouped_weight_grad, scatter_add_rows)
     from paddlebox_tpu.ops.pallas_kernels.seqpool_cvm import (
         seqpool_cvm_pallas)
     from paddlebox_tpu.ops.pallas_kernels.sorted_gather import sorted_gather
@@ -133,6 +135,38 @@ def main() -> None:
             argnums=tuple(range(6)))).lower(*scan_args).compile()
         print(f"AOT ssd_scan fwd+bwd [1, 8192, 128, 64] "
               f"{jnp.dtype(mxu).name}: OK", flush=True)
+
+    # The block-diffusion stack's expert products at published widths (16
+    # held experts; gate and up-projection side by side, 2048 -> 1536, and
+    # 768 -> 2048 out) over the rows a trip of the dispatch's loop may
+    # hold, at both operand precisions: the rows' product and its
+    # transposed-weight twin, the weights' gradient summed into its
+    # argument's buffer, and a trip's rows added to their tokens' (the
+    # cell's 8,192 rows of 2048).
+    sizes = sds((16,), jnp.int32)
+    for rows in (8192, 16384, 24576):
+        for mxu in (jnp.bfloat16, jnp.float32):
+            for k, n in ((2048, 1536), (768, 2048)):
+                for transpose_w in (False, True):
+                    w = sds((16, n, k) if transpose_w else (16, k, n), mxu)
+                    jax.jit(lambda x, w, s: grouped_matmul(
+                        x, w, s, transpose_w=transpose_w,
+                        use_pallas=True)).lower(
+                        sds((rows, k), mxu), w, sizes).compile()
+                jax.jit(lambda x, dy, s, into: grouped_weight_grad(
+                    x, dy, s, into, use_pallas=True),
+                    donate_argnums=3).lower(
+                    sds((rows, k), mxu), sds((rows, n), mxu), sizes,
+                    sds((16, k, n), jnp.float32)).compile()
+            print(f"AOT grouped_matmul fwd+transposed+weights [{rows}, "
+                  f"2048 <-> 1536 | 768] x 16 {jnp.dtype(mxu).name}: OK",
+                  flush=True)
+        jax.jit(lambda into, index, values: scatter_add_rows(
+            into, index, values, use_pallas=True), donate_argnums=0).lower(
+            sds((8192, 2048), jnp.float32), sds((rows,), jnp.int32),
+            sds((rows, 2048), jnp.float32)).compile()
+        print(f"AOT scatter_add_rows [{rows}, 2048] -> [8192, 2048]: OK",
+              flush=True)
 
     n, d, rows = 65536, 16, 16384
     sc = sds((n,), jnp.float32)
