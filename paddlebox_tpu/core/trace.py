@@ -39,6 +39,14 @@ Usage::
     with trace.span("pull", k=4):
         ...
     trace.export()           # or automatic at process exit
+
+The device half (end of this module): the dense train steps open
+``jax.named_scope`` s of one vocabulary (``SCOPE_TOPS``, ``SCOPE_PARTS``),
+which XLA keeps as each compiled instruction's ``op_name``. A step compiled
+through ``models/train_step.make_train_step`` is recorded
+(``record_program``: a reference, nothing parsed), and
+``device_scope_table`` lays a device trace's per-instruction times on the
+scopes and phases of the recorded program that ran.
 """
 
 from __future__ import annotations
@@ -47,10 +55,12 @@ import atexit
 import itertools
 import json
 import os
+import re
 import threading
 import time
 from collections import deque
-from typing import Any, Callable, Dict, List, Optional
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
+                    Sequence, Tuple)
 
 from paddlebox_tpu.core import flags
 
@@ -408,3 +418,227 @@ def stall_forensics(max_events: int = 256) -> Dict[str, Any]:
         except Exception as e:  # noqa: BLE001 - forensics must never raise
             out[name] = f"<provider failed: {e!r}>"
     return out
+
+
+# -- the device half: compiled steps read by scope ----------------------------
+
+# The named scopes of a dense train step (``jax.named_scope``). Top level:
+# the token embedding, the layers, the final norm / head / loss, and the
+# optimizer's update and apply. Inside ``stack`` one part a layer part;
+# time under ``stack`` in no part is the stack's own work (scan carries,
+# stacking of kept values, gradient sums between passes).
+SCOPE_TOPS = ("embed", "stack", "head", "optimizer")
+SCOPE_PARTS = ("attention", "mlp", "moe", "mamba")
+# operations that only hold others: their time is their children's
+CONTAINERS = frozenset(("while", "conditional", "call"))
+
+_TRANSFORM = re.compile(r"^(?:jvp|transpose|vmap)\((.*)\)$")
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([^\s=]+) = (.*)$")
+_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="((?:[^"\\]|\\.)*)"')
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([^\s(]+)\s*\(.*\{\s*$")
+_CALLED = re.compile(r"\b(?:calls|body|condition|to_apply|true_computation"
+                     r"|false_computation)=%?([\w.\-]+)")
+_CALLED_SET = re.compile(
+    r"\b(?:branch_computations|called_computations)=\{([^}]*)\}")
+_OPERAND = re.compile(r"%([\w.\-]+)")
+
+Scope = Tuple[Optional[str], Optional[str], str]    # (top, part, phase)
+
+_PROGRAMS: deque = deque(maxlen=8)      # compiled steps, newest last
+# id of a recorded program -> (its scopes, the seconds reading them took)
+_PARSED: Dict[int, Tuple[Dict[str, Tuple[str, Scope]], float]] = {}
+
+
+def record_program(compiled: Any) -> None:
+    """Keep a compiled step (``jax.stages.Compiled``) for
+    ``device_scope_table``: a reference to code that holds no device
+    buffers. Nothing is read from it until a table is asked for."""
+    _PROGRAMS.append(compiled)
+
+
+def scope_of(op_name: str) -> Scope:
+    """An instruction's ``op_name`` -> ``(top, part, phase)``: the first
+    segment of its name stack, transforms (``jvp(...)``, ``transpose(...)``,
+    ``vmap(...)``) taken off, that is one of ``SCOPE_TOPS`` (None: no
+    top-level scope), the first segment after it that is one of
+    ``SCOPE_PARTS`` (None: the top's own work), and the phase JAX's own
+    marks give: ``recompute`` under ``rematted_computation``, else
+    ``backward`` under a ``transpose(``, else ``forward``."""
+    top = part = None
+    for seg in op_name.split("/"):
+        m = _TRANSFORM.match(seg)
+        while m:
+            seg = m.group(1)
+            m = _TRANSFORM.match(seg)
+        if top is None:
+            if seg in SCOPE_TOPS:
+                top = seg
+        elif seg in SCOPE_PARTS:
+            part = seg
+            break
+    if "rematted_computation" in op_name:
+        phase = "recompute"
+    elif "transpose(" in op_name:
+        phase = "backward"
+    else:
+        phase = "forward"
+    return top, part, phase
+
+
+def _closing(text: str, start: int) -> int:
+    """Index just past the parenthesis that closes the one at ``start``."""
+    depth = 0
+    for i in range(start, len(text)):
+        depth += (text[i] == "(") - (text[i] == ")")
+        if depth == 0:
+            return i + 1
+    return len(text)
+
+
+def _opcode(rest: str) -> Tuple[str, str]:
+    """``shape opcode(operands), ...`` -> (opcode, operands); a tuple
+    shape is skipped to its closing parenthesis."""
+    rest = rest.lstrip()
+    if rest.startswith("("):
+        rest = rest[_closing(rest, 0):]
+    else:
+        rest = rest.partition(" ")[2]
+    rest = rest.lstrip()
+    opcode, paren, _ = rest.partition("(")
+    if not paren:
+        return opcode.strip(), ""
+    return opcode.strip(), rest[len(opcode):_closing(rest, len(opcode))]
+
+
+def program_scopes(text: str) -> Dict[str, Tuple[str, Scope]]:
+    """A compiled module's text (``Compiled.as_text()``) -> ``{instruction:
+    (opcode, (top, part, phase))}`` for every instruction it holds, those
+    of fused computations too: HLO names are unique within a module,
+    inside loop bodies as well, and a device trace names an operation by
+    its instruction.
+
+    An instruction whose ``op_name`` names no top-level scope (one XLA
+    made: a cast of stacked weights for the MXU hoisted out of its loop,
+    a copy or prefetch at a loop's edge; or one JAX built outside the
+    scope it serves, as a scan's partial evaluation does) takes the scope
+    of the root of the computation it fuses, else of the first of its
+    users that has one, else of the instruction that runs its
+    computation. What is left has none: it is unscoped."""
+    own: Dict[str, Scope] = {}
+    opcode: Dict[str, str] = {}
+    home: Dict[str, str] = {}           # instruction -> its computation
+    root: Dict[str, str] = {}           # computation -> its ROOT
+    caller: Dict[str, str] = {}         # computation -> who runs it
+    fuses: Dict[str, str] = {}          # fusion -> computation it fuses
+    users: Dict[str, List[str]] = {}
+    computation = ""
+    for line in text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            computation = head.group(1)
+            continue
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            continue
+        name, rest = m.groups()
+        meta = _OP_NAME.search(rest)
+        own[name] = scope_of(meta.group(1) if meta else "")
+        opcode[name], operands = _opcode(rest)
+        for operand in _OPERAND.findall(operands):
+            users.setdefault(operand, []).append(name)
+        home[name] = computation
+        if line.lstrip().startswith("ROOT "):
+            root[computation] = name
+        called = _CALLED.findall(rest)
+        for group in _CALLED_SET.findall(rest):
+            called += [c.strip().lstrip("%") for c in group.split(",")]
+        for c in called:
+            caller.setdefault(c, name)
+        if opcode[name] == "fusion" and called:
+            fuses[name] = called[0]
+
+    resolved: Dict[str, Scope] = {}
+
+    def scope(name: str, depth: int = 0) -> Scope:
+        if own[name][0] is not None or depth > 256:
+            return own[name]
+        if name not in resolved:
+            resolved[name] = own[name]          # a cycle ends here
+            inner = root.get(fuses.get(name, ""))
+            found = [own[inner]] if inner and own[inner][0] else []
+            for user in ([] if found else users.get(name, ())):
+                got = scope(user, depth + 1)
+                if got[0] is not None:
+                    found = [got]
+                    break
+            up = caller.get(home[name])
+            if not found and up:
+                found = [scope(up, depth + 1)]
+            resolved[name] = found[0] if found else own[name]
+        return resolved[name]
+    return {name: (opcode[name], scope(name)) for name in own}
+
+
+class ScopeTable(NamedTuple):
+    """A traced window's leaf time on the scopes of the step that ran."""
+    # (top, part, phase) -> (seconds, calls) of the step's instructions;
+    # top None: no top-level scope
+    rows: Dict[Scope, Tuple[float, float]]
+    # leaf time of instructions the step does not hold (other programs)
+    elsewhere: Tuple[float, float]
+    parse_s: float      # what reading the step's text took (once a process)
+
+    def seconds(self, **where: Optional[str]) -> float:
+        """Leaf seconds of the rows whose ``top``, ``part`` and ``phase``
+        equal those given (a key left out matches any)."""
+        keys = ("top", "part", "phase")
+        return sum(v[0] for k, v in self.rows.items()
+                   if all(k[keys.index(n)] == want
+                          for n, want in where.items()))
+
+
+def device_scope_table(op_seconds: Dict[str, Sequence[float]]
+                       ) -> Optional[ScopeTable]:
+    """``op_seconds``: a device trace's ``{"name (opcode)": (seconds,
+    calls)}`` (``benchmarks/trace/reduce.py``'s ``ops``) -> its leaf time
+    by the scopes of the recorded program whose instructions cover the most
+    of it: the step, where the step is the one program of the window that
+    matters. Containers (``CONTAINERS``) are left out: their children are
+    what they cost. None where no program was recorded or none ran."""
+    live = {id(c) for c in _PROGRAMS}
+    for stale in set(_PARSED) - live:
+        del _PARSED[stale]
+    for compiled in _PROGRAMS:
+        if id(compiled) not in _PARSED:
+            t0 = time.perf_counter()
+            scopes = program_scopes(compiled.as_text())
+            _PARSED[id(compiled)] = (scopes, time.perf_counter() - t0)
+    leaves = []
+    for key, (seconds, calls) in op_seconds.items():
+        name, opcode = key, ""
+        if key.endswith(")") and " (" in key:
+            name, opcode = key[:-1].rsplit(" (", 1)
+        if opcode not in CONTAINERS:
+            leaves.append((name, opcode, float(seconds), float(calls)))
+
+    def held(scopes, name, opcode):
+        got = scopes.get(name)
+        return (got is not None and got[0] not in CONTAINERS
+                and (not opcode or got[0] == opcode))
+    best, parse_s, covered = None, 0.0, 0.0
+    for compiled in _PROGRAMS:
+        scopes, seconds = _PARSED[id(compiled)]
+        cover = sum(s for n, o, s, _ in leaves if held(scopes, n, o))
+        if cover > covered:
+            best, parse_s, covered = scopes, seconds, cover
+    if best is None:
+        return None
+    rows: Dict[Scope, List[float]] = {}
+    elsewhere = [0.0, 0.0]
+    for name, opcode, seconds, calls in leaves:
+        row = (rows.setdefault(best[name][1], [0.0, 0.0])
+               if held(best, name, opcode) else elsewhere)
+        row[0] += seconds
+        row[1] += calls
+    return ScopeTable({k: (v[0], v[1]) for k, v in rows.items()},
+                      (elsewhere[0], elsewhere[1]), parse_s)

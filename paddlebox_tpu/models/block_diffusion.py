@@ -257,38 +257,44 @@ def _layer(lp, h, cfg: BlockDiffusionConfig, rule: BlockDiffusionMask,
     b, s, d = h.shape
     hd, eps = cfg.head_dim, cfg.rms_norm_eps
     hq, hkv = cfg.num_attention_heads, cfg.num_key_value_heads
-    x = _rms(h, lp["n1"], eps)
-    q = rotary_embedding(
-        _rms(_dot(x, lp["wq"]).reshape(b, s, hq, hd), lp["gq"], eps),
-        positions, cfg.rope_theta)
-    k = rotary_embedding(
-        _rms(_dot(x, lp["wk"]).reshape(b, s, hkv, hd), lp["gk"], eps),
-        positions, cfg.rope_theta)
-    v = _dot(x, lp["wv"]).reshape(b, s, hkv, hd)
     mode = flags.kernel_mode(cfg.kernels)
-    flags.note_kernel("block_diffusion_attention", mode["name"])
-    attn = flash_attention(q, k, v, mask=rule, use_pallas=mode["use_pallas"],
-                           interpret=mode["interpret"])
-    h = h + _dot(attn.reshape(b, s, -1), lp["wo"])
-    u = _rms(h, lp["n2"], eps).reshape(b * s, d)
-    idx, weights = moelib.topk_softmax_router(u, lp["router"],
-                                              cfg.num_experts_per_tok)
+    with jax.named_scope("attention"):
+        x = _rms(h, lp["n1"], eps)
+        q = rotary_embedding(
+            _rms(_dot(x, lp["wq"]).reshape(b, s, hq, hd), lp["gq"], eps),
+            positions, cfg.rope_theta)
+        k = rotary_embedding(
+            _rms(_dot(x, lp["wk"]).reshape(b, s, hkv, hd), lp["gk"], eps),
+            positions, cfg.rope_theta)
+        v = _dot(x, lp["wv"]).reshape(b, s, hkv, hd)
+        flags.note_kernel("block_diffusion_attention", mode["name"])
+        attn = flash_attention(q, k, v, mask=rule,
+                               use_pallas=mode["use_pallas"],
+                               interpret=mode["interpret"])
+        h = h + _dot(attn.reshape(b, s, -1), lp["wo"])
+    with jax.named_scope("moe"):
+        u = _rms(h, lp["n2"], eps).reshape(b * s, d)
+        idx, weights = moelib.topk_softmax_router(u, lp["router"],
+                                                  cfg.num_experts_per_tok)
 
-    # the looped form (8 static blocks of 2 L rows, 2048 wide, over 16
-    # experts' matrices would be 6 GB of the step at the published sizes)
-    # over grouped products that visit the live row tiles
-    flags.note_kernel(
-        "block_diffusion_moe_dispatch",
-        "sort_pallas_grouped" if mode["name"] == "pallas" else mode["name"])
-    # The products take their operands as they come: they are cast to
-    # what XLA makes of a float32 product under the ambient precision, as
-    # the stack's other products are (bfloat16; float32 under "highest").
-    # Elsewhere XLA's own product, and the interpreter's, at float32.
-    mxu = ambient_mxu_dtype() if mode["name"] == "pallas" else jnp.float32
-    y, counts = moelib.dropless_dispatch(
-        u, idx, weights, cfg.experts_held, _held_experts(mode, mxu),
-        _packed(lp), block_rows=dispatch_block_rows(cfg, b * s))
-    return h + y.reshape(b, s, d), counts
+        # the looped form (8 static blocks of 2 L rows, 2048 wide, over 16
+        # experts' matrices would be 6 GB of the step at the published
+        # sizes) over grouped products that visit the live row tiles
+        flags.note_kernel(
+            "block_diffusion_moe_dispatch",
+            "sort_pallas_grouped" if mode["name"] == "pallas"
+            else mode["name"])
+        # The products take their operands as they come: they are cast to
+        # what XLA makes of a float32 product under the ambient precision,
+        # as the stack's other products are (bfloat16; float32 under
+        # "highest"). Elsewhere XLA's own product, and the interpreter's,
+        # at float32.
+        mxu = (ambient_mxu_dtype() if mode["name"] == "pallas"
+               else jnp.float32)
+        y, counts = moelib.dropless_dispatch(
+            u, idx, weights, cfg.experts_held, _held_experts(mode, mxu),
+            _packed(lp), block_rows=dispatch_block_rows(cfg, b * s))
+        return h + y.reshape(b, s, d), counts
 
 
 # -- what a layer keeps for its backward pass --------------------------------
@@ -403,30 +409,35 @@ def block_diffusion_loss_fn(cfg: BlockDiffusionConfig, mesh: Mesh,
     def body(plan, params, tokens, levels, masked):
         seq, block = tokens.shape[1], cfg.block_length
         rule = BlockDiffusionMask(seq, block)
-        rows = jnp.concatenate(
-            [tokens, jnp.where(masked, cfg.mask_token_id, tokens)], axis=1)
-        h = tplib.vocab_parallel_embedding(
-            {"table": params["embed"]}, rows, axis="mp")
-        positions = jnp.tile(jnp.arange(seq), 2)
+        with jax.named_scope("embed"):
+            rows = jnp.concatenate(
+                [tokens, jnp.where(masked, cfg.mask_token_id, tokens)],
+                axis=1)
+            h = tplib.vocab_parallel_embedding(
+                {"table": params["embed"]}, rows, axis="mp")
         served = []
-        for keep, piece in zip(plan.names, params["layers"]):
-            h, counts = scan_layers(keep, rule, positions)(piece, h)
-            served.append(counts)
-        logits = _dot(_rms(h[:, seq:], params["norm_f"], cfg.rms_norm_eps),
-                      params["head"])
-        ce = tplib.parallel_cross_entropy(logits, tokens, axis="mp")
-        weight = jnp.where(masked, 1.0 / jnp.repeat(levels, block, axis=1),
-                           0.0)
-        count = lax.psum(jnp.asarray(tokens.size, jnp.float32), daxes)
-        aux = {
-            "load": lax.psum(jnp.concatenate([c.load for c in served]),
-                             daxes),
-            "dropped": lax.psum(jnp.concatenate(
-                [c.dropped for c in served]), daxes),
-            "masked": lax.psum(jnp.sum(masked, dtype=jnp.int32), daxes),
-            "weight": lax.psum(jnp.sum(weight), daxes),
-        }
-        return lax.psum(jnp.sum(weight * ce), daxes) / count, aux
+        with jax.named_scope("stack"):
+            positions = jnp.tile(jnp.arange(seq), 2)
+            for keep, piece in zip(plan.names, params["layers"]):
+                h, counts = scan_layers(keep, rule, positions)(piece, h)
+                served.append(counts)
+        with jax.named_scope("head"):
+            logits = _dot(_rms(h[:, seq:], params["norm_f"],
+                               cfg.rms_norm_eps), params["head"])
+            ce = tplib.parallel_cross_entropy(logits, tokens, axis="mp")
+            weight = jnp.where(
+                masked, 1.0 / jnp.repeat(levels, block, axis=1), 0.0)
+            count = lax.psum(jnp.asarray(tokens.size, jnp.float32), daxes)
+            aux = {
+                "load": lax.psum(jnp.concatenate(
+                    [c.load for c in served]), daxes),
+                "dropped": lax.psum(jnp.concatenate(
+                    [c.dropped for c in served]), daxes),
+                "masked": lax.psum(jnp.sum(masked, dtype=jnp.int32),
+                                   daxes),
+                "weight": lax.psum(jnp.sum(weight), daxes),
+            }
+            return lax.psum(jnp.sum(weight * ce), daxes) / count, aux
 
     def loss(params, tokens, levels, masked):
         if tokens.shape[1] % cfg.block_length:

@@ -136,40 +136,42 @@ def _block(p, x, cfg: GPTConfig, heads_local: int):
     b, s, d = x.shape
     in_dtype = x.dtype
     hd = cfg.d_model // cfg.n_heads
-    h = _ln(x, p["ln1_g"], p["ln1_b"])
-    qkv = jnp.dot(h, p["wqkv"], preferred_element_type=jnp.float32)
-    qkv = qkv.reshape(b, s, heads_local, 3, hd)
-    q, k, v = qkv[:, :, :, 0], qkv[:, :, :, 1], qkv[:, :, :, 2]
-    sp_n = lax.axis_size("sp")
-    if cfg.attention not in ("auto", "ring", "flash"):
-        raise ValueError(f"unknown attention mode {cfg.attention!r}; "
-                         "choose from 'auto', 'ring', 'flash'")
-    if cfg.attention == "flash" and sp_n > 1:
-        # The flash kernel sees only the local K/V shard; with a sharded
-        # sequence only ring attention is exact.
-        raise ValueError("attention='flash' requires sp axis size 1; "
-                         "use 'ring' or 'auto' with a sharded sequence")
-    use_flash = cfg.attention == "flash" or (
-        cfg.attention == "auto" and sp_n == 1
-        and jax.default_backend() == "tpu")
-    flags.note_kernel("gpt_attention", "flash" if use_flash else "ring")
-    if use_flash:
-        from paddlebox_tpu.ops.pallas_kernels import flash_attention
-        attn = flash_attention(q, k, v, causal=True)
-    else:
-        attn = splib.ring_attention(q, k, v, axis="sp", causal=True)
-    attn = attn.reshape(b, s, heads_local * hd)
-    o = jnp.dot(attn, p["wo"], preferred_element_type=jnp.float32)
-    o = lax.psum(o, "mp")                       # row-parallel combine
-    x = x + o
-    h2 = _ln(x, p["ln2_g"], p["ln2_b"])
-    u = jnp.dot(h2, p["wi"], preferred_element_type=jnp.float32) + p["bi"]
-    u = jax.nn.gelu(u)
-    y = jnp.dot(u, p["wo2"], preferred_element_type=jnp.float32)
-    y = lax.psum(y, "mp") + p["bo2"]
-    # Residual stream stays in the input dtype (bf16-safe scan carry);
-    # note x is rebound above, so use the dtype captured at entry.
-    return (x + y).astype(in_dtype)
+    with jax.named_scope("attention"):
+        h = _ln(x, p["ln1_g"], p["ln1_b"])
+        qkv = jnp.dot(h, p["wqkv"], preferred_element_type=jnp.float32)
+        qkv = qkv.reshape(b, s, heads_local, 3, hd)
+        q, k, v = qkv[:, :, :, 0], qkv[:, :, :, 1], qkv[:, :, :, 2]
+        sp_n = lax.axis_size("sp")
+        if cfg.attention not in ("auto", "ring", "flash"):
+            raise ValueError(f"unknown attention mode {cfg.attention!r}; "
+                             "choose from 'auto', 'ring', 'flash'")
+        if cfg.attention == "flash" and sp_n > 1:
+            # The flash kernel sees only the local K/V shard; with a
+            # sharded sequence only ring attention is exact.
+            raise ValueError("attention='flash' requires sp axis size 1; "
+                             "use 'ring' or 'auto' with a sharded sequence")
+        use_flash = cfg.attention == "flash" or (
+            cfg.attention == "auto" and sp_n == 1
+            and jax.default_backend() == "tpu")
+        flags.note_kernel("gpt_attention", "flash" if use_flash else "ring")
+        if use_flash:
+            from paddlebox_tpu.ops.pallas_kernels import flash_attention
+            attn = flash_attention(q, k, v, causal=True)
+        else:
+            attn = splib.ring_attention(q, k, v, axis="sp", causal=True)
+        attn = attn.reshape(b, s, heads_local * hd)
+        o = jnp.dot(attn, p["wo"], preferred_element_type=jnp.float32)
+        o = lax.psum(o, "mp")                       # row-parallel combine
+        x = x + o
+    with jax.named_scope("mlp"):
+        h2 = _ln(x, p["ln2_g"], p["ln2_b"])
+        u = jnp.dot(h2, p["wi"], preferred_element_type=jnp.float32) + p["bi"]
+        u = jax.nn.gelu(u)
+        y = jnp.dot(u, p["wo2"], preferred_element_type=jnp.float32)
+        y = lax.psum(y, "mp") + p["bo2"]
+        # Residual stream stays in the input dtype (bf16-safe scan carry);
+        # note x is rebound above, so use the dtype captured at entry.
+        return (x + y).astype(in_dtype)
 
 
 def _data_axes(mesh: Mesh) -> tuple:
@@ -199,31 +201,35 @@ def gpt_loss_fn(cfg: GPTConfig, mesh: Mesh, specs: Dict, *,
 
     def body(params, tokens, targets):
         # tokens local [B_local, S_local]
-        x = tplib.vocab_parallel_embedding(
-            {"table": params["embed"]}, tokens, axis="mp")
-        rank_sp = lax.axis_index("sp")
         s_local = tokens.shape[1]
-        pos_ids = rank_sp * s_local + jnp.arange(s_local)
-        x = x + params["pos"][pos_ids][None, :, :]
+        with jax.named_scope("embed"):
+            x = tplib.vocab_parallel_embedding(
+                {"table": params["embed"]}, tokens, axis="mp")
+            rank_sp = lax.axis_index("sp")
+            pos_ids = rank_sp * s_local + jnp.arange(s_local)
+            x = x + params["pos"][pos_ids][None, :, :]
 
-        # Microbatch the local batch for the pipeline.
-        bl = x.shape[0]
-        m = num_microbatches
-        x_mb = x.reshape(m, bl // m, s_local, cfg.d_model)
-        stage_params_local = jax.tree.map(lambda a: a[0], params["layers"])
-        h_mb = pplib.gpipe_apply(stage_fn, stage_params_local, x_mb,
-                                 axis="pp")
-        h = h_mb.reshape(bl, s_local, cfg.d_model)
+        with jax.named_scope("stack"):
+            # Microbatch the local batch for the pipeline.
+            bl = x.shape[0]
+            m = num_microbatches
+            x_mb = x.reshape(m, bl // m, s_local, cfg.d_model)
+            stage_params_local = jax.tree.map(lambda a: a[0],
+                                              params["layers"])
+            h_mb = pplib.gpipe_apply(stage_fn, stage_params_local, x_mb,
+                                     axis="pp")
+            h = h_mb.reshape(bl, s_local, cfg.d_model)
 
-        h = _ln(h, params["lnf_g"], params["lnf_b"])
-        logits_local = jnp.dot(h, params["head"],
-                               preferred_element_type=jnp.float32)
-        losses = tplib.parallel_cross_entropy(logits_local, targets,
-                                              axis="mp")
-        # Global mean over all tokens (replica × sp shards).
-        total = lax.psum(jnp.sum(losses), raxes)
-        count = lax.psum(jnp.asarray(losses.size, jnp.float32), raxes)
-        return total / count
+        with jax.named_scope("head"):
+            h = _ln(h, params["lnf_g"], params["lnf_b"])
+            logits_local = jnp.dot(h, params["head"],
+                                   preferred_element_type=jnp.float32)
+            losses = tplib.parallel_cross_entropy(logits_local, targets,
+                                                  axis="mp")
+            # Global mean over all tokens (replica × sp shards).
+            total = lax.psum(jnp.sum(losses), raxes)
+            count = lax.psum(jnp.asarray(losses.size, jnp.float32), raxes)
+            return total / count
 
     in_specs = (specs, P(daxes, "sp"), P(daxes, "sp"))
     return jax.shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=P(),

@@ -167,26 +167,28 @@ def rotary_embedding(x, positions, theta: float):
 def _layer(lp, h, cfg: LoopedConfig):
     b, s, _ = h.shape
     hd, eps = cfg.head_dim, cfg.rms_norm_eps
-    x = _rms(h, lp["n1"], eps)
-    positions = jnp.arange(s)
-    q = rotary_embedding(
-        _dot(x, lp["wq"]).reshape(b, s, cfg.num_attention_heads, hd),
-        positions, cfg.rope_theta)
-    k = rotary_embedding(
-        _dot(x, lp["wk"]).reshape(b, s, cfg.num_key_value_heads, hd),
-        positions, cfg.rope_theta)
-    v = _dot(x, lp["wv"]).reshape(b, s, cfg.num_key_value_heads, hd)
-    mode = flags.kernel_mode(cfg.kernels)
-    flags.note_kernel("looped_attention", mode["name"])
-    attn = flash_attention(q, k, v, causal=True,
-                           use_pallas=mode["use_pallas"],
-                           interpret=mode["interpret"])
-    a = h + _rms(_dot(attn.reshape(b, s, -1), lp["wo"]), lp["n2"], eps)
-    x = _rms(a, lp["n3"], eps)
-    gate = checkpoint_name(_dot(x, lp["w_gate"]), "looped_gate")
-    up = checkpoint_name(_dot(x, lp["w_up"]), "looped_up")
-    return a + _rms(_dot(jax.nn.silu(gate) * up, lp["w_down"]), lp["n4"],
-                    eps)
+    with jax.named_scope("attention"):
+        x = _rms(h, lp["n1"], eps)
+        positions = jnp.arange(s)
+        q = rotary_embedding(
+            _dot(x, lp["wq"]).reshape(b, s, cfg.num_attention_heads, hd),
+            positions, cfg.rope_theta)
+        k = rotary_embedding(
+            _dot(x, lp["wk"]).reshape(b, s, cfg.num_key_value_heads, hd),
+            positions, cfg.rope_theta)
+        v = _dot(x, lp["wv"]).reshape(b, s, cfg.num_key_value_heads, hd)
+        mode = flags.kernel_mode(cfg.kernels)
+        flags.note_kernel("looped_attention", mode["name"])
+        attn = flash_attention(q, k, v, causal=True,
+                               use_pallas=mode["use_pallas"],
+                               interpret=mode["interpret"])
+        a = h + _rms(_dot(attn.reshape(b, s, -1), lp["wo"]), lp["n2"], eps)
+    with jax.named_scope("mlp"):
+        x = _rms(a, lp["n3"], eps)
+        gate = checkpoint_name(_dot(x, lp["w_gate"]), "looped_gate")
+        up = checkpoint_name(_dot(x, lp["w_up"]), "looped_up")
+        return a + _rms(_dot(jax.nn.silu(gate) * up, lp["w_down"]),
+                        lp["n4"], eps)
 
 
 # -- what an application keeps for its backward pass -------------------------
@@ -340,34 +342,39 @@ def looped_loss_fn(cfg: LoopedConfig, mesh: Mesh, specs: Dict):
                 + read["gate_b"]), None
 
     def body(plan, params, tokens, targets):
-        h = tplib.vocab_parallel_embedding(
-            {"table": params["embed"]}, tokens, axis="mp")
+        with jax.named_scope("embed"):
+            h = tplib.vocab_parallel_embedding(
+                {"table": params["embed"]}, tokens, axis="mp")
         count = jnp.zeros((), jnp.int32)
         read = {n: params[n] for n in ("head", "gate_w", "gate_b")}
         pieces = list(params["layers"])
         losses, gates = [], []
         for p in range(passes):
-            for i in range(cfg.pieces):
-                pieces[i], h, ran = scan_layers(
-                    plan.names[p * cfg.pieces + i])(pieces[i], h, None)
-                count = count + ran
-            h = _rms(h, params["norm_f"], cfg.rms_norm_eps)
-            read, (ce, gate), _ = read_pass(read, h, targets)
+            with jax.named_scope("stack"):
+                for i in range(cfg.pieces):
+                    pieces[i], h, ran = scan_layers(
+                        plan.names[p * cfg.pieces + i])(pieces[i], h, None)
+                    count = count + ran
+            with jax.named_scope("head"):
+                h = _rms(h, params["norm_f"], cfg.rms_norm_eps)
+                read, (ce, gate), _ = read_pass(read, h, targets)
             losses.append(ce)
             gates.append(gate)
-        losses = jnp.stack(losses)                          # [T, B, S]
-        p_exit = exit_distribution(jnp.stack(gates[:-1]))
-        entropy = jnp.sum(jax.scipy.special.entr(p_exit), axis=0)
-        per_token = (jnp.sum(p_exit * losses, axis=0)
-                     - cfg.exit_entropy_weight * entropy)
-        tokens_all = lax.psum(jnp.asarray(per_token.size, jnp.float32),
-                              daxes)
+        with jax.named_scope("head"):
+            losses = jnp.stack(losses)                      # [T, B, S]
+            p_exit = exit_distribution(jnp.stack(gates[:-1]))
+            entropy = jnp.sum(jax.scipy.special.entr(p_exit), axis=0)
+            per_token = (jnp.sum(p_exit * losses, axis=0)
+                         - cfg.exit_entropy_weight * entropy)
+            tokens_all = lax.psum(
+                jnp.asarray(per_token.size, jnp.float32), daxes)
 
-        def mean(x):
-            return lax.psum(jnp.sum(x, axis=(-2, -1)), daxes) / tokens_all
-        aux = {"pass_losses": mean(losses), "exit_p": mean(p_exit),
-               "exit_entropy": mean(entropy), "applications": count}
-        return mean(per_token), aux
+            def mean(x):
+                return (lax.psum(jnp.sum(x, axis=(-2, -1)), daxes)
+                        / tokens_all)
+            aux = {"pass_losses": mean(losses), "exit_p": mean(p_exit),
+                   "exit_entropy": mean(entropy), "applications": count}
+            return mean(per_token), aux
 
     def loss(params, tokens, targets):
         plan = _plan_for(cfg, mesh, params, tokens)

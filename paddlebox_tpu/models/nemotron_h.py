@@ -265,6 +265,8 @@ def _experts(p, h, cfg: NemotronHConfig):
 
 
 _MIXER = {"M": _mamba, "*": _attention, "E": _experts}
+# the named scope of each mixer's layer, norm and residual add included
+_PART = {"M": "mamba", "*": "attention", "E": "moe"}
 
 
 # -- what a layer keeps for its backward pass --------------------------------
@@ -319,37 +321,42 @@ def nemotron_h_loss_fn(cfg: NemotronHConfig, mesh: Mesh, specs: Dict):
 
     def layer(letter, keep):
         def apply(lp, x):
-            y, counts = _MIXER[letter](lp, _rms(x, lp["norm"],
-                                                cfg.norm_eps), cfg)
-            return x + y, counts
+            with jax.named_scope(_PART[letter]):
+                y, counts = _MIXER[letter](lp, _rms(x, lp["norm"],
+                                                    cfg.norm_eps), cfg)
+                return x + y, counts
         return jax.checkpoint(
             apply, policy=jax.checkpoint_policies.save_only_these_names(
                 *keep) if keep else None)
 
     def body(plan, params, tokens, targets):
-        x = tplib.vocab_parallel_embedding(
-            {"table": params["embed"]}, tokens, axis="mp")
+        with jax.named_scope("embed"):
+            x = tplib.vocab_parallel_embedding(
+                {"table": params["embed"]}, tokens, axis="mp")
         served = []
-        for letter, keep, lp in zip(cfg.pattern, plan.names,
-                                    params["layers"]):
-            x, counts = layer(letter, keep)(lp, x)
-            if counts is not None:
-                served.append(counts)
-        logits = _dot(_rms(x, params["norm_f"], cfg.norm_eps),
-                      params["head"])
-        losses = tplib.parallel_cross_entropy(logits, targets, axis="mp")
-        total = lax.psum(jnp.sum(losses), daxes)
-        count = lax.psum(jnp.asarray(losses.size, jnp.float32), daxes)
-        held = cfg.experts_held[1]
-        aux = {
-            "load": lax.psum(jnp.stack(
-                [c.load for c in served]) if served
-                else jnp.zeros((0, held), jnp.int32), daxes),
-            "dropped": lax.psum(jnp.stack(
-                [c.dropped for c in served]) if served
-                else jnp.zeros((0,), jnp.int32), daxes),
-        }
-        return total / count, aux
+        with jax.named_scope("stack"):
+            for letter, keep, lp in zip(cfg.pattern, plan.names,
+                                        params["layers"]):
+                x, counts = layer(letter, keep)(lp, x)
+                if counts is not None:
+                    served.append(counts)
+        with jax.named_scope("head"):
+            logits = _dot(_rms(x, params["norm_f"], cfg.norm_eps),
+                          params["head"])
+            losses = tplib.parallel_cross_entropy(logits, targets,
+                                                  axis="mp")
+            total = lax.psum(jnp.sum(losses), daxes)
+            count = lax.psum(jnp.asarray(losses.size, jnp.float32), daxes)
+            held = cfg.experts_held[1]
+            aux = {
+                "load": lax.psum(jnp.stack(
+                    [c.load for c in served]) if served
+                    else jnp.zeros((0, held), jnp.int32), daxes),
+                "dropped": lax.psum(jnp.stack(
+                    [c.dropped for c in served]) if served
+                    else jnp.zeros((0,), jnp.int32), daxes),
+            }
+            return total / count, aux
 
     def loss(params, tokens, targets):
         plan = _plan_for(cfg, mesh, params, tokens)

@@ -8,9 +8,12 @@ carried calling-convention bugs), so their TPU-compile surface — notably
 the bf16 conv forward/transpose path resnet now uses — is exactly the
 kind of thing that would otherwise only fail inside the recorded run:
 
-    python tools/aot_check_dense.py [--hybrid | --looped | --blockdiff]
+    python tools/aot_check_dense.py [--gpt | --hybrid | --looped |
+                                     --blockdiff] [--text DIR]
 
-``--hybrid`` checks the hybrid cell in their place, ``--looped`` the looped
+``--gpt`` checks the GPT-2 medium cell's timed step (flash attention, as
+``auto`` resolves on the chip), ``--hybrid`` the hybrid cell in their place,
+``--looped`` the looped
 cell (``models/looped.py`` at ``benchmarks/configs/ouro_2_6b.json``, 4,096
 positions: the timed step and the set-up's ``highest`` gradient function
 as ``benchmarks/runners/looped_train.py`` builds it), ``--blockdiff`` the
@@ -25,9 +28,17 @@ positions): the timed step, and the same loss's gradient at ``highest``
 with every gradient an output, which the set-up runs first. Either above
 ``HYBRID_MEMORY_SHARE`` of the v5e's memory fails the check: on the chip
 that is an out-of-memory in set-up, a failed cell.
+
+``--text DIR`` also writes each compiled program's text to
+``DIR/<cell>.<program>.hlo`` with its metadata (``op_name``, source lines),
+the module's source-location tables and the Mosaic kernels' debug
+locations taken out (``stripped``): what two checkouts compile can then be
+compared character for character, and a change that only names things
+(``jax.named_scope``) must leave it equal.
 """
 
 import os
+import re
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -110,6 +121,57 @@ def check_bert(sh) -> None:
 HYBRID_MEMORY_SHARE = 0.93
 
 
+# what a module's text says about where it came from, not what it computes
+_METADATA = re.compile(r', metadata=\{(?:[^{}"]|"(?:[^"\\]|\\.)*")*\}')
+_SOURCE_TABLES = ("FileNames", "FunctionNames", "FileLocations",
+                  "StackFrames")
+_TABLE_ROW = re.compile(r"^\d+ ")
+_KERNEL_BODY = re.compile(r'"body":"([A-Za-z0-9+/=]+)"')
+
+
+def _kernel_body(match) -> str:
+    """A Mosaic kernel's serialized module (bytecode), which carries the
+    source lines it was traced from, as the digest of its text without
+    them."""
+    import base64
+    import hashlib
+
+    from jax._src.lib.mlir import ir
+    body = base64.b64decode(match.group(1))
+    if not body.startswith(b"ML\xefR"):    # text XLA wrote, no locations
+        return match.group(0)
+    with ir.Context() as ctx:
+        ctx.allow_unregistered_dialects = True
+        module = ir.Module.parse(body)
+        asm = module.operation.get_asm(enable_debug_info=False)
+    return '"body":"sha256:%s"' % hashlib.sha256(asm.encode()).hexdigest()
+
+
+def stripped(text: str) -> str:
+    """A compiled module's text without its instructions' metadata,
+    without the source-location tables and with each Mosaic kernel's
+    module read without its debug locations: what shifts whenever a line
+    of the program is edited."""
+    out, in_table = [], False
+    for line in text.splitlines():
+        if line in _SOURCE_TABLES:
+            in_table = True
+            continue
+        if in_table and _TABLE_ROW.match(line):
+            continue
+        in_table = False
+        out.append(_KERNEL_BODY.sub(_kernel_body, _METADATA.sub("", line)))
+    return "\n".join(out) + "\n"
+
+
+def _text_dir():
+    if "--text" not in sys.argv:
+        return None
+    path = sys.argv[sys.argv.index("--text") + 1]
+    os.makedirs(path, exist_ok=True)
+    return path
+
+
 def program_bytes(compiled) -> dict:
     m = compiled.memory_analysis()
     return {"arguments": m.argument_size_in_bytes,
@@ -166,8 +228,15 @@ def _check_programs(name: str, device, config, seq, init, said, programs):
     # a described device reports no memory: the plan was made for the
     # stacks' stated default, which is the v5e's
     limit = int(HYBRID_MEMORY_SHARE * residual_plan.DEFAULT_DEVICE_BYTES)
+    text_dir = _text_dir()
     for program, lower in programs(mesh, specs, opt):
-        parts = program_bytes(lower(params, opt_state, tok).compile())
+        compiled = lower(params, opt_state, tok).compile()
+        if text_dir:
+            path = os.path.join(
+                text_dir, f"{name}.{program.replace(' ', '_')}.hlo")
+            with open(path, "w") as f:
+                f.write(stripped(compiled.as_text()))
+        parts = program_bytes(compiled)
         total = sum(parts.values())
         print(f"AOT {name} {program}: {json.dumps(parts)} total {total} "
               f"of {limit} allowed", flush=True)
@@ -177,6 +246,25 @@ def _check_programs(name: str, device, config, seq, init, said, programs):
                 f"{HYBRID_MEMORY_SHARE:.0%} of the v5e's "
                 f"{residual_plan.DEFAULT_DEVICE_BYTES}")
     print(f"AOT {name} step and setup gradient fit: OK")
+
+
+def check_gpt(device) -> None:
+    from paddlebox_tpu.models.gpt import (GPTConfig, init_gpt,
+                                          make_gpt_train_step)
+    config, seq = _cell_files("gpt2_medium", "train_s1024")
+    cfg = GPTConfig(vocab_size=config["vocab_size"],
+                    d_model=config["n_embd"], n_heads=config["n_head"],
+                    n_layers=config["n_layer"], d_ff=config["n_inner"],
+                    max_seq_len=config["n_positions"], attention="flash")
+
+    def programs(mesh, specs, opt):
+        def step(params, opt_state, tok):
+            return make_gpt_train_step(cfg, mesh, specs, opt).lower(
+                params, opt_state, tok, tok)
+        return (("step", step),)
+    _check_programs("gpt", device, config, seq,
+                    lambda key: init_gpt(key, cfg, pp_stages=1),
+                    lambda mesh, params, tok: {}, programs)
 
 
 def check_hybrid(device) -> None:
@@ -267,6 +355,9 @@ def main() -> None:
     if topo is None:
         return
     sh = NamedSharding(Mesh([topo.devices[0]], ("d",)), P())
+    if "--gpt" in sys.argv:
+        check_gpt(topo.devices[0])
+        return
     if "--hybrid" in sys.argv:      # two minutes and a half of its own
         check_hybrid(topo.devices[0])
         return
