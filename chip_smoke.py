@@ -938,7 +938,8 @@ def phase_nemotron(sm: Smoke, out: dict):
     mode = "interpret" if sm.rehearsal else "pallas"
     want = {"nemotron_ssd": [mode], "nemotron_attention": [mode],
             "ssd_scan": [mode], "flash_attention": [mode],
-            "nemotron_moe_dispatch": ["sort_ragged_dot"]}
+            "nemotron_moe_dispatch": [
+                "interpret" if sm.rehearsal else "sort_pallas_grouped"]}
     check(all(resolved.get(k) == v for k, v in want.items()),
           f"kernels resolved to {resolved}, want {want}")
     check(load.sum() > 0, "no held expert served any assignment")

@@ -14,8 +14,10 @@ attention, ``E`` a latent mixture of experts with one shared expert.
 - ``E``: sigmoid scores over all routed experts, the top k chosen
   (``parallel/moe.py``); the routed experts live in a latent space
   (``W_down``, ``W_up``) and the layer computes the part of the routed sum
-  that the experts it holds (``experts_held``) give; the shared expert
-  sees the full-width input; squared ReLU.
+  that the experts it holds (``experts_held``) give, through the looped
+  dispatch over the grouped products of
+  ``ops/pallas_kernels/grouped_matmul.py``; the shared expert sees the
+  full-width input; squared ReLU.
 
 Built like ``models/gpt.py``: one ``shard_map`` over the hybrid mesh,
 vocabulary-parallel embedding and cross entropy over ``mp``, batch over
@@ -46,7 +48,11 @@ from paddlebox_tpu.models.residual_plan import Keepable, product, ranked
 from paddlebox_tpu.models.train_step import make_train_step
 from paddlebox_tpu.ops.pallas_kernels.flash_attention import (
     RESIDUAL_NAMES as FLASH_RESIDUAL_NAMES, flash_attention)
-from paddlebox_tpu.ops.pallas_kernels.ssd_scan import ssd_scan
+from paddlebox_tpu.ops.pallas_kernels.grouped_matmul import (
+    ROW_TILE, grouped_matmul, grouped_weight_grad, row_tile_schedule,
+    scatter_add_rows)
+from paddlebox_tpu.ops.pallas_kernels.ssd_scan import (ambient_mxu_dtype,
+                                                       ssd_scan)
 from paddlebox_tpu.parallel import moe as moelib
 from paddlebox_tpu.parallel import tp as tplib
 
@@ -245,20 +251,85 @@ def _attention(p, h, cfg: NemotronHConfig):
     return _dot(attn.reshape(b, s, -1), p["wo"]), None
 
 
+# The looped dispatch's block, in even router's shares of a layer's
+# assignments (a layer's held load is 0.7 to 1.2 of one): one and two
+# shares read alike on the chip, one holds less (PERF.md section 6).
+DISPATCH_SHARES = 1
+
+
+def dispatch_block_rows(cfg: NemotronHConfig, rows: int) -> int:
+    """Assignments a trip of the expert dispatch's loop holds, for
+    ``rows`` rows through a layer: ``DISPATCH_SHARES`` times what the
+    held experts get of an even router, in whole row tiles of the grouped
+    products."""
+    share = -(-rows * cfg.num_experts_per_tok * cfg.experts_held[1]
+              // cfg.n_routed_experts)
+    return -(-DISPATCH_SHARES * share // ROW_TILE) * ROW_TILE
+
+
+def _held_experts(mode: Dict, mxu) -> moelib.LoopedExperts:
+    """Squared-ReLU experts (``w1``, ``w2``) over contiguous row segments,
+    ``sizes[e]`` rows for held expert e, each row's result times its
+    ``scale``: a grouped product in and one out, forward; backward the
+    one in again and four more (the rows' cotangent through ``w2`` and
+    through ``w1`` transposed; the two weights' gradients, each added to
+    the sum it is handed). Every product's operands are ``mxu``, every
+    sum float32; what lies between the products is float32. ``mode``:
+    ``flags.kernel_mode``'s."""
+    def products(sizes, rows):
+        kernels = dict(use_pallas=mode["use_pallas"],
+                       interpret=mode["interpret"], sizes=sizes)
+        if mode["use_pallas"]:      # one table of visits for all of them
+            kernels["schedule"] = row_tile_schedule(sizes, rows, ROW_TILE)
+        return (functools.partial(grouped_matmul, **kernels),
+                functools.partial(grouped_weight_grad, **kernels))
+
+    def forward(p, rows, scale, sizes):
+        rows_by, _ = products(sizes, rows.shape[0])
+        hidden = _relu2(rows_by(rows, p["w1"].astype(mxu))) * scale[:, None]
+        return rows_by(hidden.astype(mxu), p["w2"].astype(mxu))
+
+    def backward(p, rows, scale, sizes, dy, sums):
+        rows_by, weights_by = products(sizes, rows.shape[0])
+        w1, w2 = p["w1"].astype(mxu), p["w2"].astype(mxu)
+        act = jax.nn.relu(rows_by(rows, w1))
+        hidden = act * act
+        # y = scale * (hidden @ w2): the cotangent of hidden is scale *
+        # (dy @ w2.T) and that of scale is <dy @ w2.T, hidden>, so the
+        # out-product is not computed again
+        back = rows_by(dy, w2, transpose_w=True)
+        din = (2.0 * back * scale[:, None] * act).astype(mxu)
+        sums = {"w1": weights_by(rows, din, into=sums["w1"]),
+                "w2": weights_by((hidden * scale[:, None]).astype(mxu), dy,
+                                 into=sums["w2"])}
+        return (rows_by(din, w1, transpose_w=True),
+                jnp.sum(back * hidden, axis=-1), sums)
+    return moelib.LoopedExperts(
+        forward, backward, mxu, functools.partial(
+            scatter_add_rows, use_pallas=mode["use_pallas"],
+            interpret=mode["interpret"]))
+
+
 def _experts(p, h, cfg: NemotronHConfig):
     b, s, d = h.shape
     x = h.reshape(b * s, d)
     idx, weights = moelib.topk_sigmoid_router(
         x, p["gate"], p["bias"], k=cfg.num_experts_per_tok,
         scaling=cfg.routed_scaling_factor)
-
-    def held_experts(rows, sizes):
-        return lax.ragged_dot(_relu2(lax.ragged_dot(rows, p["w1"], sizes)),
-                              p["w2"], sizes)
-    flags.note_kernel("nemotron_moe_dispatch", "sort_ragged_dot")
+    mode = _kernel_mode(cfg)
+    flags.note_kernel(
+        "nemotron_moe_dispatch",
+        "sort_pallas_grouped" if mode["name"] == "pallas" else mode["name"])
+    # The grouped products take their operands as they come: cast to what
+    # XLA makes of a float32 product under the ambient precision, as the
+    # stack's other products are (bfloat16; float32 under "highest").
+    # Elsewhere XLA's own product, and the interpreter's, at float32.
+    mxu = ambient_mxu_dtype() if mode["name"] == "pallas" else jnp.float32
     routed, counts = moelib.dropless_dispatch(
         checkpoint_name(_dot(x, p["w_down"]), "moe_latent"), idx, weights,
-        cfg.experts_held, held_experts)
+        cfg.experts_held, _held_experts(mode, mxu),
+        {"w1": p["w1"], "w2": p["w2"]},
+        block_rows=dispatch_block_rows(cfg, b * s))
     shared = checkpoint_name(_dot(x, p["ws1"]), "moe_shared_hidden")
     y = _dot(routed, p["w_up"]) + _dot(_relu2(shared), p["ws2"])
     return y.reshape(b, s, d), counts

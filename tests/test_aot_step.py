@@ -34,7 +34,7 @@ def _run_tool(name, timeout, *args):
 def test_pallas_kernels_aot_compile_for_tpu():
     """sorted_scatter, sorted_gather, flash_attention fwd+bwd (the three
     dense cells' shapes: equal heads of 64 and of 128, grouped heads),
-    ssd_scan fwd+bwd, the block-diffusion stack's grouped products and
+    ssd_scan fwd+bwd, the two expert stacks' grouped products and
     seqpool_cvm at the shapes the benchmarks use."""
     out = _run_tool("aot_check_kernels.py", 900)
     assert out.count("AOT sorted_scatter") == 3
@@ -44,8 +44,8 @@ def test_pallas_kernels_aot_compile_for_tpu():
     assert "AOT flash_attention grouped fwd+bwd" in out
     assert out.count("AOT flash_attention block-diffusion fwd+bwd") == 2
     assert out.count("AOT ssd_scan fwd+bwd") == 2
-    assert out.count("AOT grouped_matmul fwd+transposed+weights") == 6
-    assert out.count("AOT scatter_add_rows") == 3
+    assert out.count("AOT grouped_matmul fwd+transposed+weights") == 10
+    assert out.count("AOT scatter_add_rows") == 5
     assert "AOT seqpool_cvm" in out
     assert "PALLAS KERNELS TPU AOT COMPILE: OK" in out
 

@@ -7,6 +7,7 @@ import pytest
 from jax import lax
 
 from paddlebox_tpu.core import flags
+from paddlebox_tpu.models import nemotron_h as nh
 from paddlebox_tpu.models.block_diffusion import _held_experts, _packed
 from paddlebox_tpu.parallel.moe import (LoopedExperts, dropless_dispatch,
                                         topk_sigmoid_router,
@@ -77,13 +78,29 @@ def _looped(rows_fn):
 _expert_of = _looped(lambda p, rows, sizes: _expert(p["w1"], p["w2"])(
     rows, sizes))
 
+# The squared-ReLU experts three ways: the backward pass from ``jax.vjp``
+# of ``lax.ragged_dot``; the hybrid stack's written-out one over the XLA
+# products; the same over the Pallas kernels in the interpreter, whose row
+# tiles (128) the blocks must hold whole.
+RELU2 = {
+    "vjp": (lambda: _expert_of, 40),
+    "xla": (lambda: nh._held_experts(flags.kernel_mode("xla"), jnp.float32),
+            40),
+    "interpret": (lambda: nh._held_experts(flags.kernel_mode("interpret"),
+                                           jnp.float32), 128),
+}
+
 
 @pytest.mark.parametrize("shares,looped", [(1, False), (4, False),
-                                           (4, True)])
+                                           (4, True), (4, "xla"),
+                                           (1, "interpret")])
 def test_shares_add_up_to_the_uncut_layer(shares, looped):
     """What all the shares give (4 chips holding 4 experts each, or one
     holding all 16) adds up to the layer over all experts, in value and
-    in every gradient; the blocks unrolled under ``cond`` or as a loop."""
+    in every gradient; the blocks unrolled under ``cond`` or as a loop
+    (``looped``: True the experts' backward from ``jax.vjp``, else the
+    hybrid stack's experts of that ``RELU2`` name), in blocks that do not
+    divide the T * K rows, or in two trips of 128 rows."""
     x, gate, w1, w2 = _layer()
     count = E // shares
 
@@ -94,9 +111,10 @@ def test_shares_add_up_to_the_uncut_layer(shares, looped):
         def share(first):
             held = {"w1": w1[first:first + count],
                     "w2": w2[first:first + count]}
-            if looped:      # in blocks that do not divide the T * K rows
-                return dropless_dispatch(x, idx, w, (first, count),
-                                         _expert_of, held, block_rows=40)[0]
+            if looped:
+                make, block_rows = RELU2["vjp" if looped is True else looped]
+                return dropless_dispatch(x, idx, w, (first, count), make(),
+                                         held, block_rows=block_rows)[0]
             return dropless_dispatch(x, idx, w, (first, count),
                                      _expert(held["w1"], held["w2"]))[0]
         return sum(share(first) for first in range(0, E, count))
@@ -175,35 +193,122 @@ def test_eight_shares_of_the_softmax_swiglu_layer_add_up_to_the_uncut_one(
         assert float(jnp.linalg.norm(g - w) / jnp.linalg.norm(w)) < 1e-5
 
 
-@pytest.mark.parametrize("experts", ["xla", "interpret"])
+def _both_layers(experts):
+    """(the looped experts, how a device's weights are handed over, the
+    router, the uncut layer, which of ``(x, gate, w1, w3, w2)`` it reads)
+    of the block-diffusion stack's gated layer (``experts`` a ``SWIGLU``
+    name) or the hybrid stack's squared-ReLU one (``relu2_`` and a
+    ``RELU2`` name)."""
+    if experts.startswith("relu2_"):
+        make, _ = RELU2[experts[len("relu2_"):]]
+        return (make(), lambda w1, w3, w2: {"w1": w1, "w2": w2},
+                lambda x, gate: topk_sigmoid_router(
+                    x, gate, jnp.zeros(E), k=K, scaling=2.5),
+                lambda x, gate, w1, w3, w2: _uncut(x, gate, w1, w2),
+                (0, 1, 2, 4))
+    make, _ = SWIGLU[experts]
+    return (make(), lambda w1, w3, w2: _packed({"w1": w1, "w3": w3,
+                                                "w2": w2}),
+            lambda x, gate: topk_softmax_router(x, gate, K), _uncut_swiglu,
+            tuple(range(5)))
+
+
+@pytest.mark.parametrize("experts", ["xla", "interpret", "relu2_xla",
+                                     "relu2_interpret"])
 def test_every_trip_of_the_loop_sums_into_one_gradient(experts):
-    """One device holding all 16 gated experts serves the ``T * K`` = 256
-    assignments in two trips of 128 rows (one row tile each in the
-    interpreter): both trips' gradients of the experts' weights land in
-    the one carried sum, and the layer is the uncut one."""
+    """One device holding all 16 experts (gated, or squared ReLU) serves
+    the ``T * K`` = 256 assignments in two trips of 128 rows (one row
+    tile each in the interpreter): both trips' gradients of the experts'
+    weights land in the one carried sum, and the layer is the uncut
+    one."""
     x, gate, w1, w2 = _layer(5)
     w3 = jax.random.normal(jax.random.PRNGKey(55), w1.shape) * 0.3
-    make, _ = SWIGLU[experts]
+    looped, pack, route, uncut, read = _both_layers(experts)
 
     def cut(x, gate, w1, w3, w2):
-        idx, w = topk_softmax_router(x, gate, K)
+        idx, w = route(x, gate)
         out, counts = dropless_dispatch(
-            x, idx, w, (0, E), make(),
-            _packed({"w1": w1, "w3": w3, "w2": w2}), block_rows=128)
+            x, idx, w, (0, E), looped, pack(w1, w3, w2), block_rows=128)
         return out, counts
     args = (x, gate, w1, w3, w2)
     with jax.default_matmul_precision("highest"):
         out, counts = jax.jit(cut)(*args)
         assert int(counts.load.sum()) == T * K and int(counts.dropped) == 0
         np.testing.assert_allclose(np.asarray(out),
-                                   np.asarray(_uncut_swiglu(*args)),
+                                   np.asarray(uncut(*args)),
                                    rtol=1e-5, atol=1e-5)
-        want = jax.grad(lambda *a: jnp.sum(jnp.sin(_uncut_swiglu(*a))),
-                        argnums=tuple(range(5)))(*args)
+        want = jax.grad(lambda *a: jnp.sum(jnp.sin(uncut(*a))),
+                        argnums=read)(*args)
         got = jax.jit(jax.grad(lambda *a: jnp.sum(jnp.sin(cut(*a)[0])),
-                               argnums=tuple(range(5))))(*args)
+                               argnums=read))(*args)
     for g, w in zip(got, want):
         assert float(jnp.linalg.norm(g - w) / jnp.linalg.norm(w)) < 1e-5
+
+
+@pytest.mark.parametrize("experts", ["xla", "interpret", "relu2_xla",
+                                     "relu2_interpret"])
+def test_rows_past_the_last_segment_reach_nothing(experts):
+    """The ``LoopedExperts`` contract: rows past the last segment may hold
+    anything, in ``rows`` and in ``dy`` (on the TPU a grouped product
+    leaves them unwritten); nothing of them reaches a live row, a live
+    row's scale cotangent or the weights' sums."""
+    looped, pack, _, _, _ = _both_layers(experts)
+    ks = jax.random.split(jax.random.PRNGKey(6), 6)
+    rows, held = 128, 4
+    sizes = jnp.array([30, 0, 41, 27], jnp.int32)      # 98 of 128 live
+    w1 = jax.random.normal(ks[0], (held, F, INNER)) * 0.3
+    w3 = jax.random.normal(ks[1], (held, F, INNER)) * 0.3
+    w2 = jax.random.normal(ks[2], (held, INNER, F)) * 0.3
+    params = pack(w1, w3, w2)
+    x = jax.random.normal(ks[3], (rows, F))
+    dy = jax.random.normal(ks[4], (rows, F))
+    scale = jax.random.uniform(ks[5], (rows,))
+    live = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
+    sums = jax.tree.map(lambda p: jnp.ones(p.shape, jnp.float32), params)
+
+    def run(x, dy):
+        with jax.default_matmul_precision("highest"):
+            y = looped.forward(params, x, scale, sizes)
+            return (y,) + tuple(looped.backward(params, x, scale, sizes, dy,
+                                                sums))
+    want = jax.jit(run)(jnp.where(live, x, 0.0), jnp.where(live, dy, 0.0))
+    got = jax.jit(run)(jnp.where(live, x, jnp.nan),
+                       jnp.where(live, dy, jnp.inf))
+    for name, g, w in (("out", got[0], want[0]), ("drows", got[1], want[1]),
+                       ("dscale", got[2][:, None], want[2][:, None])):
+        np.testing.assert_allclose(np.asarray(jnp.where(live, g, 0.0)),
+                                   np.asarray(jnp.where(live, w, 0.0)),
+                                   rtol=1e-6, atol=1e-6, err_msg=name)
+    for g, w in zip(jax.tree.leaves(got[3]), jax.tree.leaves(want[3])):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("kernels", ["xla", "interpret"])
+def test_bfloat16_operands_round_the_products_and_nothing_else(kernels):
+    """The hybrid stack's experts as the timed step runs them on the
+    chip: bfloat16 operands, float32 sums and float32 between the
+    products. The layer and the cotangents of the tokens, the router and
+    both weights stay within a few 2^-8 of the float32 ones (read about
+    one), and do differ from them."""
+    x, gate, w1, w2 = _layer(7)
+
+    def cut(mxu):
+        def layer(x, gate, w1, w2):
+            idx, w = topk_sigmoid_router(x, gate, jnp.zeros(E), k=K,
+                                         scaling=2.5)
+            return dropless_dispatch(
+                x, idx, w, (0, E), nh._held_experts(
+                    flags.kernel_mode(kernels), mxu), {"w1": w1, "w2": w2},
+                block_rows=128)[0]
+        return layer
+    args = (x, gate, w1, w2)
+    cot = jax.random.normal(jax.random.PRNGKey(77), x.shape)
+    got, want = (jax.jit(jax.vjp, static_argnums=0)(cut(mxu), *args)
+                 for mxu in (jnp.bfloat16, jnp.float32))
+    for g, w in zip((got[0],) + got[1](cot), (want[0],) + want[1](cot)):
+        err = float(jnp.linalg.norm(g - w) / jnp.linalg.norm(w))
+        assert 1e-5 < err < 4 * 2.0 ** -8
 
 
 def test_softmax_router_is_the_sort_based_one_ties_included():
@@ -237,17 +342,25 @@ def test_softmax_router_is_the_sort_based_one_ties_included():
     assert float(jnp.abs(grad).max()) > 0.0
 
 
-@pytest.mark.parametrize("looped", [False, True])
+@pytest.mark.parametrize("looped", [False, True, "xla", "interpret"])
 def test_nothing_is_dropped_when_one_expert_takes_half_the_tokens(looped):
+    """``looped``: False the ``cond`` form; True the loop in blocks of T
+    rows with the experts' backward from ``jax.vjp``; else the hybrid
+    stack's experts of that ``RELU2`` name, in its blocks."""
     x, gate, w1, w2 = _layer(1)
     # expert 5 is planted to win for the first half of the tokens
     bias = jnp.zeros(E)
     x = x.at[:T // 2].set(jnp.abs(x[:T // 2]))
     gate = gate.at[:, 5].set(3.0)
     idx, w = topk_sigmoid_router(x, gate, bias, k=K, scaling=2.5)
-    if looped:
+    if looped is True:
         dispatch = lambda x, idx, w: dropless_dispatch(
             x, idx, w, (4, 4), _expert_of, {"w1": w1[4:8], "w2": w2[4:8]})
+    elif looped:
+        make, block_rows = RELU2[looped]
+        dispatch = lambda x, idx, w: dropless_dispatch(
+            x, idx, w, (4, 4), make(), {"w1": w1[4:8], "w2": w2[4:8]},
+            block_rows=block_rows)
     else:
         dispatch = lambda x, idx, w: dropless_dispatch(
             x, idx, w, (4, 4), _expert(w1[4:8], w2[4:8]))
@@ -263,15 +376,17 @@ def test_nothing_is_dropped_when_one_expert_takes_half_the_tokens(looped):
                                rtol=2e-2, atol=2e-2)
 
 
-@pytest.mark.parametrize("looped", [False, True])
+@pytest.mark.parametrize("looped", [False, True, "xla", "interpret"])
 def test_no_held_expert_chosen_gives_zero(looped):
     x, gate, w1, w2 = _layer(2)
     idx = jnp.zeros((T, K), jnp.int32)              # everyone picks 0
     w = jnp.ones((T, K))
     if looped:
+        make, block_rows = ((lambda: _expert_of, 0) if looped is True
+                            else RELU2[looped])
         out, counts = dropless_dispatch(
-            x, idx, w, (8, 4), _expert_of,
-            {"w1": w1[8:12], "w2": w2[8:12]})
+            x, idx, w, (8, 4), make(), {"w1": w1[8:12], "w2": w2[8:12]},
+            block_rows=block_rows)
     else:
         out, counts = dropless_dispatch(x, idx, w, (8, 4),
                                         _expert(w1[8:12], w2[8:12]))

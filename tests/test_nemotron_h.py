@@ -176,6 +176,30 @@ def test_expert_layer_shares_add_up_to_the_uncut_reference():
     assert int(np.concatenate(served).sum()) == 48 * cfg.num_experts_per_tok
 
 
+def test_dispatch_block_is_one_even_share_in_whole_row_tiles():
+    """The cell's layer: 8,192 rows, 22 of 512 experts a row, 8 held:
+    2,816 assignments, 22 row tiles of 128; a share under a tile takes
+    one tile."""
+    cell = NemotronHConfig(experts_held=(0, 8))
+    assert nh.dispatch_block_rows(cell, 8192) == 2816 == 22 * 128
+    assert nh.dispatch_block_rows(SMALL, 80) == 128
+
+
+@pytest.mark.parametrize("kernels", ["xla", "interpret"])
+def test_expert_dispatch_site_records_what_ran(kernels):
+    """``nemotron_moe_dispatch`` says which grouped products the layer
+    traced: the Pallas kernels read ``sort_pallas_grouped`` on the chip,
+    elsewhere the mode's name."""
+    from paddlebox_tpu.core import flags
+    cfg = dataclasses.replace(SMALL, pattern="E", kernels=kernels)
+    params, _, _, _ = _seeded(cfg)
+    h = jnp.ones((1, 16, cfg.hidden_size))
+    flags.resolved_kernels(reset=True)
+    jax.eval_shape(lambda p, h: nh._experts(p, h, cfg),
+                   params["layers"][0], h)
+    assert flags.resolved_kernels()["nemotron_moe_dispatch"] == [kernels]
+
+
 def test_step_trains_and_returns_the_routers_counts():
     cfg = dataclasses.replace(SMALL, kernels="xla")
     params, specs, tokens, targets = _seeded(cfg, seq=32)

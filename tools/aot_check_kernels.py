@@ -136,37 +136,45 @@ def main() -> None:
         print(f"AOT ssd_scan fwd+bwd [1, 8192, 128, 64] "
               f"{jnp.dtype(mxu).name}: OK", flush=True)
 
-    # The block-diffusion stack's expert products at published widths (16
-    # held experts; gate and up-projection side by side, 2048 -> 1536, and
-    # 768 -> 2048 out) over the rows a trip of the dispatch's loop may
-    # hold, at both operand precisions: the rows' product and its
-    # transposed-weight twin, the weights' gradient summed into its
-    # argument's buffer, and a trip's rows added to their tokens' (the
-    # cell's 8,192 rows of 2048).
-    sizes = sds((16,), jnp.int32)
-    for rows in (8192, 16384, 24576):
-        for mxu in (jnp.bfloat16, jnp.float32):
-            for k, n in ((2048, 1536), (768, 2048)):
-                for transpose_w in (False, True):
-                    w = sds((16, n, k) if transpose_w else (16, k, n), mxu)
-                    jax.jit(lambda x, w, s: grouped_matmul(
-                        x, w, s, transpose_w=transpose_w,
-                        use_pallas=True)).lower(
-                        sds((rows, k), mxu), w, sizes).compile()
-                jax.jit(lambda x, dy, s, into: grouped_weight_grad(
-                    x, dy, s, into, use_pallas=True),
-                    donate_argnums=3).lower(
-                    sds((rows, k), mxu), sds((rows, n), mxu), sizes,
-                    sds((16, k, n), jnp.float32)).compile()
-            print(f"AOT grouped_matmul fwd+transposed+weights [{rows}, "
-                  f"2048 <-> 1536 | 768] x 16 {jnp.dtype(mxu).name}: OK",
-                  flush=True)
-        jax.jit(lambda into, index, values: scatter_add_rows(
-            into, index, values, use_pallas=True), donate_argnums=0).lower(
-            sds((8192, 2048), jnp.float32), sds((rows,), jnp.int32),
-            sds((rows, 2048), jnp.float32)).compile()
-        print(f"AOT scatter_add_rows [{rows}, 2048] -> [8192, 2048]: OK",
-              flush=True)
+    # The two expert stacks' products at published widths over the rows a
+    # trip of the dispatch's loop may hold, at both operand precisions:
+    # the rows' product and its transposed-weight twin, the weights'
+    # gradient summed into its argument's buffer, and a trip's rows added
+    # to their tokens' (the cell's 8,192 rows). Block diffusion: 16 held
+    # experts, gate and up-projection side by side (2048 -> 1536) and 768
+    # -> 2048 out, trips of one to three shares of 8,192 rows; the hybrid
+    # stack: 8 held squared-ReLU experts, 1024 -> 2688 -> 1024, trips of
+    # one and two shares of 2,816 rows.
+    for held, widths, trips, width in (
+            (16, ((2048, 1536), (768, 2048)), (8192, 16384, 24576), 2048),
+            (8, ((1024, 2688), (2688, 1024)), (2816, 5632), 1024)):
+        sizes = sds((held,), jnp.int32)
+        for rows in trips:
+            for mxu in (jnp.bfloat16, jnp.float32):
+                for k, n in widths:
+                    for transpose_w in (False, True):
+                        w = sds((held, n, k) if transpose_w
+                                else (held, k, n), mxu)
+                        jax.jit(lambda x, w, s: grouped_matmul(
+                            x, w, s, transpose_w=transpose_w,
+                            use_pallas=True)).lower(
+                            sds((rows, k), mxu), w, sizes).compile()
+                    jax.jit(lambda x, dy, s, into: grouped_weight_grad(
+                        x, dy, s, into, use_pallas=True),
+                        donate_argnums=3).lower(
+                        sds((rows, k), mxu), sds((rows, n), mxu), sizes,
+                        sds((held, k, n), jnp.float32)).compile()
+                shapes = ", ".join(f"{k} -> {n}" for k, n in widths)
+                print(f"AOT grouped_matmul fwd+transposed+weights [{rows}, "
+                      f"{shapes}] x {held} {jnp.dtype(mxu).name}: OK",
+                      flush=True)
+            jax.jit(lambda into, index, values: scatter_add_rows(
+                into, index, values, use_pallas=True),
+                donate_argnums=0).lower(
+                sds((8192, width), jnp.float32), sds((rows,), jnp.int32),
+                sds((rows, width), jnp.float32)).compile()
+            print(f"AOT scatter_add_rows [{rows}, {width}] -> [8192, "
+                  f"{width}]: OK", flush=True)
 
     n, d, rows = 65536, 16, 16384
     sc = sds((n,), jnp.float32)
