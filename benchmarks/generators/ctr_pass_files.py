@@ -51,6 +51,17 @@ def plan(traffic: Dict, config: Dict, chips: int) -> Dict:
     core = int(round(pass_keys * float(traffic["shared_with_previous"])))
     fresh = pass_keys - hot - core - unseen
     n_passes = int(traffic["distinct_passes"])
+    warmup = int(traffic["warmup_passes"])
+    window = int(traffic["window_passes"])
+    if warmup < 1 or window < 2:
+        raise ValueError(f"traffic: warmup_passes {warmup} must be at least "
+                         f"1 and window_passes {window} at least 2 (the "
+                         f"traced pass is the window's second)")
+    if warmup + window > n_passes:
+        raise ValueError(f"traffic: warmup_passes {warmup} + window_passes "
+                         f"{window} = {warmup + window} passes, more than "
+                         f"distinct_passes {n_passes}: a set of files "
+                         f"would train twice in one run")
     if min(hot, core, fresh, unseen) < 0:
         raise ValueError("traffic: pass_keys too small for its parts")
     if core + hot + n_passes * fresh > n_resident:
@@ -64,7 +75,8 @@ def plan(traffic: Dict, config: Dict, chips: int) -> Dict:
     return {
         "n_resident": n_resident, "pass_keys": pass_keys, "hot": hot,
         "core": core, "fresh": fresh, "unseen": unseen,
-        "n_passes": n_passes, "batches": int(traffic["pass_batches"]),
+        "n_passes": n_passes, "warmup_passes": warmup,
+        "window_passes": window, "batches": int(traffic["pass_batches"]),
         "lines_per_file": int(config["batch_per_chip"]),
         "files_per_pass": int(traffic["pass_batches"]) * chips,
         "slots": int(model["slots"]), "dense_dim": int(model["dense_dim"]),

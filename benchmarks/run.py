@@ -3,9 +3,11 @@
     python benchmarks/run.py --workload <cell> --seed <n> --seconds <s> \
         --trace <0|1>
 
-Loads, warms up, measures for ``--seconds``, checks the outputs and prints
-one JSON object as the last line of standard output. Fails, printing no
-result, where jax finds no TPU or fewer chips than the cell asks for.
+Loads, warms up, measures for ``--seconds`` (the CTR day cells: for a
+count of whole passes their traffic mix sets), checks the outputs and
+prints one JSON object as the last line of standard output. Fails,
+printing no result, where jax finds no TPU or fewer chips than the cell
+asks for.
 ``--rehearse`` is the benchmark's own switch for control flow: the cell's
 ``rehearse`` sizes on the CPU backend, ``"metrics": {}``, never a device
 number.
@@ -290,6 +292,14 @@ def main(argv=None) -> int:
         line["breakdown"] = traced["breakdown"]
     if args.rehearse:
         line["rehearsal"] = True
+    compared = result.get("compared")
+    if compared:
+        # each number that decided ``correct`` beside its limit: the last
+        # lines of standard error and the last key of the line
+        for name, c in compared.items():
+            print(f"run.py: compared {name} = {c['value']!r}, limit "
+                  f"{c['limit']!r}", file=sys.stderr)
+        line["compared"] = compared
     if args.detail:
         os.makedirs(os.path.dirname(os.path.abspath(args.detail)),
                     exist_ok=True)

@@ -10,8 +10,12 @@ pass boundaries through ``pass_boundary_hook``:
   the device from the key hash), the pass files are written by a process
   pool meanwhile, and ``warmup_passes`` passes train so that every program
   of a steady pass is compiled or loaded;
-- window: opens at the boundary that ends the warm-up, closes at the first
-  boundary at or after ``--seconds``; whole passes only;
+- window: opens at the boundary that ends the warm-up and closes at the
+  boundary that completes ``window_passes`` more passes, whatever their
+  wall time (``--seconds`` is not read here): the same work in every run,
+  so a faster program shortens the window and never trains a set of files
+  twice inside it (``plan`` refuses a mix whose warm-up and window need
+  more than ``distinct_passes``);
 - after the window the hook stops the day (the day-end base dump is not
   part of a pass), and one held-out batch is evaluated by the program
   (``eval_pass``) and by the plain reference on the rows read back from
@@ -143,11 +147,12 @@ def run(job) -> Dict:
     if job.trace:
         trace.GLOBAL.enable(ring_events=1 << 18)
     interval = 15                   # 96 pass slots a day, far more than fit
-    warmup = int(traffic["warmup_passes"])
+    warmup = p["warmup_passes"]
+    closing = warmup + p["window_passes"]  # the pass that closes the window
     boundaries: List[float] = []    # perf_counter at each pass boundary
     reports: List[Dict] = []        # the program's pass report, per pass
     built_keys: List[int] = []      # store/pass_keys counter, per boundary
-    window = {"open": None, "close": None, "last": None, "compiles": None}
+    window = {"open": None, "close": None, "compiles": None}
 
     def hook(day: str, pass_id: int) -> None:
         now = time.perf_counter()
@@ -156,18 +161,16 @@ def run(job) -> Dict:
         built_keys.append(int(monitor.get("store/pass_keys")))
         if job.tracing_now():       # the traced span is one whole pass
             job.stop_device_trace()
-        if pass_id < warmup:
-            return
         if pass_id == warmup:
             window["compiles"] = job.compiles()
             window["open"] = time.perf_counter()
+        elif pass_id == closing:
+            window["close"] = now
+            raise _WindowClosed()
         elif pass_id == warmup + 1 and job.trace:
             # the window's second pass: by then the preload runs at its
             # steady distance behind training
             job.start_device_trace()
-        elif now - window["open"] >= job.seconds:
-            window["close"], window["last"] = now, pass_id
-            raise _WindowClosed()
 
     runner = DayRunner(
         trainer, feed, out_dir, split_interval=interval, split_per_pass=1,
@@ -184,7 +187,7 @@ def run(job) -> Dict:
         pass
     compiles_in_window = job.compiles() - window["compiles"]
 
-    in_window = reports[warmup:window["last"]]
+    in_window = reports[warmup:closing]
     steps = sum(int(r["steps"]) for r in in_window)
     failed = sum(int(r["steps"]) for r in in_window
                  if not np.isfinite(r["loss"]) or r["lookup_overflow"])
@@ -198,18 +201,26 @@ def run(job) -> Dict:
     auc_ok = ceiling + AUC_BAND[0] <= last["auc"] <= ceiling + AUC_BAND[1]
     with job.span("check/reference"):
         held = _held_out_check(job, gen, trainer, store, feed, p,
-                               (window["last"] - 1) % p["n_passes"],
-                               data_dir)
+                               closing - 1, data_dir)
     correct = bool(auc_ok and overflow == 0 and failed == 0 and held["ok"])
+    band = [ceiling + AUC_BAND[0], ceiling + AUC_BAND[1]]
+    compared = {"auc_last_pass": {"value": float(last["auc"]),
+                                  "limit": band},
+                "failed_steps": {"value": int(failed), "limit": 0},
+                "lookup_overflow": {"value": int(overflow), "limit": 0}}
+    compared.update({f"held_out_{k}_gap": {"value": held["diff"][k],
+                                           "limit": held["tol"][k]}
+                     for k in EVAL_TOL})
     return {
         "attempted": steps, "failed": failed, "correct": correct,
+        "compared": compared,
         "window_open": window["open"],
         "end_to_end": {"ctr_samples_per_s_per_chip":
                        steps * feed.batch_size / wall / chips},
         "detail": {
             "passes": len(in_window), "wall_s": wall,
             "pass_walls_s": np.diff(
-                boundaries[warmup - 1:window["last"]]).tolist(),
+                boundaries[warmup - 1:closing]).tolist(),
             "auc_last_pass": last["auc"], "auc_ceiling": ceiling,
             "held_out": held, "lookup_overflow": overflow,
             "last_pass_report": last,

@@ -171,7 +171,12 @@ def test_the_cell_reports_its_metrics():
         "moe.dropped_assignments", "blockdiff.mfu",
         "blockdiff.flash_attention_roofline",
         "blockdiff.flash_attention_device_ms_per_step",
-        "blockdiff.kernel_fallback", "blockdiff.masked_positions_per_step"}
+        "blockdiff.kernel_fallback", "blockdiff.masked_positions_per_step",
+        # the step's device time by named scope
+        "dense.attention_ms_per_step", "moe.layer_ms_per_step",
+        "dense.stack_other_ms_per_step", "dense.head_ms_per_step",
+        "dense.optimizer_ms_per_step", "dense.recompute_ms_per_step",
+        "dense.unscoped_share"}
     for name in listed:
         assert os.path.exists(os.path.join(BENCH, "metrics", name + ".json"))
     end_to_end = {e["name"]: e for e in MANIFEST["end_to_end"]}

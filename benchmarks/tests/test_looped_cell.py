@@ -119,7 +119,12 @@ def test_the_cell_reports_its_metrics():
         "looped.flash_attention_roofline",
         "looped.flash_attention_device_ms_per_step",
         "looped.applications_per_step", "looped.exit_expected_pass",
-        "looped.kernel_fallback"}
+        "looped.kernel_fallback",
+        # the step's device time by named scope
+        "dense.attention_ms_per_step", "dense.mlp_ms_per_step",
+        "dense.stack_other_ms_per_step", "dense.head_ms_per_step",
+        "dense.optimizer_ms_per_step", "dense.recompute_ms_per_step",
+        "dense.unscoped_share"}
     for name in listed:
         assert os.path.exists(os.path.join(BENCH, "metrics", name + ".json"))
 
